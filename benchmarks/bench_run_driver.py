@@ -26,6 +26,7 @@ import numpy as np
 from benchmarks.common import fmt_row, make_task, stack_batches
 from repro.core import TrainerSpec
 from repro.models.paper_nets import make_classifier_loss
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def bench(steps: int, batch: int, num_nodes: int, seed: int,
@@ -81,6 +82,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI configuration (plumbing, not a benchmark)")
     args = ap.parse_args()
+    enable_compile_cache()
     steps = 20 if args.smoke else args.steps
     r = bench(steps, args.batch, args.nodes, args.seed, args.compress)
     print(fmt_row(

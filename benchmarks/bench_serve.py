@@ -53,6 +53,7 @@ from repro.serve import (
     merge_prefill_cache,
     poisson_trace,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 SMOKE_CLASSES = (
     TrafficClass("chat", prompt_len=6, gen_min=2, gen_max=16, weight=3.0),
@@ -181,6 +182,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=4)
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     classes = SMOKE_CLASSES if args.smoke else FULL_CLASSES
     clock = "steps" if args.smoke else "wall"
@@ -190,7 +192,7 @@ def main():
     max_batch = min(args.batch, 4) if args.smoke else args.batch
     max_len = max(c.prompt_len + c.gen_max for c in classes)
 
-    cfg = get_arch(args.arch, smoke=True)
+    cfg = get_arch(args.arch, smoke=args.smoke)
     model = TransformerLM(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
     trace = poisson_trace(classes, rate=rate, horizon=horizon,

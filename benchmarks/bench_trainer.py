@@ -55,6 +55,7 @@ from benchmarks.common import make_task, params_digest
 from repro.core import TrainerSpec, run_segments
 from repro.models.paper_nets import make_classifier_loss
 from repro.obs import MetricsSink, RecompileWatchdog
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _make_mode(seed: int, with_sink: bool, sanitize: bool = False) -> dict:
@@ -216,6 +217,7 @@ def main():
                     help="interleaved timing rounds per mode "
                          "(default: 5 full, 2 smoke)")
     args = ap.parse_args()
+    enable_compile_cache()
     steps = 24 if args.smoke else args.steps
     seg = 12 if args.smoke else args.seg
     budget = args.overhead_budget if args.overhead_budget is not None \

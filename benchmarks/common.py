@@ -70,7 +70,7 @@ def _gossip_mixer(graph, kwargs, num_nodes, topology, drop_p, seed,
 
     from repro.dynamics import DynamicGossipMixer, make_schedule
     from repro.graphs import build_graph, metropolis_weights
-    from repro.utils.compat import make_auto_mesh
+    from repro.launch.mesh import make_auto_mesh
 
     if jax.device_count() < num_nodes:
         raise RuntimeError(
@@ -119,7 +119,7 @@ def _hierarchical_mixer(graph, kwargs, num_nodes, replicas, seed):
         metropolis_weights,
         permutation_decomposition,
     )
-    from repro.utils.compat import make_auto_mesh
+    from repro.launch.mesh import make_auto_mesh
 
     if jax.device_count() < num_nodes * replicas:
         raise RuntimeError(

@@ -11,6 +11,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
@@ -18,6 +20,7 @@ def main() -> None:
                     help="comma-separated subset: fig2,fig3,fig4,table1,"
                          "fig5,fig6,fig7,fig8,roofline")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks import (
         fig2_fmnist_robustness,

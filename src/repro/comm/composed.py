@@ -81,7 +81,6 @@ from repro.comm.wire import (
     _merge_dtype_bytes,
     _send_mask,
 )
-from repro.utils.compat import shard_map, shard_map_unchecked
 from repro.utils.tree import tree_bytes
 
 
@@ -372,7 +371,7 @@ class ComposedMixer(Mixer):
             def body(tr, sw, mws):
                 return inner(tr, sw, mws)
 
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=t.mesh,
             in_specs=(t.param_specs, t._p_node,
@@ -449,11 +448,10 @@ class ComposedMixer(Mixer):
         )
 
     def _quantized_gossip(self, theta, self_w, match_ws, masks, key):
-        from repro.kernels.quant_gossip.ops import masked_quant_gossip_round
+        from repro.kernels.quant_gossip.ops import quant_gossip_round
 
         t = self.transport
         cfg = self.quantized
-        interpret = cfg.interpret or jax.default_backend() != "tpu"
 
         def body(tr, sw, mws, mks, k0):
             leaves, treedef = jax.tree.flatten(tr)
@@ -467,19 +465,20 @@ class ComposedMixer(Mixer):
                     jax.random.fold_in(k0, i), self._node_index())
                 for m, (pw, mk, perm) in enumerate(
                         zip(mws, mks, t.perms)):
-                    acc = masked_quant_gossip_round(
-                        xf, acc, pw, mk, t.axis, perm,
-                        jax.random.fold_in(lk, m), qmax=self._qmax,
-                        block_d=cfg.block_d, interpret=interpret,
+                    acc = quant_gossip_round(
+                        xf, acc, pw, t.axis, perm,
+                        jax.random.fold_in(lk, m), mask=mk, qmax=self._qmax,
+                        block_d=cfg.block_d, interpret=cfg.interpret,
                         use_kernel=cfg.use_kernel)
                 out.append(acc.reshape(x.shape).astype(x.dtype))
             return treedef.unflatten(out)
 
         p_rep = jax.sharding.PartitionSpec()
         n = len(t.perms)
-        return shard_map_unchecked(
+        return jax.shard_map(
             body,
             mesh=t.mesh,
+            check_vma=False,
             in_specs=(t.param_specs, t._p_node,
                       [t._p_node] * n, [t._p_node] * n, p_rep),
             out_specs=t.param_specs,
@@ -493,6 +492,12 @@ class ComposedMixer(Mixer):
         rate = self._rate(state)
         gamma = self.wire.gamma_for(rate)
         node_ks = per_node_keys(sub, jnp.arange(self.k))
+        # a time-varying W can isolate a node: like the gossip lowering's
+        # sender mask, it then sends nothing and its θ̂ stays frozen
+        send = None
+        if self._dynamic:
+            off_diag = w * (1.0 - jnp.eye(self.k, dtype=w.dtype))
+            send = jnp.any(off_diag != 0, axis=1).astype(jnp.float32)
         leaves, treedef = jax.tree.flatten(theta)
         hats = (treedef.flatten_up_to(state.hat) if self.ef
                 else [() for _ in leaves])
@@ -505,7 +510,7 @@ class ComposedMixer(Mixer):
             if self.ef:
                 res_sq = res_sq + jnp.sum(jnp.square(xf - hf))
             _, public, new_hat = self._encode_leaf(
-                xf, hf, fold_leaf(node_ks, i), rate)
+                xf, hf, fold_leaf(node_ks, i), rate, send_mask=send)
             mixed = jnp.einsum(
                 "kl,ld->kd", w, public,
                 precision=jax.lax.Precision.HIGHEST)
@@ -603,9 +608,10 @@ class ComposedMixer(Mixer):
                     res_sq)
 
         in_hat = (specs if ef else (), specs if ef else ())
-        shard = shard_map_unchecked(
+        shard = jax.shard_map(
             body,
             mesh=t.mesh,
+            check_vma=False,
             in_specs=(specs, in_hat[0], in_hat[1], p_node,
                       [p_node] * len(match_ws), [p_node] * len(mask_args),
                       p_rep, p_rep),
@@ -750,9 +756,10 @@ class ComposedMixer(Mixer):
             return u(o_t), u(o_h), u(o_s), res_sq
 
         n = len(t.perms)
-        shard = shard_map_unchecked(
+        shard = jax.shard_map(
             body,
             mesh=t.mesh,
+            check_vma=False,
             in_specs=(specs, specs, p_node, [p_node] * n, [p_node] * n,
                       p_rep, p_rep),
             out_specs=(specs, specs, specs, p_rep),

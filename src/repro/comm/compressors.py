@@ -265,9 +265,13 @@ class IntQuantizer:
             q = _unpack_int4(q, d)
         return q.astype(jnp.float32) * scale
 
+    def value_bytes(self, d):
+        """Bytes of the quantized values on the wire (packed nibbles for
+        static int4, one byte per entry otherwise)."""
+        return d if not self._pack() else (d + 1) // 2
+
     def payload_bytes(self, d):
-        # packed nibbles for static int4, full bytes otherwise, + f32 scale
-        return (d if not self._pack() else (d + 1) // 2) + _SCALE_BYTES
+        return self.value_bytes(d) + _SCALE_BYTES
 
     def payload_bits(self, d, rate=None):
         if rate is None:
@@ -279,11 +283,13 @@ class KernelInt8Quantizer(IntQuantizer):
     """int8 quantizer served by the fused Pallas quant_gossip kernel.
 
     Same wire format as :class:`IntQuantizer` except the scale is per
-    (node, block): the kernel computes each block's absmax and quantizes it
-    in one VMEM-resident pass, and ``accumulate`` fuses dequantize with the
-    weighted neighbor combine so the full-precision message never exists.
-    The dynamic qmax rides into the kernel as a traced SMEM-style scalar, so
-    a schedule's int8→int4 switch costs no recompile.
+    (node, block) and the int8 values keep the kernel's tile view (the
+    ragged tail of a leaf rides zero-padded): the kernel computes each
+    block's absmax and quantizes it in one VMEM-resident pass, and
+    ``accumulate`` fuses dequantize with the weighted neighbor combine so
+    the full-precision message never exists.  The dynamic qmax rides into
+    the kernel as a traced SMEM scalar, so a schedule's int8→int4 switch
+    costs no recompile.
     """
 
     def __init__(self, block_d: int = 65536, interpret: bool = False,
@@ -294,61 +300,58 @@ class KernelInt8Quantizer(IntQuantizer):
         self.interpret = interpret
 
     def compress(self, x, keys, rate=None):
-        from repro.kernels.quant_gossip.ops import quantize_blockwise
-
-        qmax = jnp.float32(self.qmax) if rate is None else rate
-        u = _uniform_rows(keys, x.shape[1])
-        return quantize_blockwise(x, u, qmax=qmax, block_d=self.block_d,
-                                  interpret=self.interpret)
+        return self.compress_masked(x, keys, None, rate)
 
     def decompress(self, payload, d):
-        from repro.kernels.quant_gossip.ops import dequantize_blockwise
+        from repro.kernels.quant_gossip.ops import dequantize_tiles
 
         q, scale = payload
-        return dequantize_blockwise(q, scale)
+        return dequantize_tiles(q, scale, d)
 
     def accumulate(self, acc, payload, weight):
         """acc + weight * dequantize(payload), fused (one pass over q)."""
-        from repro.kernels.quant_gossip.ops import dequant_accumulate
-
-        q, scale = payload
-        return dequant_accumulate(acc, q, scale, weight,
-                                  interpret=self.interpret)
+        return self.accumulate_masked(acc, payload, weight, None)
 
     def compress_masked(self, x, keys, mask, rate=None):
-        """Sender-masked quantize via the fused masked Pallas kernel: masked
-        rows emit a zero payload and zero scales (nothing on the wire), so
-        the EF innovation of a fully-faulted node stays unsent and its θ̂
-        frozen.  An all-ones mask is bit-identical to :meth:`compress`."""
-        from repro.kernels.quant_gossip.ops import masked_quantize_blockwise
+        """Sender-masked quantize via the fused Pallas kernel: masked rows
+        emit a zero payload and zero scales (nothing on the wire), so the EF
+        innovation of a fully-faulted node stays unsent and its θ̂ frozen.
+        ``mask=None`` and an all-ones mask are bit-identical."""
+        from repro.kernels.quant_gossip.ops import quantize_tiles
 
         qmax = jnp.float32(self.qmax) if rate is None else rate
         u = _uniform_rows(keys, x.shape[1])
-        return masked_quantize_blockwise(x, u, mask, qmax=qmax,
-                                         block_d=self.block_d,
-                                         interpret=self.interpret)
+        return quantize_tiles(x, u, qmax=qmax, block_d=self.block_d,
+                              mask=mask, interpret=self.interpret)
 
     def accumulate_masked(self, acc, payload, weight, mask):
         """acc + mask·weight·dequantize(payload), fused; masked links add
         exactly 0 (bitwise passthrough of acc)."""
-        from repro.kernels.quant_gossip.ops import masked_dequant_accumulate
+        from repro.kernels.quant_gossip.ops import dequant_accumulate_tiles
 
         q, scale = payload
-        return masked_dequant_accumulate(acc, q, scale, weight, mask,
-                                         interpret=self.interpret)
+        return dequant_accumulate_tiles(acc, q, scale, weight, mask,
+                                        interpret=self.interpret)
 
     def _n_blocks(self, d):
         from repro.kernels.quant_gossip.kernel import num_blocks
 
         return num_blocks(d, self.block_d)
 
+    def value_bytes(self, d):
+        from repro.kernels.quant_gossip.kernel import block_len
+
+        n = self._n_blocks(d)
+        return n * block_len(d, n)
+
     def payload_bytes(self, d):
-        return d + _SCALE_BYTES * self._n_blocks(d)
+        return self.value_bytes(d) + _SCALE_BYTES * self._n_blocks(d)
 
     def payload_bits(self, d, rate=None):
         if rate is None:
             return 8 * self.payload_bytes(d)
-        return quant_bits(rate) * d + 8 * _SCALE_BYTES * self._n_blocks(d)
+        return (quant_bits(rate) * self.value_bytes(d)
+                + 8 * _SCALE_BYTES * self._n_blocks(d))
 
 
 def _num_kept(d: int, ratio: float) -> int:

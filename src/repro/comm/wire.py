@@ -48,7 +48,9 @@ def ef_residual(theta, state: CommState):
 
 
 def _f32_zeros_like(tree):
-    return jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), tree)
+    # zeros_like keeps each leaf's sharding: zeros(x.shape) would build
+    # every node's copy on the default device
+    return jax.tree.map(lambda x: jnp.zeros_like(x, jnp.float32), tree)
 
 
 def _send_mask(masks):
@@ -73,7 +75,7 @@ def _codec_wire_dtypes(compressor, d: int) -> dict[str, int]:
     total = compressor.payload_bytes(d)
     name = getattr(compressor, "name", "")
     if name.startswith("int"):  # int8 / int4 / int8-kernel
-        q = d if not compressor._pack() else (d + 1) // 2
+        q = compressor.value_bytes(d)
         return {"s8": q, "f32": total - q}
     if name in ("topk", "randk"):
         return {"f32": total // 2, "s32": total // 2}

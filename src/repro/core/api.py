@@ -227,7 +227,8 @@ class DecentralizedTrainer:
                 # and the per-step errors ride out as a stacked scan output
                 # for one batched throw() on the host
                 def body(st, batch):
-                    err, (st2, m) = checked_body(st, batch)
+                    err, (st2, m) = checked_body(
+                        jax.lax.optimization_barrier(st), batch)
                     return st2, (err, m)
 
                 state, (errs, ms) = jax.lax.scan(body, state, batches)
@@ -235,7 +236,15 @@ class DecentralizedTrainer:
         else:
 
             def scan_run(state, batches):
-                return jax.lax.scan(self._train_step_fn, state, batches)
+                # the barrier keeps XLA from fusing across a step boundary:
+                # a one-step segment (whose loop XLA removes) then computes
+                # bit-for-bit what the same step does inside a longer scan,
+                # so a run's result does not depend on how it is segmented
+                def body(st, batch):
+                    return self._train_step_fn(
+                        jax.lax.optimization_barrier(st), batch)
+
+                return jax.lax.scan(body, state, batches)
 
         # the jittable scan driver, kept for the static auditor
         # (repro.analysis.audit probes donation on it even when the

@@ -107,7 +107,7 @@ class LocalUpdateMixer(Mixer):
         state = self.inner.init_state(params)
         if self.gt:
             corr = jax.tree.map(
-                lambda x: jnp.zeros(x.shape, jnp.float32), params)
+                lambda x: jnp.zeros_like(x, jnp.float32), params)
             # anchor must not alias params (astype is a no-op on f32 leaves
             # and the scan driver donates the whole carry): force a copy
             anchor = jax.tree.map(

@@ -16,7 +16,33 @@ communication algorithm), but the production framework around it does:
                     only wire buffer is the int8 payload
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-public wrapper with CPU fallback) and ref.py (pure-jnp oracle); correctness
-is swept in tests/test_kernel_*.py and tests/test_comm.py with
-interpret=True on CPU.
+public wrapper) and ref.py (pure-jnp oracle); correctness is swept in
+tests/test_kernel_*.py and tests/test_comm.py with interpret=True on CPU,
+and tests/test_tpu_compile.py compiles the main-path kernels for a
+described v5e.  Every wrapper picks its path with :func:`kernel_path`.
 """
+
+import jax
+
+
+def kernel_path(use_kernel: bool, interpret: bool) -> str:
+    """How a kernel wrapper runs: ``"pallas"``, ``"interpret"`` or ``"ref"``.
+
+    ``use_kernel=False`` asks for the jnp reference and ``interpret=True``
+    for the Pallas interpreter, on any backend.  Otherwise the TPU always
+    runs the compiled kernel (a kernel that fails to compile raises), and
+    only the CPU backend may take the reference in its place; any other
+    backend has no path and raises.
+    """
+    if not use_kernel:
+        return "ref"
+    if interpret:
+        return "interpret"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return "pallas"
+    if backend == "cpu":
+        return "ref"
+    raise NotImplementedError(
+        f"no Pallas kernel path on the {backend!r} backend; pass "
+        "use_kernel=False for the jnp reference")

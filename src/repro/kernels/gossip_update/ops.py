@@ -1,8 +1,9 @@
 """Jitted wrapper: apply the fused gossip update across a parameter pytree.
 
 ``gossip_update_tree`` flattens each leaf to 1-D and runs the Pallas kernel
-(or the jnp ref off-TPU), so the whole pytree update is a single fused pass
-per leaf instead of 7 elementwise HLO ops.
+(or the jnp ref on the CPU, see :func:`repro.kernels.kernel_path`), so the
+whole pytree update is a single fused pass per leaf instead of 7
+elementwise HLO ops.
 """
 
 from __future__ import annotations
@@ -12,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import kernel_path
 from repro.kernels.gossip_update.kernel import gossip_update
 from repro.kernels.gossip_update.ref import gossip_update_ref
 
@@ -19,10 +21,10 @@ from repro.kernels.gossip_update.ref import gossip_update_ref
 @functools.partial(jax.jit, static_argnames=("eta", "interpret", "use_kernel"))
 def gossip_update_flat(theta, grad, neighbors, weights, scale, *, eta: float,
                        interpret: bool = False, use_kernel: bool = True):
-    on_tpu = jax.default_backend() == "tpu"
-    if use_kernel and (on_tpu or interpret):
+    path = kernel_path(use_kernel, interpret)
+    if path != "ref":
         return gossip_update(theta, grad, neighbors, weights, scale, eta=eta,
-                             interpret=interpret or not on_tpu)
+                             interpret=path == "interpret")
     return gossip_update_ref(theta, grad, neighbors, weights, scale, eta=eta)
 
 

@@ -6,195 +6,170 @@ The ppermute stays an XLA collective (it is already optimal on the torus);
 these two kernels fuse everything around it so the *only* HBM-resident wire
 buffer is the int8 payload plus its per-block float32 scales:
 
-* ``quantize_blockwise``   — one pass over x: each (node, block) tile
+* ``quantize_tiles`` — one pass over x: each (node, block) tile
   computes its own absmax scale in VMEM and stochastically rounds
   ``floor(x/scale + u)`` into int8.  Per-block scales are strictly finer
   than per-node scales, so the kernel path is never less accurate than the
-  jnp compressor it replaces.
-* ``dequant_accumulate``   — one pass over the received payload:
-  ``acc + w_node · scale_block · q`` without materializing the dequantized
-  float32 message.
+  jnp compressor it replaces.  An optional per-node send mask zeroes the
+  payload and scales of masked rows.
+* ``dequant_accumulate_tiles`` — one pass over the received payload:
+  ``acc + w_node · (q · scale_block)`` without materializing the
+  dequantized float32 message.
 
-Layouts: x, u, acc (K, D); q (K, D) int8; scales (K, n_blocks) f32;
-w (K,) f32 per-node receive weight.  Stochastic-rounding uniforms ``u`` are
-an input (generated from the traced PRNG key) so the kernel is bit-exact
-reproducible against ``ref.py`` in interpret mode.
+Layout.  A row of D elements is cut into ``n_blk = ceil(D / block_d)``
+scale blocks of :func:`block_len` elements (the last one zero-padded).  The
+kernels see each block as one ``(rows, lanes)`` tile of a
+``(K·n_blk, rows, lanes)`` view, with ``lanes = 128`` (``= block`` for
+blocks of at most 128) and ``rows`` a multiple of 8 once a block spans
+more than 8 lane rows, so every block shape equals the view's last two
+dims and meets the TPU (8, 128) / (32, 128) tiling rule at any width,
+ragged or not.  The int8 payload stays in that tile view from the
+quantize kernel over the wire to the dequantize kernel:
+reshaping int8 between tiled layouts is a relayout the TPU compiler pays
+for at every leaf.  Scales come out as ``(K, n_blk)``; per-node scalars
+(qmax, the send mask, the receive weights) ride in SMEM.  The uniforms
+``u`` are an input (generated from the traced PRNG key) so the kernel is
+bit-exact against ``ref.py`` in interpret mode.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+#: a block longer than this is whole (8, 128) f32 tiles, so the tile view of
+#: an unpadded f32 row is a bitcast
+_TILE = 8 * LANES
 
 
-def _quantize_kernel(qmax_ref, x_ref, u_ref, q_ref, scale_ref):
-    # qmax rides in as a (1, 1) traced scalar so an adaptive schedule can
-    # switch int8 -> int4 wire (qmax 127 -> 7) without recompiling
-    qmax = qmax_ref[0, 0]
-    x = x_ref[...].astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(x))
-    scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
-    y = jnp.floor(x / scale + u_ref[...].astype(jnp.float32))
-    q_ref[...] = jnp.clip(y, -qmax, qmax).astype(jnp.int8)
-    scale_ref[0, 0] = scale
-
-
-def _dequant_acc_kernel(w_ref, q_ref, scale_ref, acc_ref, o_ref):
-    q = q_ref[...].astype(jnp.float32)
-    o_ref[...] = (acc_ref[...].astype(jnp.float32)
-                  + w_ref[0] * scale_ref[0, 0] * q).astype(o_ref.dtype)
-
-
-def _masked_quantize_kernel(qmax_ref, x_ref, u_ref, m_ref, q_ref, scale_ref):
-    # per-round send mask rides in as a (1, 1) traced per-node scalar: a
-    # masked-out sender (dropped link / straggler round) emits an all-zero
-    # payload and a zero scale, so the wire carries nothing and the receive
-    # side reconstructs exactly 0 — one compiled program for every round of
-    # a dynamic topology (repro.dynamics)
-    qmax = qmax_ref[0, 0]
-    m = m_ref[0, 0]
-    x = x_ref[...].astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(x))
-    scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
-    y = jnp.floor(x / scale + u_ref[...].astype(jnp.float32))
-    q_ref[...] = (jnp.clip(y, -qmax, qmax) * m).astype(jnp.int8)
-    scale_ref[0, 0] = scale * m
-
-
-def _masked_dequant_acc_kernel(w_ref, m_ref, q_ref, scale_ref, acc_ref,
-                               o_ref):
-    # a masked link contributes exactly acc (0·w·scale·q adds float zero)
-    q = q_ref[...].astype(jnp.float32)
-    o_ref[...] = (acc_ref[...].astype(jnp.float32)
-                  + m_ref[0] * w_ref[0] * scale_ref[0, 0] * q
-                  ).astype(o_ref.dtype)
-
-
-def _pick_block(d: int, block_d: int) -> int:
-    block_d = min(block_d, d)
-    if d % block_d:
-        block_d = d  # ragged tail: fall back to a single block per row
-    return block_d
+def block_len(d: int, n_blk: int) -> int:
+    """Elements per scale block when a row of ``d`` is cut into ``n_blk``
+    blocks: ``ceil(d / n_blk)``, rounded up to whole 128-lane rows past one
+    lane row and to whole (8, 128) tiles past one tile.  For every
+    ``n = num_blocks(d, block_d)``, ``ceil(d / block_len(d, n)) == n``, so
+    a receiver recovers the layout from the scales' shape alone."""
+    b = -(-d // n_blk)
+    if b > _TILE:
+        return -(-b // _TILE) * _TILE
+    return b if b <= LANES else -(-b // LANES) * LANES
 
 
 def num_blocks(d: int, block_d: int) -> int:
-    """Scale blocks per row for a given layout (mirrors :func:`_pick_block`,
-    so wire-byte accounting matches what the kernel actually emits)."""
-    return d // _pick_block(d, block_d)
+    """Scale blocks per row of ``d`` for a requested block length (the
+    kernel and ``ref.py`` emit exactly this many scales per node)."""
+    unit = _TILE if block_d > _TILE else LANES
+    if d > block_d > LANES and block_d % unit:
+        raise ValueError(f"block_d={block_d} must be <= {LANES}, a multiple "
+                         f"of {LANES} up to {_TILE}, or of {_TILE} beyond")
+    return -(-d // block_d)
 
 
-def quantize_blockwise(x, u, *, qmax=127, block_d: int = 65536,
-                       interpret: bool = False):
-    """x, u: (K, D) -> (q int8 (K, D), scales f32 (K, D/block_d)).
+def tile_shape(k: int, d: int, n_blk: int) -> tuple[int, int, int]:
+    """Shape of the ``(K·n_blk, rows, lanes)`` tile view of a (K, D) array."""
+    b = block_len(d, n_blk)
+    lanes = min(b, LANES)
+    return (k * n_blk, b // lanes, lanes)
 
-    ``qmax`` may be a python int or a traced f32 scalar (schedule-driven).
+
+def to_tiles(x, n_blk: int):
+    """(K, D) -> (K·n_blk, rows, lanes), one scale block per leading index
+    (the ragged tail zero-padded: zeros change no absmax and quantize to 0)."""
+    k, d = x.shape
+    shape = tile_shape(k, d, n_blk)
+    pad = n_blk * shape[1] * shape[2] - d
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)))
+    return x.reshape(shape)
+
+
+def from_tiles(t, k: int, d: int):
+    """Inverse of :func:`to_tiles` (drops the padded tail)."""
+    return t.reshape(k, -1)[:, :d]
+
+
+def _smem():
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _tile_spec(shape):
+    return pl.BlockSpec((1,) + shape[1:], lambda i: (i, 0, 0))
+
+
+def _quantize_kernel(qmax_ref, mask_ref, x_ref, u_ref, q_ref, scale_ref, *,
+                     n_blk):
+    # qmax is a traced scalar so an adaptive schedule can switch the int8
+    # wire to int4 (qmax 127 -> 7) without recompiling; the per-node send
+    # mask zeroes a masked sender's payload and scale (nothing on the wire)
+    qmax = qmax_ref[0]
+    m = mask_ref[pl.program_id(0) // n_blk]
+    x = x_ref[...]
+    absmax = jnp.max(jnp.max(jnp.abs(x), axis=2, keepdims=True), axis=1,
+                     keepdims=True)
+    scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
+    y = jnp.floor(x / scale + u_ref[...])
+    q_ref[...] = (jnp.clip(y, -qmax, qmax) * m).astype(jnp.int8)
+    scale_ref[...] = jnp.broadcast_to(scale * m, scale_ref.shape)
+
+
+def _dequant_acc_kernel(w_ref, q_ref, scale_ref, acc_ref, o_ref, *, n_blk):
+    w = w_ref[pl.program_id(0) // n_blk]
+    deq = q_ref[...].astype(jnp.float32) * scale_ref[...]
+    o_ref[...] = (acc_ref[...].astype(jnp.float32) + w * deq
+                  ).astype(o_ref.dtype)
+
+
+def quantize_tiles(x, u, *, qmax=127, block_d: int = 65536, mask=None,
+                   interpret: bool = False):
+    """x, u: (K, D) -> (q int8 tiles (K·n_blk, rows, lanes), scales f32
+    (K, n_blk)).
+
+    ``qmax`` may be a python int or a traced f32 scalar (schedule-driven);
+    ``mask`` (K,) in {0, 1}, traced, zeroes masked rows' payload and scales.
     """
     k, d = x.shape
-    block_d = _pick_block(d, block_d)
-    n_blk = d // block_d
-    grid = (k, n_blk)
-    qmax_arr = jnp.reshape(jnp.asarray(qmax, jnp.float32), (1, 1))
-    return pl.pallas_call(
-        _quantize_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, d), jnp.int8),
-            jax.ShapeDtypeStruct((k, n_blk), jnp.float32),
-        ],
+    n_blk = num_blocks(d, block_d)
+    xt = to_tiles(x.astype(jnp.float32), n_blk)
+    ut = to_tiles(u.astype(jnp.float32), n_blk)
+    if mask is None:
+        mask = jnp.ones((k,), jnp.float32)
+    scale_shape = (xt.shape[0], 1, xt.shape[2])
+    q, s = pl.pallas_call(
+        functools.partial(_quantize_kernel, n_blk=n_blk),
+        grid=(xt.shape[0],),
+        in_specs=[_smem(), _smem(), _tile_spec(xt.shape),
+                  _tile_spec(xt.shape)],
+        out_specs=[_tile_spec(xt.shape), _tile_spec(scale_shape)],
+        out_shape=[jax.ShapeDtypeStruct(xt.shape, jnp.int8),
+                   jax.ShapeDtypeStruct(scale_shape, jnp.float32)],
+        name="quant_gossip_quantize",
         interpret=interpret,
-    )(qmax_arr, x, u)
+    )(jnp.reshape(jnp.asarray(qmax, jnp.float32), (1,)),
+      jnp.reshape(mask.astype(jnp.float32), (k,)), xt, ut)
+    return q, s[:, 0, 0].reshape(k, n_blk)
 
 
-def dequant_accumulate(acc, q, scales, w, *, block_d: int = 65536,
-                       interpret: bool = False):
-    """acc (K, D) f32, q (K, D) int8, scales (K, n_blk), w (K,) -> (K, D)."""
+def dequant_accumulate_tiles(acc, q, scales, w, *, interpret: bool = False):
+    """acc (K, D) f32, q int8 tiles, scales (K, n_blk), w (K,) -> (K, D):
+    ``acc + w · (q · scale)`` per (node, block)."""
     k, d = acc.shape
     n_blk = scales.shape[1]
-    block_d = d // n_blk
-    grid = (k, n_blk)
-    return pl.pallas_call(
-        _dequant_acc_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((k, d), acc.dtype),
+    acct = to_tiles(acc, n_blk)
+    st = jnp.broadcast_to(scales.reshape(k * n_blk, 1, 1).astype(jnp.float32),
+                          (k * n_blk, 1, q.shape[2]))
+    out = pl.pallas_call(
+        functools.partial(_dequant_acc_kernel, n_blk=n_blk),
+        grid=(q.shape[0],),
+        in_specs=[_smem(), _tile_spec(q.shape), _tile_spec(st.shape),
+                  _tile_spec(acct.shape)],
+        out_specs=_tile_spec(acct.shape),
+        out_shape=jax.ShapeDtypeStruct(acct.shape, acc.dtype),
+        name="quant_gossip_dequant_acc",
         interpret=interpret,
-    )(w, q, scales, acc)
+    )(jnp.reshape(w.astype(jnp.float32), (k,)), q, st, acct)
+    return from_tiles(out, k, d)
 
-
-def masked_quantize_blockwise(x, u, mask, *, qmax=127, block_d: int = 65536,
-                              interpret: bool = False):
-    """Masked-sender variant: x, u (K, D); mask (K,) in {0, 1} traced.
-
-    Masked rows emit an all-zero payload and zero scales (nothing on the
-    wire); the mask is a traced operand so per-round link faults never
-    recompile.
-    """
-    k, d = x.shape
-    block_d = _pick_block(d, block_d)
-    n_blk = d // block_d
-    grid = (k, n_blk)
-    qmax_arr = jnp.reshape(jnp.asarray(qmax, jnp.float32), (1, 1))
-    mask2 = jnp.reshape(mask.astype(jnp.float32), (k, 1))
-    return pl.pallas_call(
-        _masked_quantize_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, d), jnp.int8),
-            jax.ShapeDtypeStruct((k, n_blk), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qmax_arr, x, u, mask2)
-
-
-def masked_dequant_accumulate(acc, q, scales, w, mask, *,
-                              block_d: int = 65536, interpret: bool = False):
-    """Masked-receive variant: acc + mask·w·dequant(q, scales), fused.
-
-    ``w`` and ``mask`` are per-node (K,) traced operands — the per-round
-    neighbor weights/mask of a dynamic topology; a masked link contributes
-    exactly ``acc``.
-    """
-    k, d = acc.shape
-    n_blk = scales.shape[1]
-    block_d = d // n_blk
-    grid = (k, n_blk)
-    return pl.pallas_call(
-        _masked_dequant_acc_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((k, d), acc.dtype),
-        interpret=interpret,
-    )(w, mask.astype(jnp.float32), q, scales, acc)

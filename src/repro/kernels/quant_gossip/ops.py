@@ -1,12 +1,14 @@
-"""Jitted wrappers for the fused quantize-gossip kernels with CPU fallback.
+"""Jitted wrappers for the fused quantize-gossip kernels.
 
-On TPU (or with ``interpret=True``) these dispatch to the Pallas kernels;
-elsewhere they run the bit-identical jnp oracle, so the compressed gossip
-mixer works unchanged in CPU simulation.
+On the TPU these run the compiled Pallas kernels, and with ``interpret=True``
+the Pallas interpreter; only the CPU backend runs the bit-identical jnp
+oracle in their place (:func:`repro.kernels.kernel_path`), so the compressed
+gossip mixer works unchanged in CPU simulation.
 
 ``quant_gossip_round`` composes one full compressed matching exchange —
 quantize → ppermute(int8 payload + scales) → dequantize-accumulate — for use
-inside ``shard_map``; the full-precision message never exists on the wire.
+inside ``shard_map``; the full-precision message never exists on the wire,
+and the int8 payload keeps the kernel's tile view end to end (``*_tiles``).
 """
 
 from __future__ import annotations
@@ -16,123 +18,92 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import kernel_path
 from repro.kernels.quant_gossip import kernel as _k
 from repro.kernels.quant_gossip import ref as _r
 
 
-def _use_pallas(interpret: bool, use_kernel: bool) -> bool:
-    return use_kernel and (jax.default_backend() == "tpu" or interpret)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("block_d", "interpret", "use_kernel"))
-def quantize_blockwise(x, u, *, qmax=127, block_d: int = 65536,
+def quantize_tiles(x, u, *, qmax=127, block_d: int = 65536, mask=None,
+                   interpret: bool = False, use_kernel: bool = True):
+    """(K, D) f32 -> (q int8 tiles (K·n_blk, rows, lanes), per-block scales
+    f32 (K, n_blk)): the wire payload, in the kernel's tile view.
+
+    ``qmax`` is traced (not static), so schedule-driven int8 -> int4 rate
+    switches reuse one compiled program; so is the optional per-node send
+    ``mask`` (K,) in {0, 1}: masked rows put nothing on the wire.
+    """
+    path = kernel_path(use_kernel, interpret)
+    if path != "ref":
+        return _k.quantize_tiles(x, u, qmax=qmax, block_d=block_d, mask=mask,
+                                 interpret=path == "interpret")
+    if mask is None:
+        q, s = _r.quantize_blockwise_ref(x, u, qmax=qmax, block_d=block_d)
+    else:
+        q, s = _r.masked_quantize_blockwise_ref(x, u, mask, qmax=qmax,
+                                                block_d=block_d)
+    return _k.to_tiles(q, s.shape[1]), s
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
+def dequant_accumulate_tiles(acc, q, scales, w, mask=None, *,
+                             interpret: bool = False, use_kernel: bool = True):
+    """acc + w·dequant(q, scales) for a tile-view payload, one fused pass.
+
+    ``w`` and the optional link ``mask`` are per-node (K,) traced operands;
+    a masked link contributes exactly ``acc`` (bitwise): its weight is 0.
+    """
+    w = jnp.reshape(jnp.asarray(w, jnp.float32), (-1,))
+    if mask is not None:
+        w = w * jnp.reshape(jnp.asarray(mask, jnp.float32), (-1,))
+    path = kernel_path(use_kernel, interpret)
+    if path != "ref":
+        return _k.dequant_accumulate_tiles(acc, q, scales, w,
+                                           interpret=path == "interpret")
+    k, d = acc.shape
+    return _r.dequant_accumulate_ref(acc, _k.from_tiles(q, k, d), scales, w)
+
+
+@functools.partial(jax.jit, static_argnames=("d",))
+def dequantize_tiles(q, scales, d: int):
+    """Tile-view payload -> (K, d) float32 (``q · scale`` per block)."""
+    k, n_blk = scales.shape
+    out = q.astype(jnp.float32) * scales.reshape(k * n_blk, 1, 1)
+    return _k.from_tiles(out, k, d)
+
+
+def quantize_blockwise(x, u, *, qmax=127, block_d: int = 65536, mask=None,
                        interpret: bool = False, use_kernel: bool = True):
     """(K, D) f32 -> (q int8 (K, D), per-block scales f32 (K, n_blk)).
 
-    ``qmax`` is traced (not static), so schedule-driven int8 -> int4 rate
-    switches reuse one compiled program.
+    ``mask`` (K,) in {0, 1} is traced, like ``qmax``: masked rows emit a
+    zero payload and zero scales, so per-round topology faults reuse one
+    compiled program.  Two wires are built from this kernel: the memoryless
+    dynamic gossip round quantizes θ per matching (``quant_gossip_round``),
+    and the error-feedback dynamic wire quantizes the *innovation delta*
+    θ − θ̂ once per round (``KernelInt8Quantizer.compress_masked``) with the
+    node-level any-live-link sender mask — a fully-masked node's θ̂ stays
+    frozen exactly as the jnp path's masked input does.
     """
-    if _use_pallas(interpret, use_kernel):
-        on_tpu = jax.default_backend() == "tpu"
-        return _k.quantize_blockwise(x, u, qmax=qmax, block_d=block_d,
-                                     interpret=interpret or not on_tpu)
-    return _r.quantize_blockwise_ref(x, u, qmax=qmax, block_d=block_d)
+    q, s = quantize_tiles(x, u, qmax=qmax, block_d=block_d, mask=mask,
+                          interpret=interpret, use_kernel=use_kernel)
+    return _k.from_tiles(q, *x.shape), s
 
 
-@jax.jit
-def dequantize_blockwise(q, scales):
-    return _r.dequantize_blockwise_ref(q, scales)
+def dequant_accumulate(acc, q, scales, w, mask=None, *,
+                       interpret: bool = False, use_kernel: bool = True):
+    """acc + mask·w·dequant(q, scales), one fused pass over the (K, D) int8
+    payload.  Per-node weights and the optional link mask are traced; a
+    masked link contributes exactly ``acc`` bitwise."""
+    return dequant_accumulate_tiles(acc, _k.to_tiles(q, scales.shape[1]),
+                                    scales, w, mask, interpret=interpret,
+                                    use_kernel=use_kernel)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
-def dequant_accumulate(acc, q, scales, w, *, interpret: bool = False,
-                       use_kernel: bool = True):
-    """acc + w·dequant(q, scales), one fused pass over the int8 payload."""
-    w = jnp.reshape(jnp.asarray(w, jnp.float32), (-1,))
-    if _use_pallas(interpret, use_kernel):
-        on_tpu = jax.default_backend() == "tpu"
-        return _k.dequant_accumulate(acc, q, scales, w,
-                                     interpret=interpret or not on_tpu)
-    return _r.dequant_accumulate_ref(acc, q, scales, w)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_d", "interpret", "use_kernel"))
-def masked_quantize_blockwise(x, u, mask, *, qmax=127, block_d: int = 65536,
-                              interpret: bool = False,
-                              use_kernel: bool = True):
-    """Masked-sender quantize: masked rows put nothing on the wire.
-
-    ``mask`` (K,) in {0, 1} is traced, like ``qmax`` — per-round topology
-    faults reuse one compiled program.
-
-    Two wires are built from this kernel: the memoryless dynamic gossip
-    round quantizes θ per matching (``masked_quant_gossip_round``), and the
-    error-feedback dynamic wire quantizes the *innovation delta* θ − θ̂ once
-    per round (``KernelInt8Quantizer.compress_masked``) with the node-level
-    any-live-link sender mask — a fully-masked node emits zero payload and
-    zero scales, so its θ̂ stays frozen exactly as the jnp path's masked
-    input does (dequantizing to 0), and the zero buffer is what a
-    mask-consulting transport would skip.
-    """
-    if _use_pallas(interpret, use_kernel):
-        on_tpu = jax.default_backend() == "tpu"
-        return _k.masked_quantize_blockwise(
-            x, u, mask, qmax=qmax, block_d=block_d,
-            interpret=interpret or not on_tpu)
-    return _r.masked_quantize_blockwise_ref(x, u, mask, qmax=qmax,
-                                            block_d=block_d)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
-def masked_dequant_accumulate(acc, q, scales, w, mask, *,
-                              interpret: bool = False,
-                              use_kernel: bool = True):
-    """acc + mask·w·dequant(q, scales): per-round neighbor weights *and*
-    link mask are traced operands (the dynamic-topology receive combine,
-    shared by the memoryless wire and the EF delta rounds via
-    ``KernelInt8Quantizer.accumulate_masked``).  A masked link contributes
-    exactly ``acc`` bitwise — with the weights gathered from W_r a dropped
-    link already has weight 0, so the mask is the bitwise-passthrough (and
-    transport-skip) guarantee on top."""
-    w = jnp.reshape(jnp.asarray(w, jnp.float32), (-1,))
-    mask = jnp.reshape(jnp.asarray(mask, jnp.float32), (-1,))
-    if _use_pallas(interpret, use_kernel):
-        on_tpu = jax.default_backend() == "tpu"
-        return _k.masked_dequant_accumulate(
-            acc, q, scales, w, mask, interpret=interpret or not on_tpu)
-    return _r.masked_dequant_accumulate_ref(acc, q, scales, w, mask)
-
-
-def masked_quant_gossip_round(x, acc, weight, mask, axis, perm, key, *,
-                              qmax: int = 127, block_d: int = 65536,
-                              interpret: bool = False,
-                              use_kernel: bool = True):
-    """One masked compressed matching exchange (must run inside shard_map).
-
-    Like :func:`quant_gossip_round` with the per-round link mask threaded to
-    both ends: masked senders emit a zero payload (their innovation never
-    crosses the wire) and masked receivers combine exactly 0.  ``weight``
-    and ``mask`` are traced (K_local,) operands, so every round of a dynamic
-    topology reuses one compiled program.
-    """
-    with jax.named_scope("obs:kernel/masked_quant_gossip_round"):
-        u = jax.random.uniform(key, x.shape, jnp.float32)
-        q, scales = masked_quantize_blockwise(x, u, mask, qmax=qmax,
-                                              block_d=block_d,
-                                              interpret=interpret,
-                                              use_kernel=use_kernel)
-        q = jax.lax.ppermute(q, axis, perm)
-        scales = jax.lax.ppermute(scales, axis, perm)
-        return masked_dequant_accumulate(acc, q, scales, weight, mask,
-                                         interpret=interpret,
-                                         use_kernel=use_kernel)
-
-
-def quant_gossip_round(x, acc, weight, axis, perm, key, *, qmax: int = 127,
-                       block_d: int = 65536, interpret: bool = False,
-                       use_kernel: bool = True):
+def quant_gossip_round(x, acc, weight, axis, perm, key, *, mask=None,
+                       qmax: int = 127, block_d: int = 65536,
+                       interpret: bool = False, use_kernel: bool = True):
     """One compressed matching exchange (must run inside shard_map).
 
     Args:
@@ -142,15 +113,20 @@ def quant_gossip_round(x, acc, weight, axis, perm, key, *, qmax: int = 127,
       axis: mesh axis name(s) carrying the node dimension.
       perm: static list of (src, dst) ppermute pairs.
       key: PRNG key for the stochastic-rounding uniforms.
+      mask: optional traced (K_local,) per-round link mask, applied at both
+        ends: masked senders emit a zero payload and masked receivers
+        combine exactly 0, so every round of a dynamic topology reuses one
+        compiled program.
 
     Returns acc + weight · dequant(ppermute(quantize(x))).
     """
     with jax.named_scope("obs:kernel/quant_gossip_round"):
         u = jax.random.uniform(key, x.shape, jnp.float32)
-        q, scales = quantize_blockwise(x, u, qmax=qmax, block_d=block_d,
-                                       interpret=interpret,
-                                       use_kernel=use_kernel)
+        q, scales = quantize_tiles(x, u, qmax=qmax, block_d=block_d,
+                                   mask=mask, interpret=interpret,
+                                   use_kernel=use_kernel)
         q = jax.lax.ppermute(q, axis, perm)
         scales = jax.lax.ppermute(scales, axis, perm)
-        return dequant_accumulate(acc, q, scales, weight, interpret=interpret,
-                                  use_kernel=use_kernel)
+        return dequant_accumulate_tiles(acc, q, scales, weight, mask,
+                                        interpret=interpret,
+                                        use_kernel=use_kernel)

@@ -1,43 +1,48 @@
 """Pure-jnp oracles for the fused quantize / dequantize-accumulate kernels.
 
 Bit-exact against the Pallas kernels given the same uniforms ``u`` (both
-compute ``clip(floor(x/scale + u))`` with a per-(node, block) absmax scale).
+compute ``clip(floor(x/scale + u))`` with a per-(node, block) absmax scale
+over the block layout of :func:`~repro.kernels.quant_gossip.kernel.block_len`).
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.quant_gossip.kernel import block_len, num_blocks
+
 
 def _blocked(x, n_blk):
+    """(K, D) -> (K, n_blk, block), the ragged tail zero-padded."""
     k, d = x.shape
-    return x.reshape(k, n_blk, d // n_blk)
+    b = block_len(d, n_blk)
+    return jnp.pad(x, ((0, 0), (0, n_blk * b - d))).reshape(k, n_blk, b)
+
+
+def _unblocked(xb, d):
+    return xb.reshape(xb.shape[0], -1)[:, :d]
 
 
 def quantize_blockwise_ref(x, u, *, qmax=127, block_d: int = 65536):
-    """x, u: (K, D) -> (q int8 (K, D), scales f32 (K, D/block_d)).
+    """x, u: (K, D) -> (q int8 (K, D), scales f32 (K, n_blk)).
 
     ``qmax`` may be a python int or a traced f32 scalar.
     """
     k, d = x.shape
-    block_d = min(block_d, d)
-    if d % block_d:
-        block_d = d
-    n_blk = d // block_d
+    n_blk = num_blocks(d, block_d)
     xb = _blocked(x.astype(jnp.float32), n_blk)
     absmax = jnp.max(jnp.abs(xb), axis=2, keepdims=True)
     scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
     y = jnp.floor(xb / scale + _blocked(u.astype(jnp.float32), n_blk))
     q = jnp.clip(y, -qmax, qmax).astype(jnp.int8)
-    return q.reshape(k, d), scale.reshape(k, n_blk)
+    return _unblocked(q, d), scale.reshape(k, n_blk)
 
 
 def dequantize_blockwise_ref(q, scales):
     """(K, D) int8 + (K, n_blk) scales -> (K, D) float32."""
-    k, d = q.shape
-    n_blk = scales.shape[1]
-    out = _blocked(q.astype(jnp.float32), n_blk) * scales[:, :, None]
-    return out.reshape(k, d)
+    d = q.shape[1]
+    out = _blocked(q.astype(jnp.float32), scales.shape[1]) * scales[:, :, None]
+    return _unblocked(out, d)
 
 
 def dequant_accumulate_ref(acc, q, scales, w):

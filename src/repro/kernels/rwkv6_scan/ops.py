@@ -6,6 +6,7 @@ import functools
 
 import jax
 
+from repro.kernels import kernel_path
 from repro.kernels.rwkv6_scan.kernel import wkv6_scan
 from repro.kernels.rwkv6_scan.ref import wkv6_ref
 
@@ -14,8 +15,8 @@ from repro.kernels.rwkv6_scan.ref import wkv6_ref
 def wkv6(r, k, v, w, u, *, block_t: int = 64, interpret: bool = False,
          use_kernel: bool = True):
     """r,k,v,w: (B,H,T,hd); u: (H,hd) -> (B,H,T,hd)."""
-    on_tpu = jax.default_backend() == "tpu"
-    if use_kernel and (on_tpu or interpret):
+    path = kernel_path(use_kernel, interpret)
+    if path != "ref":
         return wkv6_scan(r, k, v, w, u, block_t=block_t,
-                         interpret=interpret or not on_tpu)
+                         interpret=path == "interpret")
     return wkv6_ref(r, k, v, w, u)

@@ -48,7 +48,7 @@ from repro.models import SHAPES, TransformerLM, input_shapes
 from repro.obs import expect_compiles
 from repro.models.config import ArchConfig, ShapeConfig
 from repro.optim import sgd
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.utils.hlo import collective_summary, parse_collectives
 from repro.utils.roofline import model_flops
 

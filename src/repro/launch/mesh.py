@@ -19,7 +19,14 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro.utils.compat import make_auto_mesh
+
+def make_auto_mesh(axis_shapes, axis_names, *, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (``make_mesh`` defaults to
+    ``Explicit`` axes, whose sharding-in-types rules the shard_map mixers
+    and the pjit rules here do not follow)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         (jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -35,6 +35,7 @@ from repro.serve.prefill import (  # noqa: F401  (compat re-exports)
     merge_prefill_cache,
 )
 from repro.serve.sampling import sample_tokens
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def timed_generate(model: TransformerLM, params, prompt, gen_len: int,
@@ -192,6 +193,7 @@ def main():
     ap.add_argument("--log-dir", default=None)
     ap.add_argument("--log-every", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch, smoke=args.smoke)
     model = TransformerLM(cfg)

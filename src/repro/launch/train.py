@@ -2,9 +2,10 @@
 
 Runs the paper's algorithm end-to-end on any of the assigned architectures
 (synthetic token streams, per-node distribution shift) or the paper's own
-MLP/CNN image models.  On this CPU container use the smoke configs; on a real
-TPU slice the same entry point takes ``--mesh single|multi`` and shards the
-node axis across the pod(s).
+MLP/CNN image models.  On the CPU use the ``--smoke`` configs; on a TPU the
+same entry point trains the published widths, with the K nodes stacked on
+one device (K=2 of qwen2-0.5b fits one 16 GB v5e at seq 512).  One node per
+chip over a ``("node",)`` mesh is ``chip_smoke.py --four-chips``.
 
 Trainer construction is declarative (``repro.core.TrainerSpec``: the same
 flags drive the benchmarks and examples) and the hot loop runs through
@@ -81,6 +82,7 @@ from repro.obs import (
     format_train,
     profile,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _dynamics_meta(spec: TrainerSpec) -> dict:
@@ -224,6 +226,7 @@ def main():
     add_obs_cli_args(ap)
     TrainerSpec.add_cli_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     with MetricsSink(args.log_dir,
                      vector_every=args.tap_vectors_every) as sink:
         if args.paper:

@@ -209,9 +209,10 @@ def init_paged_kv(cfg: ArchConfig, num_pages: int, page_size: int, *,
 def quantize_kv_rows(x, *, scale_block: int = KV_SCALE_BLOCK):
     """(N, D) -> (q int8 (N, D), scales f32 (N, S)), round-to-nearest.
 
-    Reuses the ``quant_gossip`` blockwise-quantize Pallas kernel (jnp oracle
-    off-TPU) with u = 0.5, i.e. ``round(x / scale)`` — the cache write path
-    is deterministic, unlike the stochastically-rounded gossip wire.
+    Reuses the ``quant_gossip`` blockwise-quantize Pallas kernel (the jnp
+    oracle on the CPU) with u = 0.5, i.e. ``round(x / scale)`` — the cache
+    write path is deterministic, unlike the stochastically-rounded gossip
+    wire.
     """
     from repro.kernels.quant_gossip import ops as qops
 
@@ -222,7 +223,10 @@ def quantize_kv_rows(x, *, scale_block: int = KV_SCALE_BLOCK):
 
 def _expand_kv_scales(scales, d: int):
     """(..., S) per-block scales -> (..., D) per-element multipliers."""
-    return jnp.repeat(scales, d // scales.shape[-1], axis=-1)
+    from repro.kernels.quant_gossip.kernel import block_len
+
+    b = block_len(d, scales.shape[-1])
+    return jnp.repeat(scales, b, axis=-1)[..., :d]
 
 
 def paged_kv_write(pool, k, v, page_ids, offsets, *,

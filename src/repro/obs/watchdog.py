@@ -133,12 +133,7 @@ class CompileCounter:
         return self
 
     def __exit__(self, *exc) -> None:
-        try:
-            from jax._src import monitoring as _m
-
-            _m._unregister_event_listener_by_callback(self._listener)
-        except Exception:  # pragma: no cover - private API moved; keep counting
-            pass
+        _monitoring.unregister_event_listener(self._listener)
 
 
 class _ExpectCompiles:

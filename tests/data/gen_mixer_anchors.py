@@ -173,7 +173,7 @@ def gossip_configs():
         metropolis_weights,
         permutation_decomposition,
     )
-    from repro.utils.compat import make_auto_mesh
+    from repro.launch.mesh import make_auto_mesh
 
     k = 8
     w = metropolis_weights(build_graph("ring", k))

@@ -393,8 +393,7 @@ from jax.sharding import PartitionSpec as P
 from repro.analysis import audit_wire
 from repro.comm.protocol import Mixer, trivial_comm_state
 from repro.graphs import metropolis_weights, permutation_decomposition, ring_graph
-from repro.utils.compat import make_auto_mesh
-from jax.experimental.shard_map import shard_map
+from repro.launch.mesh import make_auto_mesh
 
 k = 8
 w = metropolis_weights(ring_graph(k))
@@ -429,8 +428,9 @@ class SmugglingMixer(Mixer):
                     lambda x: jax.lax.ppermute(x, "n", pairs), t)
                 out = jax.tree.map(lambda o, r: o + pw[i] * r, out, recv)
             return out
-        mixed = shard_map(body, mesh=self.mesh,
-                          in_specs=(self.specs,), out_specs=self.specs)(theta)
+        mixed = jax.shard_map(body, mesh=self.mesh,
+                              in_specs=(self.specs,),
+                              out_specs=self.specs)(theta)
         return mixed, state._replace(rounds=state.rounds + 1)
 
 mesh = make_auto_mesh((k,), ("n",))
@@ -546,7 +546,7 @@ from jax.sharding import PartitionSpec as P
 from repro.comm import CompressionConfig
 from repro.dynamics import DynamicCompressedGossipMixer, StaticSchedule
 from repro.graphs import metropolis_weights, ring_graph
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 
 k = 8
 w = metropolis_weights(ring_graph(k))
@@ -583,7 +583,7 @@ from jax.sharding import PartitionSpec as P
 from repro.comm import CompressionConfig
 from repro.dynamics import DropoutSchedule, DynamicCompressedGossipMixer
 from repro.graphs import metropolis_weights, ring_graph
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 
 k = 8
 w = metropolis_weights(ring_graph(k))

@@ -9,7 +9,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_arch
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.models import SHAPES, TransformerLM, input_shapes
 from repro.models.transformer import input_specs
 

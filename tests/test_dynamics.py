@@ -171,7 +171,7 @@ from repro.core.consensus import GossipMixer
 from repro.dynamics import (DynamicGossipMixer, StaticSchedule,
                             DropoutSchedule, FaultConfig)
 from repro.graphs import metropolis_weights, ring_graph, permutation_decomposition
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 
 k = 8
 w = metropolis_weights(ring_graph(k))
@@ -503,7 +503,7 @@ from repro.dynamics import (DynamicCompressedDenseMixer,
                             DynamicCompressedGossipMixer, DynamicGossipMixer,
                             DropoutSchedule, StaticSchedule)
 from repro.graphs import metropolis_weights, ring_graph, permutation_decomposition
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 
 k = 8
 w = metropolis_weights(ring_graph(k))
@@ -607,7 +607,7 @@ from repro.comm import CompressionConfig
 from repro.core import TrainerSpec
 from repro.dynamics import DynamicGossipMixer, DropoutSchedule
 from repro.graphs import metropolis_weights, ring_graph
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 
 k = 8
 w = metropolis_weights(ring_graph(k))
@@ -667,7 +667,7 @@ from repro.comm import CompressionConfig
 from repro.dynamics import (DynamicCompressedGossipMixer, DynamicGossipMixer,
                             DropoutSchedule, StaticSchedule)
 from repro.graphs import metropolis_weights, ring_graph
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 
 k = 8
 w = metropolis_weights(ring_graph(k))
@@ -738,6 +738,7 @@ def test_masked_innovation_compress_matches_ref():
     keys — and an all-ones mask is bit-identical to the unmasked encode."""
     from repro.comm.compressors import (
         KernelInt8Quantizer, _uniform_rows, per_node_keys)
+    from repro.kernels.quant_gossip.kernel import from_tiles
     from repro.kernels.quant_gossip.ref import (
         masked_dequant_accumulate_ref, masked_quantize_blockwise_ref)
 
@@ -751,7 +752,9 @@ def test_masked_innovation_compress_matches_ref():
     q, s = comp.compress_masked(delta, keys, mask)
     u = _uniform_rows(keys, d)
     qr, sr = masked_quantize_blockwise_ref(delta, u, mask)
-    np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
+    # the payload keeps the kernel's tile view; (K, D) is its row order
+    np.testing.assert_array_equal(np.asarray(from_tiles(q, k, d)),
+                                  np.asarray(qr))
     np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
     # masked senders emit nothing, so their θ̂ increment dequantizes to 0
     m = np.asarray(mask)
@@ -766,7 +769,7 @@ def test_masked_innovation_compress_matches_ref():
     acc = jnp.asarray(rng.normal(size=(k, d)), jnp.float32)
     wgt = jnp.linspace(0.1, 0.4, k)
     out = comp.accumulate_masked(acc, (q, s), wgt[:, None], mask)
-    ref = masked_dequant_accumulate_ref(acc, q, s, wgt, mask)
+    ref = masked_dequant_accumulate_ref(acc, qr, sr, wgt, mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(np.asarray(out)[m == 0],
@@ -785,7 +788,7 @@ from jax.sharding import PartitionSpec as P
 from repro.comm import CompressionConfig
 from repro.dynamics import DynamicCompressedGossipMixer, DropoutSchedule
 from repro.graphs import metropolis_weights, ring_graph
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 
 k = 8
 w = metropolis_weights(ring_graph(k))
@@ -831,7 +834,7 @@ from repro.comm import CompressionConfig
 from repro.dynamics import (DynamicCompressedGossipMixer, DropoutSchedule,
                             LocalUpdateMixer)
 from repro.graphs import metropolis_weights, ring_graph
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 
 k = 8
 w = metropolis_weights(ring_graph(k))
@@ -892,14 +895,14 @@ def test_zero_recompiles_across_dynamic_rounds():
 
 @pytest.mark.parametrize("k,d,block_d", [(4, 256, 64), (3, 1000, 1000)])
 def test_masked_quantize_kernel_matches_ref(k, d, block_d):
-    from repro.kernels.quant_gossip.ops import masked_quantize_blockwise
+    from repro.kernels.quant_gossip.ops import quantize_blockwise
     from repro.kernels.quant_gossip.ref import masked_quantize_blockwise_ref
 
     x = jax.random.normal(jax.random.PRNGKey(k * d), (k, d), jnp.float32)
     u = jax.random.uniform(jax.random.PRNGKey(1), (k, d), jnp.float32)
     mask = jnp.asarray(np.arange(k) % 2, jnp.float32)
-    qk, sk = masked_quantize_blockwise(x, u, mask, block_d=block_d,
-                                       interpret=True, use_kernel=True)
+    qk, sk = quantize_blockwise(x, u, mask=mask, block_d=block_d,
+                                interpret=True, use_kernel=True)
     qr, sr = masked_quantize_blockwise_ref(x, u, mask, block_d=block_d)
     np.testing.assert_array_equal(np.asarray(qk), np.asarray(qr))
     np.testing.assert_allclose(np.asarray(sk), np.asarray(sr), rtol=1e-6)
@@ -912,7 +915,7 @@ def test_masked_quantize_kernel_matches_ref(k, d, block_d):
 @pytest.mark.parametrize("k,d,block_d", [(4, 256, 64), (2, 1000, 1000)])
 def test_masked_dequant_accumulate_matches_ref_and_passthrough(k, d, block_d):
     from repro.kernels.quant_gossip.ops import (
-        masked_dequant_accumulate, quantize_blockwise)
+        dequant_accumulate, quantize_blockwise)
     from repro.kernels.quant_gossip.ref import masked_dequant_accumulate_ref
 
     x = jax.random.normal(jax.random.PRNGKey(0), (k, d), jnp.float32)
@@ -922,8 +925,8 @@ def test_masked_dequant_accumulate_matches_ref_and_passthrough(k, d, block_d):
     mask = jnp.asarray(np.arange(k) % 2, jnp.float32)
     q, s = quantize_blockwise(x, u, block_d=block_d, interpret=True,
                               use_kernel=True)
-    out_k = masked_dequant_accumulate(acc, q, s, w, mask, interpret=True,
-                                      use_kernel=True)
+    out_k = dequant_accumulate(acc, q, s, w, mask, interpret=True,
+                               use_kernel=True)
     out_r = masked_dequant_accumulate_ref(acc, q, s, w, mask)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=1e-5, atol=1e-6)

@@ -217,9 +217,36 @@ def test_engine_matches_batch1_greedy_generate():
     assert admit_progs == {"serve_admit_s6", "serve_admit_s10"}
 
 
+#: int8 KV rounding moves this model's logits by up to ~0.045 over a
+#: generation; a greedy token may differ from the f32 argmax only inside it
+INT8_LOGIT_TOL = 0.05
+
+
+def _teacher_forced(model, params, reqs, tokens, length=24):
+    """Per request, from one f32 forward of prompt + generated tokens: the
+    gap (top logit − chosen token's logit) and the top-2 margin at every
+    generated position."""
+    toks = np.zeros((len(reqs), length), np.int32)
+    for i, r in enumerate(reqs):
+        seq = np.concatenate([r.prompt, tokens[r.rid]])
+        toks[i, :len(seq)] = seq
+    logits = np.asarray(jax.jit(model.logits_all)(
+        params, {"tokens": jnp.asarray(toks)}))
+    out = {}
+    for i, r in enumerate(reqs):
+        gen = tokens[r.rid]
+        rows = logits[i, r.s0 - 1:r.s0 - 1 + len(gen)]
+        top2 = np.sort(rows, axis=-1)[:, -2:]
+        gap = rows.max(-1) - rows[np.arange(len(gen)), gen]
+        out[r.rid] = (gap, top2[:, 1] - top2[:, 0])
+    return out
+
+
 def test_engine_int8_kv_parity():
-    """int8 KV pool reproduces f32 greedy tokens on short generations
-    (longer ones may legitimately drift on near-tie logits)."""
+    """int8 KV pool reproduces f32 greedy tokens wherever the f32 choice is
+    not a near tie, and every int8 token is the f32 teacher-forced argmax
+    within the int8 rounding error (a near tie may flip, and the rest of
+    that generation then follows its own, equally greedy, prefix)."""
     cfg = get_arch("qwen2_0_5b", smoke=True)
     model = TransformerLM(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -227,9 +254,21 @@ def test_engine_int8_kv_parity():
 
     _, f32 = _engine_tokens(model, params, reqs, quantized=False)
     _, int8 = _engine_tokens(model, params, reqs, quantized=True)
-    for rid in f32:
-        np.testing.assert_array_equal(int8[rid], f32[rid],
-                                      err_msg=f"rid {rid}")
+    ref_f32 = _teacher_forced(model, params, reqs, f32)
+    ref_int8 = _teacher_forced(model, params, reqs, int8)
+    identical = 0
+    for r in reqs:
+        gap_f32, margin = ref_f32[r.rid]
+        np.testing.assert_allclose(gap_f32, 0.0, atol=1e-5,
+                                   err_msg=f"f32 rid {r.rid}")
+        gap_int8, _ = ref_int8[r.rid]
+        assert np.all(gap_int8 <= INT8_LOGIT_TOL), (r.rid, gap_int8)
+        differ = np.nonzero(int8[r.rid] != f32[r.rid])[0]
+        if differ.size == 0:
+            identical += 1
+        else:  # the first difference is a flipped f32 near tie
+            assert margin[differ[0]] <= INT8_LOGIT_TOL, (r.rid, margin)
+    assert identical >= len(reqs) - 1, identical
 
 
 def test_engine_rejects_oversized_request():

@@ -24,7 +24,7 @@ def test_gossip_equals_dense_mixing_on_mesh():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.graphs import ring_graph, erdos_renyi_graph, metropolis_weights, \
     permutation_decomposition
 from repro.core import make_dense_mixer, make_gossip_mixer
@@ -47,12 +47,43 @@ print("OK")
 """)
 
 
+def test_node_state_follows_param_sharding():
+    """Per-node mixer state built from node-sharded parameters stays one
+    node per device: the error-feedback copies (θ̂, s) and the gradient
+    tracker.  Built on the default device, K full-width copies of them
+    exhausted one chip's memory."""
+    _run("""
+import jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.comm import CompressionConfig
+from repro.core import make_dense_mixer, make_gossip_mixer
+from repro.dynamics import LocalUpdateMixer
+from repro.graphs import ring_graph, metropolis_weights, permutation_decomposition
+from repro.launch.mesh import make_auto_mesh
+k = 4
+mesh = make_auto_mesh((k,), ("node",))
+node = NamedSharding(mesh, P("node"))
+params = jax.device_put({"w": jnp.ones((k, 8, 16)), "b": jnp.ones((k, 16))},
+                        node)
+specs = jax.tree.map(lambda _: P("node"), params)
+w = metropolis_weights(ring_graph(k))
+cc = CompressionConfig(kind="int8", seed=0)
+ef = make_gossip_mixer(permutation_decomposition(w), mesh, "node", specs, cc)
+st = ef.init_state(params)
+gt = LocalUpdateMixer(make_dense_mixer(w), 2, gradient_tracking=True)
+corr, _ = gt.init_state(params).track
+for leaf in jax.tree.leaves((st.hat, st.hat_mix, corr)):
+    assert leaf.sharding.is_equivalent_to(node, leaf.ndim), leaf.sharding
+print("OK")
+""", devices=4)
+
+
 def test_gossip_multiaxis_node_dimension():
     """Node axis spanning ('pod','data') — the multi-pod configuration."""
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.graphs import ring_graph, metropolis_weights, permutation_decomposition
 from repro.core import make_dense_mixer, make_gossip_mixer
 mesh = make_auto_mesh((2, 4), ("pod", "data"))
@@ -76,7 +107,7 @@ def test_sharded_drdsgd_step_matches_single_device():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.core import RobustConfig, TrainStepConfig, build_train_step, \
     make_dense_mixer
 from repro.core.drdsgd import init_state, replicate_params
@@ -128,7 +159,7 @@ def test_hierarchical_mixer_with_replica_axis():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.graphs import ring_graph, metropolis_weights, permutation_decomposition
 from repro.core import make_dense_mixer, make_hierarchical_mixer
 mesh = make_auto_mesh((4, 2), ("node", "replica"))
@@ -152,7 +183,7 @@ def test_smoke_arch_trains_on_mesh():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.configs import get_arch
 from repro.core import RobustConfig, TrainStepConfig, build_train_step, \
     make_dense_mixer
