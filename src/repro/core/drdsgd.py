@@ -226,38 +226,41 @@ def build_train_step(
         if step_checks is not None:
             with scope("obs:sanitize"):
                 step_checks(mixer, state.comm, mixed, comm)
-        # estimated wire bytes this step (static estimate, gated on mixing;
-        # traced wire_bits/8 when a schedule makes the rate dynamic)
-        if traced_wire:
-            comm_bytes = jnp.where(is_mix_step, comm.wire_bits / 8.0, 0.0)
-        else:
-            # bytes_per_round is shape-only host math on static mixers
-            # (traced_wire is False here): no tracer reaches the float()
-            round_bytes = float(mixer.bytes_per_round(state.params))  # repro: noqa[RPR002]
-            if cfg.mix_every == 1:
-                comm_bytes = jnp.float32(round_bytes)
+        # the step's reported numbers: loss reductions, the consensus
+        # discrepancy, wire bytes
+        with scope("obs:metrics"):
+            # estimated wire bytes this step (static estimate, gated on mixing;
+            # traced wire_bits/8 when a schedule makes the rate dynamic)
+            if traced_wire:
+                comm_bytes = jnp.where(is_mix_step, comm.wire_bits / 8.0, 0.0)
             else:
-                comm_bytes = jnp.where(is_mix_step, round_bytes, 0.0)
-        cm = comm.metrics
-        metrics = {
-            "comm_bytes": comm_bytes,
-            "loss_mean": jnp.mean(losses),
-            "loss_worst": jnp.max(losses),
-            "loss_std": jnp.std(losses),
-            "robust_objective": robust_objective(losses, cfg.robust),
-            "scale_mean": jnp.mean(scale),
-            "scale_max": jnp.max(scale),
-            "lambda_max": jnp.max(lam),
-            # wire_bits is "bits injected by the last round" — gate on the
-            # mix predicate so off-steps (mix_every > 1) report 0, not the
-            # stale value the lax.cond pass-through branch carries
-            "wire_bits": jnp.where(is_mix_step, cm.wire_bits, 0.0),
-            "ef_residual_norm": cm.res_norm,
-        }
-        if cfg.metrics_disagreement:
-            metrics["disagreement"] = tree_node_disagreement(mixed)
-        for k, v in aux.items():
-            metrics[f"aux_{k}"] = jnp.mean(v)
+                # bytes_per_round is shape-only host math on static mixers
+                # (traced_wire is False here): no tracer reaches the float()
+                round_bytes = float(mixer.bytes_per_round(state.params))  # repro: noqa[RPR002]
+                if cfg.mix_every == 1:
+                    comm_bytes = jnp.float32(round_bytes)
+                else:
+                    comm_bytes = jnp.where(is_mix_step, round_bytes, 0.0)
+            cm = comm.metrics
+            metrics = {
+                "comm_bytes": comm_bytes,
+                "loss_mean": jnp.mean(losses),
+                "loss_worst": jnp.max(losses),
+                "loss_std": jnp.std(losses),
+                "robust_objective": robust_objective(losses, cfg.robust),
+                "scale_mean": jnp.mean(scale),
+                "scale_max": jnp.max(scale),
+                "lambda_max": jnp.max(lam),
+                # wire_bits is "bits injected by the last round" — gate on the
+                # mix predicate so off-steps (mix_every > 1) report 0, not the
+                # stale value the lax.cond pass-through branch carries
+                "wire_bits": jnp.where(is_mix_step, cm.wire_bits, 0.0),
+                "ef_residual_norm": cm.res_norm,
+            }
+            if cfg.metrics_disagreement:
+                metrics["disagreement"] = tree_node_disagreement(mixed)
+            for k, v in aux.items():
+                metrics[f"aux_{k}"] = jnp.mean(v)
         if obs is not None:
             # pack the step's record for the host sink.  The per-node
             # vectors (the paper's trajectory axes) and the in-jit histogram

@@ -298,17 +298,20 @@ def paged_attention_decode(p, x, cfg: ArchConfig, *, kind: str, pool, table,
     with jax.named_scope("obs:serve/kv_write"):
         pool = paged_kv_write(pool, k[:, 0], v[:, 0], page_ids, slot % ps,
                               scale_block=scale_block)
-    ck, cv = paged_kv_gather(pool, table, t, pool["k"].dtype
-                             if "k_scale" not in pool else cfg.compute_dtype)
+    with jax.named_scope("obs:serve/kv_gather"):
+        ck, cv = paged_kv_gather(pool, table, t, pool["k"].dtype
+                                 if "k_scale" not in pool
+                                 else cfg.compute_dtype)
 
-    idx = jnp.arange(t)
-    valid = (idx[None, :] <= pos[:, None]) | (pos[:, None] >= t)  # (B, t)
-    scale = 1.0 / (hd ** 0.5)
-    qh = q.reshape(b, 1, kvh, g, hd)
-    sc = _scores(qh, ck, scale, cfg.attn_softcap)             # (B,KV,G,1,T)
-    sc = jnp.where(valid[:, None, None, None, :], sc, -1e30)
-    att = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bkgqs,bskh->bqkgh", att, cv.astype(jnp.float32))
+    with jax.named_scope("obs:serve/attend"):
+        idx = jnp.arange(t)
+        valid = (idx[None, :] <= pos[:, None]) | (pos[:, None] >= t)  # (B, t)
+        scale = 1.0 / (hd ** 0.5)
+        qh = q.reshape(b, 1, kvh, g, hd)
+        sc = _scores(qh, ck, scale, cfg.attn_softcap)         # (B,KV,G,1,T)
+        sc = jnp.where(valid[:, None, None, None, :], sc, -1e30)
+        att = jax.nn.softmax(sc, axis=-1)
+        out = jnp.einsum("bkgqs,bskh->bqkgh", att, cv.astype(jnp.float32))
     out = out.reshape(b, 1, h, hd).astype(x.dtype)
     proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(out.dtype))
     return proj, pool

@@ -10,9 +10,12 @@ Three pieces, each usable alone:
   bit-exact and donation-preserving; console lines are formatters over
   the same records, so printed fields cannot drift from the persisted
   ones.
-* **Profiler scopes** (:mod:`repro.obs.profiler`): ``obs:...`` named
-  scopes on the gradient / DR-weighting / consensus / kernel phases, a
-  wall-clock :class:`PhaseTimer` rolled up per ``run_segments`` chunk, and
+* **Profiler scopes and host spans** (:mod:`repro.obs.profiler`):
+  ``obs:...`` named scopes on the gradient / DR-weighting / consensus /
+  kernel phases and the serving engine's decode program; host spans
+  (:func:`host_scope`) on the profiler timeline and in an in-memory ring
+  the process reads back (:func:`spans`), with counters as attributes; a
+  wall-clock :class:`PhaseTimer` rolled up per ``run_segments`` chunk; and
   a ``--profile`` perfetto-trace dump.
 * **Recompile watchdog** (:mod:`repro.obs.watchdog`): jit-cache snapshots
   (:class:`RecompileWatchdog`) and a global compile counter
@@ -35,10 +38,16 @@ Three pieces, each usable alone:
 from repro.obs.hist import TRAIN_HISTOGRAMS, HistSpec, hist_counts
 from repro.obs.profiler import (
     PhaseTimer,
+    Span,
+    SpanRing,
+    clear_spans,
+    dropped_spans,
     find_perfetto_trace,
     host_scope,
     profile,
     scope,
+    span_wall_ns,
+    spans,
 )
 from repro.obs.report import (
     load_records,
@@ -81,6 +90,8 @@ __all__ = [
     "MetricsSink", "format_train", "format_eval", "format_perf",
     "format_meta", "format_record", "format_serve", "format_trace",
     "PhaseTimer", "scope", "host_scope", "profile", "find_perfetto_trace",
+    "Span", "SpanRing", "spans", "clear_spans", "dropped_spans",
+    "span_wall_ns",
     "RecompileWatchdog", "RecompileError", "CompileCounter",
     "expect_compiles", "jit_cache_size",
     "HistSpec", "hist_counts", "TRAIN_HISTOGRAMS",

@@ -145,8 +145,8 @@ def to_chrome_events(records, *, t0_us: float = 0.0,
     map to instant ("i") events — plus one complete ("X") span per finished
     request covering admit → done on its slot's track.  Trainer round
     events have no wall clock; they land on an index ruler of
-    ``_STEP_US`` µs per optimizer step.  ``t0_us`` offsets everything
-    (used to align onto an XLA profile's epoch timestamps).
+    ``_STEP_US`` µs per optimizer step.  ``t0_us`` offsets both (the run's
+    start on the output's clock).
     """
     out = []
     for r in records:
@@ -174,13 +174,20 @@ def to_chrome_events(records, *, t0_us: float = 0.0,
     return out
 
 
+def _json_default(o):
+    # numpy scalars (a slot index the host loop took from np.nonzero)
+    if isinstance(o, np.generic):
+        return o.item()
+    raise TypeError(f"{type(o).__name__} is not JSON serializable")
+
+
 def _write_trace_json(obj: dict, path: str) -> str:
     if path.endswith(".gz"):
         with gzip.open(path, "wt") as f:
-            json.dump(obj, f)
+            json.dump(obj, f, default=_json_default)
     else:
         with open(path, "w") as f:
-            json.dump(obj, f)
+            json.dump(obj, f, default=_json_default)
     return path
 
 
@@ -201,17 +208,28 @@ def export_chrome_trace(records, path: str) -> str:
         path)
 
 
+#: the span :meth:`repro.serve.ServeEngine.run` opens around each run; the
+#: lifecycle records' ``t_s`` count from its start
+RUN_SPAN = "obs:serve/run"
+
+
 def merge_with_profile(records, profile_path: str, out_path: str) -> str:
     """Merge ``trace`` records onto an XLA perfetto trace (``--profile``).
 
     Reads the trace-event JSON(.gz) ``jax.profiler.trace`` dumped (find it
-    with :func:`repro.obs.find_perfetto_trace`), offsets our run-relative
-    events to the profile's earliest timestamp, appends them under their
-    own pid, and writes ``out_path`` — one timeline with device phases and
-    host-side request/round churn.
+    with :func:`repro.obs.find_perfetto_trace`).  Its times count from the
+    profile's start, and it holds the profiler's annotation of each host
+    span (:func:`repro.obs.host_scope`).  The serve lifecycle records count
+    from their run's start, so they land from the start of the profile's
+    last ``obs:serve/run`` annotation (trainer records, and a profile with
+    no run, from the profile's start).  Appends them under their own pid
+    and writes ``out_path`` — one timeline with device phases and host-side
+    request/round churn.  Needs nothing of the process that ran the work.
     """
     base = _read_trace_json(profile_path)
     evs = base.get("traceEvents", [])
-    t0 = min((float(e["ts"]) for e in evs if "ts" in e), default=0.0)
-    base["traceEvents"] = evs + to_chrome_events(records, t0_us=t0)
+    t0_us = max((float(e["ts"]) for e in evs
+                 if e.get("name") == RUN_SPAN and e.get("ph") == "X"),
+                default=0.0)
+    base["traceEvents"] = evs + to_chrome_events(records, t0_us=t0_us)
     return _write_trace_json(base, out_path)

@@ -43,6 +43,33 @@ with slot ids, page reservations and run-relative timestamps.  The
 source of latency truth: :func:`repro.obs.report.serve_latency_summary`
 derives the bench and CLI summaries from these records.  The compiled
 decode step is untouched — zero device callbacks.
+
+Host spans (:func:`repro.obs.host_scope`, read back with
+:func:`repro.obs.spans`) time the layers of the host loop, and carry its
+counters as attributes:
+
+  ========================  ==================================================
+  span                      what it covers (attributes)
+  ========================  ==================================================
+  ``obs:serve/run``         one :meth:`ServeEngine.run` (clock, requests)
+  ``obs:serve/step``        one decode step, a ``StepTraceAnnotation`` (step,
+                            active, kv_live_tokens, kv_gathered_tokens)
+  ``obs:serve/dispatch``    the decode program's call
+  ``obs:serve/readback``    the (2, B) readback, the step's host sync
+  ``obs:serve/emit``        per-slot appends, lifecycle records, releases
+  ``obs:serve/admit``       one admission (rid, slot, prompt_tokens, pages)
+  ``obs:serve/slot_write``  block-table rows and the carry's slot writes
+  ``obs:serve/admit_call``  the prefill program's call (or the slot clear)
+  ``obs:serve/admit_wait``  the wait for the prefill
+  ========================  ==================================================
+
+``kv_gathered_tokens`` is what the step program reads of the pools,
+``max_batch`` times the ring length summed over the paged kinds;
+``kv_live_tokens`` is the live part of it: over the slots active when the
+step is dispatched and over the paged kinds, the slot's context (``s0`` plus
+the tokens emitted so far) capped at the kind's ring length.  Inside the decode program, ``obs:serve/kv_gather`` and
+``obs:serve/attend`` (in :mod:`repro.models.attention`) split the paged
+read from the attention over it.
 """
 
 from __future__ import annotations
@@ -56,7 +83,7 @@ import numpy as np
 
 from repro.models import TransformerLM
 from repro.models.attention import paged_kv_len
-from repro.obs import MetricsSink, RecompileWatchdog
+from repro.obs import MetricsSink, RecompileWatchdog, host_scope
 from repro.serve.pool import TRASH_PAGE
 from repro.serve.prefill import clear_slot_state, place_paged_prefill
 from repro.serve.sampling import sample_tokens
@@ -138,6 +165,7 @@ class ServeEngine:
         self.ring_len = {k: paged_kv_len(cfg, k, max_len) for k in self.kinds}
         self.n_blocks = {k: -(-t // page_size)
                          for k, t in self.ring_len.items()}
+        self._kv_gathered = max_batch * sum(self.ring_len.values())
         if num_pages is None:
             num_pages = {k: 1 + max_batch * nb
                          for k, nb in self.n_blocks.items()}
@@ -168,8 +196,9 @@ class ServeEngine:
         self._steps = 0
         self._admitted = 0
         self._completed = 0
-        # compile/steady split: a program's first invocation is charged to
-        # the compile bucket, everything after is steady state
+        # compile/steady split from the host spans: a program's first
+        # invocation is charged to the compile bucket, everything after is
+        # steady state
         self._decode_compiled = False
         self._decode_compile_s = 0.0
         self._decode_steady_s = 0.0
@@ -181,10 +210,12 @@ class ServeEngine:
         self._prefill_tokens = 0
 
         self._step_fn = jax.jit(self._make_step(), donate_argnums=(1,))
-        self._clear_fn = jax.jit(
-            lambda params, cache, slot: clear_slot_state(
-                self.model, cache, slot),
-            donate_argnums=(1,))
+
+        def clear(params, cache, slot):
+            with jax.named_scope("obs:serve/clear"):
+                return clear_slot_state(self.model, cache, slot)
+
+        self._clear_fn = jax.jit(clear, donate_argnums=(1,))
         self._admit_fns: dict[int, object] = {}
         self.watchdog = watchdog or RecompileWatchdog(label="serve engine")
         self.watchdog.track("serve_decode_step", self._step_fn, allowed=1)
@@ -195,24 +226,27 @@ class ServeEngine:
     def _make_step(self):
         model, max_len, eos = self.model, self.max_len, self.eos
 
+        # the program keeps the name ``step``: its module is ``jit_step``
         def step(params, carry, tables):
             pos, active = carry["pos"], carry["active"]
-            sub = jax.random.fold_in(carry["key"], carry["step"])
+            with jax.named_scope("obs:serve/sample"):
+                sub = jax.random.fold_in(carry["key"], carry["step"])
             with jax.named_scope("obs:serve/decode"):
                 logits, cache = model.paged_decode_step(
                     params, carry["tok"], pos, carry["cache"], tables,
                     max_len=max_len)
             with jax.named_scope("obs:serve/sample"):
                 nxt = sample_tokens(logits, sub, carry["temp"])
-            done = (nxt == eos) | (pos >= carry["limit"])
-            still = active & ~done
-            out = jnp.stack([jnp.where(active, nxt, -1),
-                             still.astype(jnp.int32)])
-            carry = dict(
-                carry, cache=cache, active=still,
-                tok=jnp.where(active, nxt, carry["tok"][:, 0])[:, None],
-                pos=jnp.where(active, pos + 1, pos),
-                step=carry["step"] + 1)
+            with jax.named_scope("obs:serve/carry"):
+                done = (nxt == eos) | (pos >= carry["limit"])
+                still = active & ~done
+                out = jnp.stack([jnp.where(active, nxt, -1),
+                                 still.astype(jnp.int32)])
+                carry = dict(
+                    carry, cache=cache, active=still,
+                    tok=jnp.where(active, nxt, carry["tok"][:, 0])[:, None],
+                    pos=jnp.where(active, pos + 1, pos),
+                    step=carry["step"] + 1)
             return carry, out
 
         return step
@@ -226,8 +260,9 @@ class ServeEngine:
         def admit(params, prompt, cache, rows, slot):
             with jax.named_scope("obs:serve/prefill"):
                 _, pf = model.prefill(params, {"tokens": prompt})
-            return place_paged_prefill(model, pf, cache, rows, slot, s0,
-                                       max_len)
+            with jax.named_scope("obs:serve/place"):
+                return place_paged_prefill(model, pf, cache, rows, slot, s0,
+                                           max_len)
 
         fn = jax.jit(admit, donate_argnums=(2,))
         self._admit_fns[s0] = fn
@@ -239,64 +274,86 @@ class ServeEngine:
     def _admit(self, adm: Admission, now: float) -> None:
         req, slot = adm.req, adm.slot
         s0 = req.s0
-        rows = {}
-        for kind in self._tables:
-            row = np.full((self.n_blocks[kind],), TRASH_PAGE, np.int32)
-            pages = adm.pages[kind]
-            row[:len(pages)] = pages
-            rows[kind] = jnp.asarray(row)
-            self._tables[kind] = self._tables[kind].at[slot].set(rows[kind])
-        c = self._carry
-        t0 = time.monotonic()
-        if s0 == 1:
-            # nothing to prefill, but the slot's recurrent rows still hold
-            # the previous request's state
-            cache = self._clear_fn(self.params, c["cache"], jnp.int32(slot))
-        else:
-            fn = self._admit_fn(s0)
-            prompt = jnp.asarray(req.prompt[None, :s0 - 1])
-            cache = fn(self.params, prompt, c["cache"], rows, jnp.int32(slot))
-            jax.block_until_ready(jax.tree.leaves(cache)[0])
-        dt = time.monotonic() - t0
-        if s0 in self._prefill_seen or s0 == 1:
-            self._prefill_steady_s += dt
-            self._prefill_tokens += s0 - 1
-        else:
-            self._prefill_seen.add(s0)
-            self._prefill_compile_s += dt
-
-        # the shared decode step produces the request's FIRST token: its
-        # input is the last prompt token at position s0-1, so TTFT is the
-        # latency of the slot's first decode step
-        self._carry = dict(
-            c, cache=cache,
-            tok=c["tok"].at[slot, 0].set(int(req.prompt[s0 - 1])),
-            pos=c["pos"].at[slot].set(s0 - 1),
-            active=c["active"].at[slot].set(True),
-            limit=c["limit"].at[slot].set(s0 + req.max_new - 2),
-            temp=c["temp"].at[slot].set(req.temperature))
-        self._active_np[slot] = True
-        self._slot_tokens[slot] = []
         pages_total = sum(len(p) for p in adm.pages.values())
-        meta = dict(req=req, t_admit=now, t_first=None, pages=pages_total)
-        self._slot_meta[slot] = meta
-        self._admitted += 1
-        self._trace("admitted", rid=req.rid, cls=req.cls, slot=slot,
-                    pages=pages_total, t_s=now)
-        self._trace("prefill", rid=req.rid, slot=slot, tokens=s0 - 1,
-                    dur_s=dt, t_s=now + dt)
+        with host_scope("obs:serve/admit", rid=req.rid, slot=int(slot),
+                        prompt_tokens=s0 - 1, pages=pages_total):
+            with host_scope("obs:serve/slot_write"):
+                rows = {}
+                for kind in self._tables:
+                    row = np.full((self.n_blocks[kind],), TRASH_PAGE,
+                                  np.int32)
+                    pages = adm.pages[kind]
+                    row[:len(pages)] = pages
+                    rows[kind] = jnp.asarray(row)
+                    self._tables[kind] = self._tables[kind].at[slot].set(
+                        rows[kind])
+            c = self._carry
+            with host_scope("obs:serve/admit_call") as call:
+                if s0 == 1:
+                    # nothing to prefill, but the slot's recurrent rows
+                    # still hold the previous request's state
+                    cache = self._clear_fn(self.params, c["cache"],
+                                           jnp.int32(slot))
+                else:
+                    fn = self._admit_fn(s0)
+                    prompt = jnp.asarray(req.prompt[None, :s0 - 1])
+                    cache = fn(self.params, prompt, c["cache"], rows,
+                               jnp.int32(slot))
+            dt = call.seconds
+            if s0 != 1:
+                with host_scope("obs:serve/admit_wait") as wait:
+                    jax.block_until_ready(jax.tree.leaves(cache)[0])
+                dt += wait.seconds
+            if s0 in self._prefill_seen or s0 == 1:
+                self._prefill_steady_s += dt
+                self._prefill_tokens += s0 - 1
+            else:
+                self._prefill_seen.add(s0)
+                self._prefill_compile_s += dt
+
+            # the shared decode step produces the request's FIRST token: its
+            # input is the last prompt token at position s0-1, so TTFT is
+            # the latency of the slot's first decode step
+            with host_scope("obs:serve/slot_write"):
+                self._carry = dict(
+                    c, cache=cache,
+                    tok=c["tok"].at[slot, 0].set(int(req.prompt[s0 - 1])),
+                    pos=c["pos"].at[slot].set(s0 - 1),
+                    active=c["active"].at[slot].set(True),
+                    limit=c["limit"].at[slot].set(s0 + req.max_new - 2),
+                    temp=c["temp"].at[slot].set(req.temperature))
+            self._active_np[slot] = True
+            self._slot_tokens[slot] = []
+            meta = dict(req=req, t_admit=now, t_first=None, pages=pages_total)
+            self._slot_meta[slot] = meta
+            self._admitted += 1
+            self._trace("admitted", rid=req.rid, cls=req.cls, slot=slot,
+                        pages=pages_total, t_s=now)
+            self._trace("prefill", rid=req.rid, slot=slot, tokens=s0 - 1,
+                        dur_s=dt, t_s=now + dt)
 
     # -- the decode step ------------------------------------------------------
 
     def _decode_once(self, completions: list, t0: float, clock: str,
                      enqueue_t: dict) -> None:
         was_active = np.nonzero(self._active_np)[0]
-        ts = time.monotonic()
-        self._carry, out = self._step_fn(self.params, self._carry,
-                                         self._tables)
-        out = np.asarray(out)                       # the per-step host sync
-        dt = time.monotonic() - ts
-        now = time.monotonic() - t0
+        # each paged kind's ring holds at most its length of a slot's context
+        ctx = [self._slot_meta[s]["req"].s0 + len(self._slot_tokens[s])
+               for s in was_active]
+        live = sum(min(c, t) for t in self.ring_len.values() for c in ctx)
+        with host_scope("obs:serve/step", step=self._steps,
+                        active=len(was_active), kv_live_tokens=live,
+                        kv_gathered_tokens=self._kv_gathered):
+            with host_scope("obs:serve/dispatch") as dispatch:
+                self._carry, out = self._step_fn(self.params, self._carry,
+                                                 self._tables)
+            with host_scope("obs:serve/readback") as readback:
+                out = np.asarray(out)               # the per-step host sync
+            now = time.monotonic() - t0
+            with host_scope("obs:serve/emit"):
+                self._emit(out, was_active, now, completions, clock,
+                           enqueue_t)
+        dt = dispatch.seconds + readback.seconds
         if self._decode_compiled:
             self._decode_steady_s += dt
             self._steady_tokens += len(was_active)
@@ -304,7 +361,14 @@ class ServeEngine:
         else:
             self._decode_compiled = True
             self._decode_compile_s += dt
+        self._steps += 1
+        if self._steps % self.log_every == 0:
+            self._log_serve(step_ms=dt * 1e3)
 
+    def _emit(self, out: np.ndarray, was_active, now: float,
+              completions: list, clock: str, enqueue_t: dict) -> None:
+        """Hand one step's tokens to their slots; finish and release the
+        slots whose request ended."""
         toks, still = out[0], out[1].astype(bool)
         for slot in was_active:
             self._slot_tokens[slot].append(int(toks[slot]))
@@ -337,9 +401,6 @@ class ServeEngine:
                             t_s=now, dur_s=now - meta["t_admit"])
                 self._slot_meta[slot] = None
                 self._completed += 1
-        self._steps += 1
-        if self._steps % self.log_every == 0:
-            self._log_serve(step_ms=dt * 1e3)
 
     def _tables_clear(self, slot: int) -> None:
         # a freed slot must write to the trash page again: its pages are
@@ -360,6 +421,12 @@ class ServeEngine:
         if clock not in ("wall", "steps"):
             raise ValueError(f"clock must be 'wall'|'steps', got {clock!r}")
         order = sorted(trace, key=lambda r: (r.arrival, r.rid))
+        # the lifecycle records' clock starts with this span
+        with host_scope("obs:serve/run", clock=clock, requests=len(trace)):
+            return self._run(order, clock, max_steps)
+
+    def _run(self, order: list[Request], clock: str,
+             max_steps: int | None) -> dict:
         completions: list[Completion] = []
         enqueue_t: dict[int, float] = {}
         t0 = time.monotonic()

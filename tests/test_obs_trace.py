@@ -5,7 +5,6 @@ engine's request-lifecycle trace as the single latency accounting."""
 
 import gzip
 import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -260,29 +259,35 @@ def test_export_chrome_trace_writes_loadable_json(tmp_path, suffix):
 
 
 def test_merge_with_profile_offsets_onto_the_xla_timeline(tmp_path):
-    """Merging must land our run-relative events at the profile's epoch —
-    the file layout mirrors what jax.profiler.trace dumps, so
-    find_perfetto_trace locates it the same way launch/train.py does."""
-    from repro.obs import find_perfetto_trace
+    """Merging must land our run-relative events on the profile's own clock:
+    from the start of the profiler's annotation of the run's
+    ``obs:serve/run`` span.  find_perfetto_trace locates the file
+    jax.profiler.trace dumped, as launch/train.py does."""
+    from repro.obs import find_perfetto_trace, host_scope
 
-    prof_dir = tmp_path / "plugins" / "profile" / "2026_01_01"
-    os.makedirs(prof_dir)
-    t0 = 5_000_000.0
-    xla = [{"name": "xla_run", "ph": "X", "ts": t0, "dur": 10.0,
-            "pid": 1, "tid": 2}]
-    with gzip.open(prof_dir / "host.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": xla}, f)
+    with jax.profiler.trace(str(tmp_path)):
+        jnp.ones(8).block_until_ready()
+        with host_scope("obs:serve/run", clock="wall", requests=1):
+            jnp.ones(8).block_until_ready()
 
     prof = find_perfetto_trace(str(tmp_path))
     assert prof is not None and prof.endswith(".trace.json.gz")
     recs = _mixed_trace_records()
     out = str(tmp_path / "merged.json")
     merge_with_profile(recs, prof, out)
+    with gzip.open(prof, "rt") as f:
+        xla = json.load(f)["traceEvents"]
     with open(out) as f:
         merged = json.load(f)["traceEvents"]
-    assert merged[0] == xla[0]          # the profile's events survive
-    ours = merged[1:]
+    assert merged[:len(xla)] == xla     # the profile's events survive
+    ours = merged[len(xla):]
     assert len(ours) == len(to_chrome_events(recs))
+    annotated = [e["ts"] for e in xla
+                 if e.get("ph") == "X" and e.get("name") == "obs:serve/run"]
+    assert len(annotated) == 1
+    t0 = annotated[0]
+    # not the profile's earliest event: the run starts after other work
+    assert t0 > min(e["ts"] for e in xla if "ts" in e)
     assert all(e["ts"] >= t0 for e in ours)
     queued = next(e for e in ours if e["name"] == "queued")
     assert queued["ts"] == pytest.approx(t0)
