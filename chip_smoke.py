@@ -163,7 +163,8 @@ def serve_phase(model, params, dev, *, seed: int, max_batch: int = 8,
         check(np.all(gap <= tol), (pool, "max gap", float(gap.max())))
         if quantized:
             text = engine._step_fn.lower(
-                params, engine._carry, engine._tables).compile().as_text()
+                engine.params, engine._carry,
+                engine._tables).compile().as_text()
             has = "tpu_custom_call" in text
             log(f"[serve:int8] tpu_custom_call in the decode program: {has}")
             if require_kernel:

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.models import params as pr
 from repro.models.config import ArchConfig
-from repro.models.layers import apply_rope
+from repro.models.layers import apply_rope, weight_einsum
 
 
 def attention_decl(cfg: ArchConfig):
@@ -123,9 +123,9 @@ def chunked_attention(q, k, v, q_positions, k_positions, *, window=None,
 def _project_qkv(p, x, cfg: ArchConfig, positions):
     dt = cfg.compute_dtype
     x = x.astype(dt)
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
+    q = weight_einsum("bsd,dhk->bshk", x, p["wq"], dt)
+    k = weight_einsum("bsd,dhk->bshk", x, p["wk"], dt)
+    v = weight_einsum("bsd,dhk->bshk", x, p["wv"], dt)
     if cfg.qkv_bias:
         q = q + p["bq"].astype(dt)
         k = k + p["bk"].astype(dt)
@@ -150,7 +150,7 @@ def attention_forward(p, x, cfg: ArchConfig, *, kind: str, positions,
         q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk,
     )
     out = out.reshape(b, s, h, hd)
-    proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(out.dtype))
+    proj = weight_einsum("bshk,hkd->bsd", out, p["wo"], out.dtype)
     if return_kv:
         return proj, {"k": k, "v": v}
     return proj
@@ -313,7 +313,7 @@ def paged_attention_decode(p, x, cfg: ArchConfig, *, kind: str, pool, table,
         att = jax.nn.softmax(sc, axis=-1)
         out = jnp.einsum("bkgqs,bskh->bqkgh", att, cv.astype(jnp.float32))
     out = out.reshape(b, 1, h, hd).astype(x.dtype)
-    proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(out.dtype))
+    proj = weight_einsum("bshk,hkd->bsd", out, p["wo"], out.dtype)
     return proj, pool
 
 
@@ -346,5 +346,5 @@ def attention_decode(p, x, cfg: ArchConfig, *, kind: str, cache, pos):
     att = jax.nn.softmax(sc, axis=-1)
     out = jnp.einsum("bkgqs,bskh->bqkgh", att, cv.astype(jnp.float32))
     out = out.reshape(b, 1, h, hd).astype(x.dtype)
-    proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(out.dtype))
+    proj = weight_einsum("bshk,hkd->bsd", out, p["wo"], out.dtype)
     return proj, {"k": ck, "v": cv}

@@ -55,12 +55,27 @@ def glu_mlp_decl(d_model: int, d_ff: int):
     }
 
 
+def weight_einsum(spec: str, x, w, dtype):
+    """``einsum(spec, x, w)`` of an activation and a weight, in ``dtype``.
+
+    A bfloat16 weight under a float32 compute type is read as stored: the
+    activation is rounded to bfloat16 and the product accumulates in
+    float32, which is what a default-precision float32 dot does on the TPU,
+    without a convert of the weight on every call.  Any other weight is cast
+    to ``dtype``.
+    """
+    if w.dtype == jnp.bfloat16 and jnp.dtype(dtype) == jnp.float32:
+        return jnp.einsum(spec, x.astype(jnp.bfloat16), w,
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum(spec, x.astype(dtype), w.astype(dtype))
+
+
 def glu_mlp(p, x, compute_dtype=None):
     dt = compute_dtype or x.dtype
     x = x.astype(dt)
-    gate = jax.nn.silu(jnp.einsum("...d,df->...f", x, p["w_gate"].astype(dt)))
-    up = jnp.einsum("...d,df->...f", x, p["w_up"].astype(dt))
-    return jnp.einsum("...f,fd->...d", gate * up, p["w_down"].astype(dt))
+    gate = jax.nn.silu(weight_einsum("...d,df->...f", x, p["w_gate"], dt))
+    up = weight_einsum("...d,df->...f", x, p["w_up"], dt)
+    return weight_einsum("...f,fd->...d", gate * up, p["w_down"], dt)
 
 
 # -- embeddings ---------------------------------------------------------------

@@ -47,6 +47,7 @@ from repro.models.layers import (
     glu_mlp_decl,
     rmsnorm,
     rmsnorm_decl,
+    weight_einsum,
 )
 from repro.models.moe import moe_decl, moe_ffn
 from repro.models.ssm import (
@@ -186,9 +187,9 @@ class TransformerLM:
     # -- embedding helpers ----------------------------------------------------
 
     def _unembed_table(self, params):
-        if self.cfg.tie_embeddings:
-            return params["embedding"]["table"]
-        return params["lm_head"]["table"]
+        # a tied model's tree may carry a head table of its own (the serving
+        # engine's bfloat16 copy); the embedding gather keeps its table
+        return params.get("lm_head", params["embedding"])["table"]
 
     def _input_embed(self, params, batch, drop_last_token: bool):
         """Returns (x (B,S,D), prefix_len). Stub frontends prepend embeddings."""
@@ -353,8 +354,7 @@ class TransformerLM:
         cfg = self.cfg
         x, _, prefix, caches = self._forward(params, batch, True, False)
         table = self._unembed_table(params)
-        last = jnp.einsum("bd,vd->bv", x[:, -1].astype(jnp.float32),
-                          table.astype(jnp.float32))
+        last = weight_einsum("bd,vd->bv", x[:, -1], table, jnp.float32)
         if cfg.logit_softcap:
             last = cfg.logit_softcap * jnp.tanh(last / cfg.logit_softcap)
         return last, caches
@@ -525,8 +525,7 @@ class TransformerLM:
             new_groups = jax.tree.map(lambda *xs: jnp.stack(xs), *ngs)
         x = rmsnorm(params["final_norm"], x, cfg.rmsnorm_eps)
         table = self._unembed_table(params)
-        logits = jnp.einsum("bd,vd->bv", x[:, 0].astype(jnp.float32),
-                            table.astype(jnp.float32))
+        logits = weight_einsum("bd,vd->bv", x[:, 0], table, jnp.float32)
         if cfg.logit_softcap:
             logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
         return logits, {"head": new_head, "groups": new_groups}

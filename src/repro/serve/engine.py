@@ -44,6 +44,14 @@ source of latency truth: :func:`repro.obs.report.serve_latency_summary`
 derives the bench and CLI summaries from these records.  The compiled
 decode step is untouched — zero device callbacks.
 
+Weights: the engine holds its own parameter tree (:func:`serve_weights`).
+On a TPU at the default matmul precision every weight that only enters a
+dot (attention's ``wq``/``wk``/``wv``/``wo``, the GLU MLPs, the head's
+table) is stored in bfloat16, rounded once here: a default-precision
+float32 dot rounds it so on every call anyway, and
+:func:`repro.models.layers.weight_einsum` reads it as stored.  Elsewhere
+(the CPU, ``highest``) the tree is the caller's float32 one.
+
 Host spans (:func:`repro.obs.host_scope`, read back with
 :func:`repro.obs.spans`) time the layers of the host loop, and carry its
 counters as attributes:
@@ -51,7 +59,8 @@ counters as attributes:
   ========================  ==================================================
   span                      what it covers (attributes)
   ========================  ==================================================
-  ``obs:serve/run``         one :meth:`ServeEngine.run` (clock, requests)
+  ``obs:serve/run``         one :meth:`ServeEngine.run` (clock, requests,
+                            weight_bytes, narrow_weight_bytes)
   ``obs:serve/step``        one decode step, a ``StepTraceAnnotation`` (step,
                             active, kv_live_tokens, kv_gathered_tokens)
   ``obs:serve/dispatch``    the decode program's call
@@ -88,6 +97,118 @@ from repro.serve.pool import TRASH_PAGE
 from repro.serve.prefill import clear_slot_state, place_paged_prefill
 from repro.serve.sampling import sample_tokens
 from repro.serve.scheduler import Admission, Request, Scheduler
+
+
+def narrows_weights(params) -> bool:
+    """Whether :func:`serve_weights` may store the dot weights in bfloat16:
+    only where that is the arithmetic the program does anyway, with every
+    parameter on a TPU (a default-precision float32 dot there is one
+    bfloat16 pass whose operands are rounded to nearest even) and the
+    default matmul precision in effect."""
+    shardings = [getattr(x, "sharding", None)
+                 for x in jax.tree.leaves(params)]
+    on_tpu = bool(shardings) and all(
+        s is not None and all(d.platform == "tpu" for d in s.device_set)
+        for s in shardings)
+    precision = jax.config.jax_default_matmul_precision
+    try:
+        default = precision is None or \
+            jax.lax.Precision(precision) == jax.lax.Precision.DEFAULT
+    except ValueError:                  # an algorithm name, not a precision
+        default = False
+    return on_tpu and default
+
+
+def serve_weights(model: TransformerLM, params):
+    """The parameter tree with every dot-only weight in bfloat16.
+
+    Attention's ``wq``/``wk``/``wv``/``wo``, the GLU MLPs' ``w_gate``/
+    ``w_up``/``w_down`` (shared experts' too) and the head's table become
+    ``astype(bfloat16)``; norms, biases, routers, routed experts, recurrent
+    blocks and the embedding table the gather reads stay as given.  A tied
+    head gets a bfloat16 copy of the embedding table as its own
+    ``lm_head``.  Returns a new tree that shares the leaves it keeps with
+    ``params``, which is not touched.
+    """
+    cfg = model.cfg
+
+    def bf16(x):
+        return x.astype(jnp.bfloat16)
+
+    def layer(p, blk, ffn):
+        p = dict(p)
+        if blk in ("attn", "swa"):
+            p["mix"] = {k: bf16(v) if k in ("wq", "wk", "wv", "wo") else v
+                        for k, v in p["mix"].items()}
+        if ffn == "dense":
+            p["ffn"] = jax.tree.map(bf16, p["ffn"])
+        elif ffn == "moe" and "shared" in p["ffn"]:
+            p["ffn"] = dict(p["ffn"],
+                            shared=jax.tree.map(bf16, p["ffn"]["shared"]))
+        return p
+
+    out = dict(params)
+    if cfg.head_layers():
+        out["head_layers"] = {
+            f"h{i}": layer(params["head_layers"][f"h{i}"], blk, ffn)
+            for i, (blk, ffn) in enumerate(cfg.head_layers())}
+    out["groups"] = {
+        f"l{i}": layer(params["groups"][f"l{i}"], blk, ffn)
+        for i, (blk, ffn) in enumerate(cfg.group_pattern())}
+    out["lm_head"] = {"table": bf16(model._unembed_table(params))}
+    return out
+
+
+def _nbytes(tree, dtype=None) -> int:
+    return sum(x.nbytes for x in jax.tree.leaves(tree)
+               if dtype is None or x.dtype == dtype)
+
+
+def init_carry(model: TransformerLM, max_batch: int, num_pages: dict,
+               page_size: int, *, quantized: bool, seed: int):
+    """The decode step's device-resident carry, every slot empty."""
+    b = max_batch
+    return {
+        "cache": model.init_paged_cache(b, num_pages, page_size,
+                                        quantized=quantized),
+        "tok": jnp.zeros((b, 1), jnp.int32),
+        "pos": jnp.zeros((b,), jnp.int32),
+        "active": jnp.zeros((b,), bool),
+        "limit": jnp.zeros((b,), jnp.int32),
+        "temp": jnp.zeros((b,), jnp.float32),
+        "key": jax.random.PRNGKey(seed),
+        "step": jnp.int32(0),
+    }
+
+
+def make_step(model: TransformerLM, *, max_len: int, eos: int):
+    """The engine's decode step: ``step(params, carry, tables) -> (carry,
+    (2, B) int32 of sampled tokens and the next active mask)``."""
+
+    # the program keeps the name ``step``: its module is ``jit_step``
+    def step(params, carry, tables):
+        pos, active = carry["pos"], carry["active"]
+        with jax.named_scope("obs:serve/sample"):
+            sub = jax.random.fold_in(carry["key"], carry["step"])
+        with jax.named_scope("obs:serve/decode"):
+            logits, cache = model.paged_decode_step(
+                params, carry["tok"], pos, carry["cache"], tables,
+                max_len=max_len)
+        with jax.named_scope("obs:serve/sample"):
+            nxt = sample_tokens(logits, sub, carry["temp"])
+        with jax.named_scope("obs:serve/carry"):
+            done = (nxt == eos) | (pos >= carry["limit"])
+            still = active & ~done
+            out = jnp.stack([jnp.where(active, nxt, -1),
+                             still.astype(jnp.int32)])
+            carry = dict(
+                carry, cache=cache, active=still,
+                tok=jnp.where(active, nxt, carry["tok"][:, 0])[:, None],
+                pos=jnp.where(active, pos + 1, pos),
+                step=carry["step"] + 1)
+        return carry, out
+
+    return step
 
 
 @dataclasses.dataclass
@@ -148,7 +269,12 @@ class ServeEngine:
                 f"ServeEngine needs a token frontend (got {cfg.frontend!r}) "
                 "— prefix-frontend archs have no prompt-only prefill")
         self.model = model
-        self.params = params
+        if narrows_weights(params):
+            self.params = serve_weights(model, params)
+        else:
+            self.params = params
+        self.weight_bytes = _nbytes(self.params)
+        self.narrow_weight_bytes = _nbytes(self.params, jnp.bfloat16)
         self.max_batch = max_batch
         self.max_len = max_len
         self.page_size = page_size
@@ -176,17 +302,8 @@ class ServeEngine:
         b = max_batch
         # device-resident carry: the step advances it in-jit; the host only
         # writes slot rows at admission
-        self._carry = {
-            "cache": model.init_paged_cache(b, self.num_pages, page_size,
-                                            quantized=quantized),
-            "tok": jnp.zeros((b, 1), jnp.int32),
-            "pos": jnp.zeros((b,), jnp.int32),
-            "active": jnp.zeros((b,), bool),
-            "limit": jnp.zeros((b,), jnp.int32),
-            "temp": jnp.zeros((b,), jnp.float32),
-            "key": jax.random.PRNGKey(seed),
-            "step": jnp.int32(0),
-        }
+        self._carry = init_carry(model, b, self.num_pages, page_size,
+                                 quantized=quantized, seed=seed)
         self._tables = {k: jnp.full((b, nb), TRASH_PAGE, jnp.int32)
                         for k, nb in self.n_blocks.items()}
         self._active_np = np.zeros((b,), bool)
@@ -209,7 +326,8 @@ class ServeEngine:
         self._prefill_steady_s = 0.0
         self._prefill_tokens = 0
 
-        self._step_fn = jax.jit(self._make_step(), donate_argnums=(1,))
+        self._step_fn = jax.jit(make_step(model, max_len=max_len, eos=eos),
+                                donate_argnums=(1,))
 
         def clear(params, cache, slot):
             with jax.named_scope("obs:serve/clear"):
@@ -222,34 +340,6 @@ class ServeEngine:
         self.watchdog.track("serve_clear_slot", self._clear_fn, allowed=1)
 
     # -- compiled programs ----------------------------------------------------
-
-    def _make_step(self):
-        model, max_len, eos = self.model, self.max_len, self.eos
-
-        # the program keeps the name ``step``: its module is ``jit_step``
-        def step(params, carry, tables):
-            pos, active = carry["pos"], carry["active"]
-            with jax.named_scope("obs:serve/sample"):
-                sub = jax.random.fold_in(carry["key"], carry["step"])
-            with jax.named_scope("obs:serve/decode"):
-                logits, cache = model.paged_decode_step(
-                    params, carry["tok"], pos, carry["cache"], tables,
-                    max_len=max_len)
-            with jax.named_scope("obs:serve/sample"):
-                nxt = sample_tokens(logits, sub, carry["temp"])
-            with jax.named_scope("obs:serve/carry"):
-                done = (nxt == eos) | (pos >= carry["limit"])
-                still = active & ~done
-                out = jnp.stack([jnp.where(active, nxt, -1),
-                                 still.astype(jnp.int32)])
-                carry = dict(
-                    carry, cache=cache, active=still,
-                    tok=jnp.where(active, nxt, carry["tok"][:, 0])[:, None],
-                    pos=jnp.where(active, pos + 1, pos),
-                    step=carry["step"] + 1)
-            return carry, out
-
-        return step
 
     def _admit_fn(self, s0: int):
         fn = self._admit_fns.get(s0)
@@ -422,7 +512,9 @@ class ServeEngine:
             raise ValueError(f"clock must be 'wall'|'steps', got {clock!r}")
         order = sorted(trace, key=lambda r: (r.arrival, r.rid))
         # the lifecycle records' clock starts with this span
-        with host_scope("obs:serve/run", clock=clock, requests=len(trace)):
+        with host_scope("obs:serve/run", clock=clock, requests=len(trace),
+                        weight_bytes=self.weight_bytes,
+                        narrow_weight_bytes=self.narrow_weight_bytes):
             return self._run(order, clock, max_steps)
 
     def _run(self, order: list[Request], clock: str,
@@ -478,6 +570,8 @@ class ServeEngine:
             "wall_s": wall_s,
             "admitted": self._admitted,
             "completed": self._completed,
+            "weight_bytes": self.weight_bytes,
+            "narrow_weight_bytes": self.narrow_weight_bytes,
             "decode": {
                 "compile_s": self._decode_compile_s,
                 "steady_s": self._decode_steady_s,

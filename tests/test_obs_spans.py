@@ -150,7 +150,9 @@ def test_each_decode_step_is_one_step_span_with_its_children(served):
     assert len(steps) == len(served.records) == served.report["decode"][
         "steady_steps"] + 1
     assert served.run.parent_id is None
-    assert served.run.attrs == {"clock": "steps", "requests": 6}
+    assert served.run.attrs == {
+        "clock": "steps", "requests": 6, "narrow_weight_bytes": 0,
+        "weight_bytes": 4 * served.engine.model.num_params()}
     for s in steps:
         assert s.parent_id == served.run.span_id
         kids = [c.name for c in served.tree if c.parent_id == s.span_id]

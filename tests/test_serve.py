@@ -36,6 +36,7 @@ from repro.serve import (
     TRASH_PAGE,
     pages_needed,
 )
+from repro.serve.engine import make_step
 
 # arch choices cover: pure attention, swa ring buffer (prompt 24 > window
 # 16), rwkv and mamba/attn hybrid recurrent-state passthrough
@@ -291,7 +292,7 @@ def test_engine_step_jaxpr_is_clean():
     model = TransformerLM(cfg)
     params = model.init(jax.random.PRNGKey(0))
     engine = ServeEngine(model, params, max_batch=2, max_len=16, page_size=4)
-    step = engine._make_step()
+    step = make_step(model, max_len=16, eos=-1)
     carry_a, tables_a = engine._carry, engine._tables
 
     assert audit_host_callbacks(step, params, carry_a, tables_a) == []

@@ -1,17 +1,22 @@
-"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e.
+"""Ahead-of-time compiles of the main-path programs for a TPU v5e.
 
 The TPU compiler ships with jaxlib and compiles for a chip that is described
 rather than attached, so these tests run on the CPU backend and catch what
 interpret mode cannot: block shapes that break the (8, 128) / (32, 128)
-tiling rule, and kernels that need more VMEM than the chip has.  Shapes are
-qwen2-0.5b's (``configs/qwen2_0_5b.py``): the embedding and an MLP matrix as
-per-node gossip leaves, and the int8 KV rows of the serving pool.
+tiling rule, and kernels that need more VMEM than the chip has.  Kernel
+shapes are qwen2-0.5b's (``configs/qwen2_0_5b.py``): the embedding and an
+MLP matrix as per-node gossip leaves, and the int8 KV rows of the serving
+pool.  The serving engine's decode step is compiled for two layers at
+h2o-danube-1.8b widths, to see which weight converts the compiler keeps.
 
 The topology is described inside a module-scoped fixture, never at import,
 and the persistent compilation cache is off while these tests compile.
 """
 
+import dataclasses
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +25,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
 from repro.kernels.quant_gossip import kernel as qk
+from repro.models import TransformerLM
+from repro.models.attention import paged_kv_len
+from repro.serve.engine import (init_carry, make_step, narrows_weights,
+                                serve_weights)
 
 QWEN = get_arch("qwen2_0_5b")
 EMBED = QWEN.vocab * QWEN.d_model           # 136,134,656: a multiple of 65536
@@ -104,3 +113,61 @@ def test_kv_row_quantize_compiles_for_v5e(one_chip, rows):
 
     text = _compiled_text(fn, one_chip, ((rows, KV_D), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+# -- the serving engine's decode step ----------------------------------------
+
+DANUBE_2L = dataclasses.replace(get_arch("h2o_danube_1_8b"), n_layers=2)
+
+
+@pytest.fixture(scope="module")
+def danube_decode(one_chip):
+    """The decode step's optimized HLO on the engine's prepared weights and
+    on the float32 ones, and the element counts of the dot weights (whole,
+    and one layer of a stacked one)."""
+    model = TransformerLM(DANUBE_2L)
+    b, max_len, ps = 2, 64, 16
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(model.param_shapes())
+    assert narrows_weights(params)
+    with jax.default_matmul_precision("highest"):
+        assert not narrows_weights(params)
+    prepared = on_chip(jax.eval_shape(
+        functools.partial(serve_weights, model), params))
+    nb = -(-paged_kv_len(model.cfg, "swa", max_len) // ps)
+    carry = on_chip(jax.eval_shape(functools.partial(
+        init_carry, model, b, {"swa": 1 + b * nb}, ps, quantized=False,
+        seed=0)))
+    tables = on_chip({"swa": jax.ShapeDtypeStruct((b, nb), jnp.int32)})
+    step = jax.jit(make_step(model, max_len=max_len, eos=-1),
+                   donate_argnums=(1,))
+    hlo = {name: step.lower(p, carry, tables).compile().as_text()
+           for name, p in (("prepared", prepared), ("float32", params))}
+    dots = [x for x in jax.tree.leaves(prepared) if x.dtype == jnp.bfloat16]
+    layer = [x.shape[1:] for x in jax.tree.leaves(prepared["groups"])
+             if x.dtype == jnp.bfloat16]
+    return hlo, {math.prod(s) for s in [x.shape for x in dots] + layer}
+
+
+def _weight_converts(text, sizes):
+    """The operands of f32-to-bf16 converts that hold as many elements as
+    a dot weight."""
+    dtypes = dict(re.findall(r"%([\w.\-]+) = (\w+)\[", text))
+    found = []
+    for dims, arg in re.findall(
+            r"= bf16\[([\d,]*)\]\S* convert\(%([\w.\-]+)\)", text):
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        if dtypes.get(arg) == "f32" and n in sizes:
+            found.append(arg)
+    return found
+
+
+def test_prepared_weights_end_the_decode_steps_weight_converts(danube_decode):
+    hlo, sizes = danube_decode
+    assert _weight_converts(hlo["prepared"], sizes) == []
+    # the float32 program still rounds its weights on every call
+    assert _weight_converts(hlo["float32"], sizes)
