@@ -4,7 +4,9 @@ the compile cache, the traced window and the result line.
 A cell (``workloads`` in ``BENCHMARK.json``) names a configuration and a
 traffic mix.  Its files, found by those names:
 
-* ``bench/configs/<config>.json``  -- the configuration, as it is run;
+* ``bench/configs/<config>.json``  -- the configuration, as it is run; its
+  ``family`` names ``bench/families/<family>.py``, which maps it onto the
+  program and gives its parameter layout, reference and costs;
 * ``bench/traffic/<traffic>.json`` -- the traffic mix or training job; its
   ``kind`` (``train`` or ``serve``) picks the general runner;
 * ``bench/limits/<workload>.json`` -- the limits of the numbers compared;

@@ -16,6 +16,7 @@ served token's logit lies below the reference's best logit at its position.
 from __future__ import annotations
 
 import gc
+import time
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +90,7 @@ def run(spec: common.Spec, devs, t_start: float, hooks=None):
     setup_s = common.now() - t_start
     before = engine.watchdog.snapshot()
 
+    t_window_ns = time.perf_counter_ns()
     with common.traced(spec.trace) as tr:
         rep = engine.run(_requests(reqs), clock="wall")
     # programs the engine's watchdog saw compiled inside the window
@@ -121,10 +123,11 @@ def run(spec: common.Spec, devs, t_start: float, hooks=None):
     result = {"correct": bool(correct), "attempted": len(reqs),
               "failed": len(failed), "device": device}
     names = {m["name"] for m in spec.end_to_end}
+    ttft_p95 = common.percentile(ttft, 95)
     if not spec.trace:
         m = {"serve_output_tokens_per_s": {"value": out_tok / end,
                                            "unit": "tokens/s"},
-             "ttft_p95_ms": {"value": common.percentile(ttft, 95), "unit": "ms"},
+             "ttft_p95_ms": {"value": ttft_p95, "unit": "ms"},
              "tpot_p95_ms": {"value": common.percentile(tpot, 95), "unit": "ms"},
              "setup_s": {"value": setup_s, "unit": "s"}}
         result["metrics"] = {k: v for k, v in m.items() if k in names}
@@ -145,10 +148,24 @@ def run(spec: common.Spec, devs, t_start: float, hooks=None):
                      "requests": len(reqs), "output_tokens": out_tok,
                      "compiles_in_window": in_window,
                      "ttft_p50_ms": common.percentile(ttft, 50),
+                     "ttft_p95_ms": ttft_p95,
                      "tpot_p50_ms": common.percentile(tpot, 50),
+                     **_readback_waits(t_window_ns),
                      "checked_requests": gap["checked"],
                      "checked_tokens": gap["tokens"], "check_s": check_s}
     return result, checks
+
+
+def _readback_waits(t_window_ns: int) -> dict:
+    """The window's decode readbacks (``obs:serve/readback`` spans) that
+    waited 100 ms or more, against some 36 a step: where the host stood
+    still, every running request and every queued first token waited."""
+    from repro.obs import spans
+
+    waits = [(s.end_ns - s.start_ns) / 1e6 for s in spans()
+             if s.name == "obs:serve/readback" and s.start_ns >= t_window_ns]
+    return {"readbacks_over_100ms": sum(w >= 100 for w in waits),
+            "readback_max_ms": max(waits, default=0.0)}
 
 
 # -- the check --------------------------------------------------------------------
@@ -167,10 +184,12 @@ def sample_checked(spec: common.Spec, reqs, served) -> list:
 
 
 def reference_logits_fn(cfg, dtype):
-    from bench.reference import transformer
+    from bench import families
+
+    ref = families.load(cfg).reference()
 
     def fn(params, toks, pos):
-        return transformer.logits_at(cfg, params, toks, pos, dtype)
+        return ref.logits_at(cfg, params, toks, pos, dtype)
 
     return jax.jit(fn)
 

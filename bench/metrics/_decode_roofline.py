@@ -6,16 +6,17 @@ decode-step program (``jit_step``) in the trace."""
 
 import numpy as np
 
-from bench.flops import decode_step_cost
+from bench import families
 
 
 def decode_roofline(ctx):
     steps = ctx.counts.get("decode_steps") or []
     if ctx.kind != "serve" or not steps:
         return None
+    cost = families.load(ctx.cfg).decode_step_cost
     least = 0.0
     for active, kv in steps:
-        flops, nbytes = decode_step_cost(ctx.cfg, active, kv)
+        flops, nbytes = cost(ctx.cfg, active, kv)
         least += max(flops / ctx.peaks["bf16_flops"],
                      nbytes / ctx.peaks["hbm_bytes_per_s"])
     dev = []

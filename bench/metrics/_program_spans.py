@@ -1,10 +1,10 @@
 """Shared by the readers of the serving engine's own host spans and counters
 (``repro.obs.spans()``, in this process, after the window): whether the cell
-reports the end-to-end metric a reader's metric moves, and the spans of the
-window's ``ServeEngine.run``.
+being read is one the metric lists, and the spans of the window's
+``ServeEngine.run``.
 
-These metrics list no cells: each is read in the cells that report the
-end-to-end metric it moves (:func:`reports`).  Only a program that records
+Each of these metrics lists its cells (``workloads`` in ``BENCHMARK.json``)
+and reads nothing elsewhere (:func:`listed`).  Only a program that records
 no spans at all (one older than the span ring) gives None, and the metric is
 left out of the line.  A program that has the ring but lacks a run, a span
 or a counter a reader needs, or a run the ring lost spans of, stops the run.
@@ -17,18 +17,21 @@ import os
 from bench.harness import common
 
 
-def reports(ctx, end_to_end: str) -> bool:
-    """Whether the cell being read reports the end-to-end metric
-    ``end_to_end``.  The cell is the one whose configuration and traffic
-    resolve to ``ctx.cfg`` and ``ctx.job``; it must be one cell."""
+def listed(ctx, metric: str) -> bool:
+    """Whether the cell being read, the one whose configuration and traffic
+    are ``ctx.cfg`` and ``ctx.job``, is among the cells ``metric`` lists."""
     bj = common.load_json(os.path.join(common.ROOT, "BENCHMARK.json"))
-    specs = [common.resolve(w["name"], seed=0, seconds=0.0, trace=True)
-             for w in bj["workloads"]]
-    mine = [s for s in specs if s.cfg == ctx.cfg and s.job == ctx.job]
-    if len(mine) != 1:
-        raise RuntimeError(f"{len(mine)} cells run this configuration and "
-                           "traffic; a reader needs exactly one")
-    return any(m["name"] == end_to_end for m in mine[0].end_to_end)
+    entry = next(m for m in bj["per_layer"] if m["name"] == metric)
+    cells = {w["name"]: w for w in bj["workloads"]}
+    files = {c["name"]: c["file"] for c in bj["configs"]}
+    for name in entry["workloads"]:
+        cell = cells[name]
+        cfg = common.load_json(os.path.join(common.ROOT, files[cell["config"]]))
+        job = common.load_json(os.path.join(common.BENCH, "traffic",
+                                            cell["traffic"] + ".json"))
+        if cfg == ctx.cfg and job == ctx.job:
+            return True
+    return False
 
 
 def has_spans() -> bool:

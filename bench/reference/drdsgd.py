@@ -4,7 +4,7 @@ under test.
 
 For each node i, with its own parameters theta_i and batch:
 
-    l_i, g_i = loss and gradient of the transformer       (transformer.py)
+    l_i, g_i = loss and gradient of the family's reference model
     g_i     <- g_i * min(1, clip / (|g_i| + 1e-12))      global-norm clip
     s_i      = exp(min(l_i, loss_clip) / mu) / mu        the DR reweighting
     theta_i <- theta_i - lr * s_i * g_i                  SGD
@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import transformer
+from bench import families
 
 
 def graph_edges(kind: str, k: int) -> set:
@@ -44,8 +44,9 @@ def metropolis(kind: str, k: int) -> np.ndarray:
 
 def node_grad(cfg: dict, job: dict, params, rows, dtype=jnp.float32):
     """(loss, clipped gradient, DR scale) of one node's batch (B, L + 1)."""
+    ref = families.load(cfg).reference()
     l, g = jax.value_and_grad(
-        lambda p: transformer.batch_loss(cfg, p, rows, dtype))(params)
+        lambda p: ref.batch_loss(cfg, p, rows, dtype))(params)
     leaves = jax.tree.leaves(g)
     norm = jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32))) for x in leaves))
     g = jax.tree.map(lambda x: x * jnp.minimum(1.0, job["grad_clip"] / (norm + 1e-12)

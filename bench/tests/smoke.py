@@ -15,7 +15,7 @@ for p in (os.path.join(ROOT, "src"), ROOT):
 from bench.harness import common  # noqa: E402
 
 #: qwen2-like: QKV bias, tied head, full attention
-QWEN = {"name": "smoke-qwen", "hidden_size": 64, "intermediate_size": 128,
+QWEN = {"name": "smoke-qwen", "family": "dense", "hidden_size": 64, "intermediate_size": 128,
         "num_hidden_layers": 2, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
         "rms_norm_eps": 1e-6, "rope_theta": 1e6, "attention_bias": True,

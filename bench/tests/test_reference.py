@@ -10,7 +10,8 @@ import pytest
 
 from bench.tests import smoke  # noqa: F401  (puts src/ on the path)
 from bench.harness import program, serve, train
-from bench.reference import drdsgd, transformer
+from bench import families
+from bench.reference import drdsgd
 from bench.weights import flatten, make_params
 
 
@@ -20,7 +21,8 @@ def test_logits_match_the_model(cfg):
     params = make_params(cfg, 5)
     toks = np.random.default_rng(0).integers(0, cfg["vocab_size"], 40).astype(np.int32)
     theirs = np.asarray(model.logits_all(params, {"tokens": jnp.asarray(toks[None])}))[0]
-    ours = np.asarray(transformer.logits_at(cfg, params, jnp.asarray(toks),
+    ref = families.load(cfg).reference()
+    ours = np.asarray(ref.logits_at(cfg, params, jnp.asarray(toks),
                                             jnp.arange(40)))
     np.testing.assert_allclose(ours, theirs, rtol=2e-5, atol=2e-5)
 
@@ -31,8 +33,9 @@ def test_loss_and_gradient_match_the_model(cfg):
     params = make_params(cfg, 6)
     rows = np.random.default_rng(1).integers(0, cfg["vocab_size"], (2, 33)).astype(np.int32)
     l0, g0 = jax.value_and_grad(model.loss)(params, {"tokens": jnp.asarray(rows)})
+    ref = families.load(cfg).reference()
     l1, g1 = jax.value_and_grad(
-        lambda p: transformer.batch_loss(cfg, p, jnp.asarray(rows)))(params)
+        lambda p: ref.batch_loss(cfg, p, jnp.asarray(rows)))(params)
     np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6)
     for name, a in flatten(g0).items():
         a, b = np.asarray(a), np.asarray(flatten(g1)[name])
@@ -66,3 +69,31 @@ def test_serve_cell_is_correct_on_cpu(cfg, cell):
     assert result["correct"], checks
     assert result["failed"] == 0 and result["attempted"] == 20
     assert checks["served_mean_gap"]["value"] < 1e-5
+
+
+def test_a_traced_run_keeps_to_the_jobs_trace_seconds(monkeypatch):
+    """A job's ``trace_seconds`` caps a traced run's window (the profile is
+    stubbed: the CPU has no device trace to read)."""
+    import contextlib
+    import types
+
+    from bench.harness import common
+
+    @contextlib.contextmanager
+    def traced(enabled):
+        yield types.SimpleNamespace(trace=types.SimpleNamespace(window=lambda: (0, 1)))
+
+    monkeypatch.setattr(common, "traced", traced)
+    monkeypatch.setattr(common, "read_per_layer", lambda spec, ctx: {})
+    monkeypatch.setattr(common, "busy_and_window", lambda ctx: (1.0, 1.0))
+    monkeypatch.setattr(common, "breakdown", lambda ctx: {})
+    job = dict(smoke.train_job(), trace_seconds=0.5)
+    s = smoke.spec(smoke.QWEN, job, smoke.limits("train.qwen2-0.5b.k2-complete"),
+                   seconds=4.0)
+    s.trace = True
+    result, _ = train.run(s, smoke.devices(), 0.0)
+    assert result["correct"]
+    assert 0.5 <= result["log"]["window_s"] < 2.0
+    s.trace = False
+    result, _ = train.run(s, smoke.devices(), 0.0)
+    assert result["log"]["window_s"] >= 4.0

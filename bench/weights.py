@@ -1,20 +1,8 @@
 """Seeded random weights for a configuration file, made on the device in one
-jitted call, in the parameter layout the serving and training stack reads:
-
-    embedding.table (V, D)           final_norm.scale (D,)
-    lm_head.table (V, D)             only when the embeddings are not tied
-    groups.l0.norm1.scale (L, D)     groups.l0.norm2.scale (L, D)
-    groups.l0.mix.wq (L, D, H, hd)   groups.l0.mix.bq (L, H, hd)   with bias
-    groups.l0.mix.wk (L, D, KV, hd)  groups.l0.mix.bk (L, KV, hd)
-    groups.l0.mix.wv (L, D, KV, hd)  groups.l0.mix.bv (L, KV, hd)
-    groups.l0.mix.wo (L, H, hd, D)
-    groups.l0.ffn.w_gate (L, D, F)   groups.l0.ffn.w_up (L, D, F)
-    groups.l0.ffn.w_down (L, F, D)
-
-Matrices are N(0, 1/fan_in); norm scales are 1 + N(0, 0.1^2) and biases
-N(0, 0.02^2), so that no parameter is at a value where a wrong use of it
-would go unseen.  The benchmark hands the same tree to the program and, made
-anew from the seed, to the plain reference.
+jitted call, in the parameter layout the configuration's family
+(``bench/families/<family>.py``) gives: its ``shapes`` and, leaf by leaf,
+its ``init`` rule.  The benchmark hands the same tree to the program and,
+made anew from the seed, to the plain reference.
 """
 
 from __future__ import annotations
@@ -23,55 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def dims(cfg: dict) -> dict:
-    d = cfg["hidden_size"]
-    h = cfg["num_attention_heads"]
-    return dict(L=cfg["num_hidden_layers"], D=d, H=h,
-                KV=cfg["num_key_value_heads"],
-                hd=cfg.get("head_dim") or d // h,
-                F=cfg["intermediate_size"], V=cfg["vocab_size"])
-
-
-def shapes(cfg: dict) -> dict:
-    """{path: shape} of every parameter."""
-    m = dims(cfg)
-    L, D, H, KV, hd, F, V = (m[k] for k in ("L", "D", "H", "KV", "hd", "F", "V"))
-    s = {
-        "embedding.table": (V, D),
-        "final_norm.scale": (D,),
-        "groups.l0.norm1.scale": (L, D),
-        "groups.l0.norm2.scale": (L, D),
-        "groups.l0.mix.wq": (L, D, H, hd),
-        "groups.l0.mix.wk": (L, D, KV, hd),
-        "groups.l0.mix.wv": (L, D, KV, hd),
-        "groups.l0.mix.wo": (L, H, hd, D),
-        "groups.l0.ffn.w_gate": (L, D, F),
-        "groups.l0.ffn.w_up": (L, D, F),
-        "groups.l0.ffn.w_down": (L, F, D),
-    }
-    if cfg.get("attention_bias"):
-        s.update({"groups.l0.mix.bq": (L, H, hd),
-                  "groups.l0.mix.bk": (L, KV, hd),
-                  "groups.l0.mix.bv": (L, KV, hd)})
-    if not cfg.get("tie_word_embeddings"):
-        s["lm_head.table"] = (V, D)
-    return s
-
-
-def _std(path: str, shape: tuple) -> tuple[str, float]:
-    leaf = path.rsplit(".", 1)[-1]
-    if leaf == "scale":
-        return "norm", 0.1
-    if leaf in ("bq", "bk", "bv"):
-        return "normal", 0.02
-    if leaf in ("wq", "wk", "wv"):                     # (L, D, heads, hd)
-        return "normal", 1.0 / np.sqrt(shape[-3])
-    if leaf == "wo":                                   # (L, H, hd, D)
-        return "normal", 1.0 / np.sqrt(shape[-3] * shape[-2])
-    if leaf == "table":
-        return "normal", 1.0 / np.sqrt(shape[-1])
-    return "normal", 1.0 / np.sqrt(shape[-2])
+from bench import families
 
 
 def nest(flat: dict) -> dict:
@@ -105,7 +45,8 @@ def seed_key(seed: int) -> jax.Array:
 
 def make_params(cfg: dict, seed: int, dtype=jnp.float32, device=None):
     """The parameter tree of ``cfg`` for ``seed``, made on the device."""
-    spec = shapes(cfg)
+    fam = families.load(cfg)
+    spec = fam.shapes(cfg)
     names = sorted(spec)
 
     def make(key):
@@ -113,7 +54,7 @@ def make_params(cfg: dict, seed: int, dtype=jnp.float32, device=None):
         flat = {}
         for k, name in zip(keys, names):
             shape = spec[name]
-            kind, std = _std(name, shape)
+            kind, std = fam.init(name, shape)
             x = jax.random.normal(k, shape, jnp.float32) * std
             flat[name] = (x + 1.0 if kind == "norm" else x).astype(dtype)
         return nest(flat)
