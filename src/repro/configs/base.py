@@ -1,4 +1,5 @@
-"""Config registry: the 10 assigned architectures + the paper's own models.
+"""Config registry: the 10 assigned architectures, the benchmark's
+jamba2-3b, and the paper's own models.
 
 Every module under ``repro/configs`` exposes ``full()`` (the exact assigned
 configuration) and ``smoke()`` (a reduced same-family variant: <=2-ish layers,
@@ -22,6 +23,7 @@ ARCH_IDS = (
     "llama3_405b",
     "musicgen_medium",
     "deepseek_moe_16b",
+    "jamba2_3b",
 )
 
 # CLI aliases with the original dashes/dots
@@ -36,6 +38,7 @@ ALIASES = {
     "llama3-405b": "llama3_405b",
     "musicgen-medium": "musicgen_medium",
     "deepseek-moe-16b": "deepseek_moe_16b",
+    "jamba2-3b": "jamba2_3b",
 }
 
 

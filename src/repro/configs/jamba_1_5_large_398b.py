@@ -3,7 +3,9 @@
 72L, d_model=8192, 64 heads (GQA kv=8), d_ff=24576 (expert size),
 vocab=65536, MoE 16 experts top-2 on every second layer.  Period-8 block:
 one attention layer per 7 Mamba layers (attention at position 4, as in the
-released model).  Mamba recurrent state => `long_500k` runs.
+released model).  As in every Jamba, attention has no positional encoding
+and the Mamba mixers RMS-norm dt, B and C after ``x_proj``.  Mamba
+recurrent state => `long_500k` runs.
 [arXiv:2403.19887]
 """
 
@@ -28,6 +30,7 @@ def full() -> ArchConfig:
         mamba_d_state=16,
         mamba_d_conv=4,
         mamba_expand=2,
+        rope_theta=None,
     )
 
 
@@ -48,6 +51,7 @@ def smoke() -> ArchConfig:
         mamba_d_state=8,
         mamba_d_conv=4,
         mamba_expand=2,
+        rope_theta=None,
         attn_q_chunk=32,
         attn_kv_chunk=32,
         logits_chunk=64,

@@ -5,9 +5,10 @@ and the dry-run; it never materializes the (S, S) score matrix — memory per
 step is q_chunk x kv_chunk — and doubles as the reference oracle for the
 Pallas ``flash_attention`` kernel.
 
-Supports: grouped KV heads, RoPE, optional QKV bias (qwen2), sliding-window
-masking (h2o-danube / gemma2 local layers), attention-score soft-capping
-(gemma2), and ring-buffer KV caches for decode.
+Supports: grouped KV heads, RoPE (or no positional encoding: jamba),
+optional QKV bias (qwen2), sliding-window masking (h2o-danube / gemma2
+local layers), attention-score soft-capping (gemma2), and ring-buffer KV
+caches for decode.
 """
 
 from __future__ import annotations
@@ -130,8 +131,9 @@ def _project_qkv(p, x, cfg: ArchConfig, positions):
         q = q + p["bq"].astype(dt)
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope_theta is not None:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 

@@ -45,7 +45,7 @@ class ArchConfig:
     attn_softcap: float | None = None    # gemma2
     logit_softcap: float | None = None   # gemma2
     qkv_bias: bool = False               # qwen2
-    rope_theta: float = 10000.0
+    rope_theta: float | None = 10000.0   # None: no positional encoding (jamba)
     rmsnorm_eps: float = 1e-6
     tie_embeddings: bool = False
     frontend: str = "token"              # token | patch_stub | frame_stub

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.models import params as pr
 from repro.models.config import ArchConfig
+from repro.models.layers import rmsnorm
 
 # ---------------------------------------------------------------------------
 # RWKV6
@@ -188,6 +189,10 @@ def mamba_decl(cfg: ArchConfig):
         "a_log": pr.constant((di, ds), ("hidden", "state"), 0.0),
         "d_skip": pr.ones((di,), ("hidden",)),
         "out_proj": pr.normal((di, d), ("hidden", "embed"), fan_in=di),
+        # Jamba's RMSNorm scales on the x_proj split (dt, B, C)
+        "dt_norm": pr.ones((dtr,), (None,)),
+        "b_norm": pr.ones((ds,), ("state",)),
+        "c_norm": pr.ones((ds,), ("state",)),
     }
 
 
@@ -200,12 +205,19 @@ def mamba_init_state(cfg: ArchConfig, batch: int):
 
 
 def _mamba_ssm_scan(p, u, cfg: ArchConfig, h0):
-    """Selective scan. u: (B,S,di) post-conv activations. Returns (y, hT)."""
+    """Selective scan. u: (B,S,di) post-conv activations. Returns (y, hT).
+
+    As in Jamba, dt, B and C are RMS-normed after ``x_proj``, before
+    ``dt_proj`` and the scan.  The recurrence itself runs
+    under the ``obs:ssm/scan`` scope."""
     ds = cfg.mamba_d_state
     dtr = p["dt_proj"].shape[0]
     f32 = jnp.float32
     proj = jnp.einsum("bsd,dk->bsk", u.astype(f32), p["x_proj"].astype(f32))
     dt_low, bmat, cmat = jnp.split(proj, [dtr, dtr + ds], axis=-1)
+    dt_low = rmsnorm({"scale": p["dt_norm"]}, dt_low, cfg.rmsnorm_eps)
+    bmat = rmsnorm({"scale": p["b_norm"]}, bmat, cfg.rmsnorm_eps)
+    cmat = rmsnorm({"scale": p["c_norm"]}, cmat, cfg.rmsnorm_eps)
     dt = jax.nn.softplus(
         jnp.einsum("bsr,rd->bsd", dt_low, p["dt_proj"].astype(f32))
         + p["dt_bias"].astype(f32)
@@ -220,12 +232,13 @@ def _mamba_ssm_scan(p, u, cfg: ArchConfig, h0):
         y = jnp.einsum("bds,bs->bd", h, c_t)
         return h, y
 
-    hT, ys = jax.lax.scan(
-        body, h0,
-        (u.transpose(1, 0, 2), dt.transpose(1, 0, 2),
-         bmat.transpose(1, 0, 2), cmat.transpose(1, 0, 2)),
-    )
-    y = ys.transpose(1, 0, 2) + u.astype(f32) * p["d_skip"].astype(f32)
+    with jax.named_scope("obs:ssm/scan"):
+        hT, ys = jax.lax.scan(
+            body, h0,
+            (u.transpose(1, 0, 2), dt.transpose(1, 0, 2),
+             bmat.transpose(1, 0, 2), cmat.transpose(1, 0, 2)),
+        )
+        y = ys.transpose(1, 0, 2) + u.astype(f32) * p["d_skip"].astype(f32)
     return y, hT
 
 
@@ -253,7 +266,8 @@ def mamba_forward(p, x, cfg: ArchConfig, state=None):
     di = cfg.mamba_expand * cfg.d_model
     xz = jnp.einsum("bsd,dk->bsk", x.astype(dt_), p["in_proj"].astype(dt_))
     u, z = jnp.split(xz, [di], axis=-1)
-    u, conv_state = _causal_conv(p, u, cfg, state["conv"])
+    with jax.named_scope("obs:ssm/conv"):
+        u, conv_state = _causal_conv(p, u, cfg, state["conv"])
     u = jax.nn.silu(u)
     y, ssm_state = _mamba_ssm_scan(p, u, cfg, state["ssm"])
     y = y.astype(dt_) * jax.nn.silu(z)

@@ -50,7 +50,9 @@ dot (attention's ``wq``/``wk``/``wv``/``wo``, the GLU MLPs, the head's
 table) is stored in bfloat16, rounded once here: a default-precision
 float32 dot rounds it so on every call anyway, and
 :func:`repro.models.layers.weight_einsum` reads it as stored.  Elsewhere
-(the CPU, ``highest``) the tree is the caller's float32 one.
+(the CPU, ``highest``) the tree is the caller's float32 one.  A tree
+assigned to ``engine.params`` later is held the same way, so the compiled
+programs see the dtypes they were built for.
 
 Host spans (:func:`repro.obs.host_scope`, read back with
 :func:`repro.obs.spans`) time the layers of the host loop, and carry its
@@ -66,11 +68,18 @@ counters as attributes:
   ``obs:serve/dispatch``    the decode program's call
   ``obs:serve/readback``    the (2, B) readback, the step's host sync
   ``obs:serve/emit``        per-slot appends, lifecycle records, releases
-  ``obs:serve/admit``       one admission (rid, slot, prompt_tokens, pages)
+  ``obs:serve/admit``       one admission (rid, slot, prompt_tokens, pages,
+                            scan_tokens)
   ``obs:serve/slot_write``  block-table rows and the carry's slot writes
   ``obs:serve/admit_call``  the prefill program's call (or the slot clear)
   ``obs:serve/admit_wait``  the wait for the prefill
   ========================  ==================================================
+
+``scan_tokens`` is the prompt tokens the prefill scans one at a time, times
+the recurrent (mamba, rwkv) layers that scan them: 0 for a model of
+attention layers alone.  Inside the prefill and decode programs,
+``obs:ssm/conv`` and ``obs:ssm/scan`` (in :mod:`repro.models.ssm`) scope the
+Mamba layers' causal convolution and selective scan.
 
 ``kv_gathered_tokens`` is what the step program reads of the pools,
 ``max_batch`` times the ring length summed over the paged kinds;
@@ -269,10 +278,7 @@ class ServeEngine:
                 f"ServeEngine needs a token frontend (got {cfg.frontend!r}) "
                 "— prefix-frontend archs have no prompt-only prefill")
         self.model = model
-        if narrows_weights(params):
-            self.params = serve_weights(model, params)
-        else:
-            self.params = params
+        self.params = params
         self.weight_bytes = _nbytes(self.params)
         self.narrow_weight_bytes = _nbytes(self.params, jnp.bfloat16)
         self.max_batch = max_batch
@@ -287,6 +293,10 @@ class ServeEngine:
 
         blocks = {blk for blk, _ in cfg.head_layers()} | {
             blk for blk, _ in cfg.group_pattern()}
+        # layers whose prefill is a scan over every prompt token
+        self._scanned_layers = sum(
+            blk in ("mamba", "rwkv") for blk, _ in
+            cfg.head_layers() + cfg.group_pattern() * cfg.n_groups)
         self.kinds = sorted(blocks & {"attn", "swa"})
         self.ring_len = {k: paged_kv_len(cfg, k, max_len) for k in self.kinds}
         self.n_blocks = {k: -(-t // page_size)
@@ -339,6 +349,17 @@ class ServeEngine:
         self.watchdog.track("serve_decode_step", self._step_fn, allowed=1)
         self.watchdog.track("serve_clear_slot", self._clear_fn, allowed=1)
 
+    @property
+    def params(self):
+        return self._params
+
+    @params.setter
+    def params(self, params):
+        """Every tree the engine is given is held as :func:`serve_weights`
+        makes it, where :func:`narrows_weights` allows."""
+        self._params = (serve_weights(self.model, params)
+                        if narrows_weights(params) else params)
+
     # -- compiled programs ----------------------------------------------------
 
     def _admit_fn(self, s0: int):
@@ -366,7 +387,8 @@ class ServeEngine:
         s0 = req.s0
         pages_total = sum(len(p) for p in adm.pages.values())
         with host_scope("obs:serve/admit", rid=req.rid, slot=int(slot),
-                        prompt_tokens=s0 - 1, pages=pages_total):
+                        prompt_tokens=s0 - 1, pages=pages_total,
+                        scan_tokens=(s0 - 1) * self._scanned_layers):
             with host_scope("obs:serve/slot_write"):
                 rows = {}
                 for kind in self._tables:

@@ -97,6 +97,7 @@ def test_full_config_exact_assignment(arch):
         "llama3_405b": (126, 16384, 128, 8, 53248, 128256),
         "musicgen_medium": (48, 1536, 24, 24, 6144, 2048),
         "deepseek_moe_16b": (28, 2048, 16, 16, 1408, 102400),
+        "jamba2_3b": (28, 2560, 20, 1, 8192, 65536),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
            cfg.vocab)
