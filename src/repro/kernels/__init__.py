@@ -10,6 +10,9 @@ communication algorithm), but the production framework around it does:
                     (paper Eq. 9 in one HBM pass)
   rwkv6_scan/       chunked WKV6 recurrence with the state matrix resident
                     in VMEM scratch across time chunks
+  mamba_scan/       Mamba-1 selective scan with the state resident in
+                    VMEM scratch across time blocks, 1024 channels to a
+                    vreg a token; Jamba's prefill runs it (``models/ssm.py``)
   quant_gossip/     fused int8 quantize / dequantize-accumulate for the
                     compressed gossip consensus (repro.comm): per-block
                     absmax scales + stochastic rounding in one pass, so the

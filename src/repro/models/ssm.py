@@ -1,9 +1,12 @@
 """Recurrent sequence blocks: RWKV6 ("Finch") time-mix and Mamba selective SSM.
 
-Both are implemented as explicit `lax.scan` recurrences over time with a
-carried state, which (a) is the exact semantics the architectures define,
-(b) gives O(1)-per-token decode for `decode_32k` / `long_500k`, and (c) serves
-as the reference oracle for the `rwkv6_scan` Pallas kernel.
+Both are recurrences over time with a carried state, which is the exact
+semantics the architectures define and gives O(1)-per-token decode for
+`decode_32k` / `long_500k`.  RWKV6's is an explicit `lax.scan`, the
+reference oracle for the `rwkv6_scan` Pallas kernel.  Mamba's selective scan
+goes through `kernels/mamba_scan`: its `lax.scan` of one token a step is now
+that kernel's reference (`ref.py`), which the CPU and the one-token decode
+step run, while the TPU runs longer sequences through the kernel.
 
 RWKV6's defining feature (arXiv:2404.05892) — the *data-dependent* per-channel
 decay `w_t = exp(-exp(w0 + tanh(x̃_t A) B))` — is implemented faithfully, as is
@@ -15,6 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.mamba_scan import ops as mamba_scan
 from repro.models import params as pr
 from repro.models.config import ArchConfig
 from repro.models.layers import rmsnorm
@@ -208,8 +212,10 @@ def _mamba_ssm_scan(p, u, cfg: ArchConfig, h0):
     """Selective scan. u: (B,S,di) post-conv activations. Returns (y, hT).
 
     As in Jamba, dt, B and C are RMS-normed after ``x_proj``, before
-    ``dt_proj`` and the scan.  The recurrence itself runs
-    under the ``obs:ssm/scan`` scope."""
+    ``dt_proj`` and the scan.  The recurrence and the ``d_skip`` term run
+    under the ``obs:ssm/scan`` scope: more than one token through the
+    ``mamba_scan`` kernel's path, one token (the decode step) as a single
+    plain step of its reference."""
     ds = cfg.mamba_d_state
     dtr = p["dt_proj"].shape[0]
     f32 = jnp.float32
@@ -224,21 +230,8 @@ def _mamba_ssm_scan(p, u, cfg: ArchConfig, h0):
     )                                                        # (B,S,di)
     a = -jnp.exp(p["a_log"].astype(f32))                     # (di, ds)
 
-    def body(h, inp):
-        u_t, dt_t, b_t, c_t = inp                            # (B,di),(B,di),(B,ds),(B,ds)
-        da = jnp.exp(dt_t[..., None] * a)                    # (B,di,ds)
-        dbu = dt_t[..., None] * b_t[:, None, :] * u_t[..., None].astype(f32)
-        h = da * h + dbu
-        y = jnp.einsum("bds,bs->bd", h, c_t)
-        return h, y
-
     with jax.named_scope("obs:ssm/scan"):
-        hT, ys = jax.lax.scan(
-            body, h0,
-            (u.transpose(1, 0, 2), dt.transpose(1, 0, 2),
-             bmat.transpose(1, 0, 2), cmat.transpose(1, 0, 2)),
-        )
-        y = ys.transpose(1, 0, 2) + u.astype(f32) * p["d_skip"].astype(f32)
+        y, hT = mamba_scan.selective_scan(u, dt, bmat, cmat, a, p["d_skip"], h0)
     return y, hT
 
 

@@ -69,7 +69,7 @@ counters as attributes:
   ``obs:serve/readback``    the (2, B) readback, the step's host sync
   ``obs:serve/emit``        per-slot appends, lifecycle records, releases
   ``obs:serve/admit``       one admission (rid, slot, prompt_tokens, pages,
-                            scan_tokens)
+                            scan_tokens, scan_kernel_tokens)
   ``obs:serve/slot_write``  block-table rows and the carry's slot writes
   ``obs:serve/admit_call``  the prefill program's call (or the slot clear)
   ``obs:serve/admit_wait``  the wait for the prefill
@@ -77,9 +77,14 @@ counters as attributes:
 
 ``scan_tokens`` is the prompt tokens the prefill scans one at a time, times
 the recurrent (mamba, rwkv) layers that scan them: 0 for a model of
-attention layers alone.  Inside the prefill and decode programs,
-``obs:ssm/conv`` and ``obs:ssm/scan`` (in :mod:`repro.models.ssm`) scope the
-Mamba layers' causal convolution and selective scan.
+attention layers alone.  ``scan_kernel_tokens`` is the prompt tokens times
+the Mamba layers when the prefill program, as it was traced, ran its Mamba
+scans through the ``mamba_scan`` kernel
+(:func:`repro.kernels.mamba_scan.ops.traced_paths`), else 0: on the
+reference path, the CPU's, and for a one-token prompt.  Inside the prefill
+and decode programs, ``obs:ssm/conv`` and ``obs:ssm/scan`` (in
+:mod:`repro.models.ssm`) scope the Mamba layers' causal convolution and
+selective scan.
 
 ``kv_gathered_tokens`` is what the step program reads of the pools,
 ``max_batch`` times the ring length summed over the paged kinds;
@@ -99,6 +104,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.mamba_scan.ops import traced_paths
 from repro.models import TransformerLM
 from repro.models.attention import paged_kv_len
 from repro.obs import MetricsSink, RecompileWatchdog, host_scope
@@ -294,9 +300,9 @@ class ServeEngine:
         blocks = {blk for blk, _ in cfg.head_layers()} | {
             blk for blk, _ in cfg.group_pattern()}
         # layers whose prefill is a scan over every prompt token
-        self._scanned_layers = sum(
-            blk in ("mamba", "rwkv") for blk, _ in
-            cfg.head_layers() + cfg.group_pattern() * cfg.n_groups)
+        layers = cfg.head_layers() + cfg.group_pattern() * cfg.n_groups
+        self._scanned_layers = sum(blk in ("mamba", "rwkv") for blk, _ in layers)
+        self._mamba_layers = sum(blk == "mamba" for blk, _ in layers)
         self.kinds = sorted(blocks & {"attn", "swa"})
         self.ring_len = {k: paged_kv_len(cfg, k, max_len) for k in self.kinds}
         self.n_blocks = {k: -(-t // page_size)
@@ -345,6 +351,8 @@ class ServeEngine:
 
         self._clear_fn = jax.jit(clear, donate_argnums=(1,))
         self._admit_fns: dict[int, object] = {}
+        # prompt lengths whose prefill program, as traced, runs the kernel
+        self._kernel_prefills: set[int] = set()
         self.watchdog = watchdog or RecompileWatchdog(label="serve engine")
         self.watchdog.track("serve_decode_step", self._step_fn, allowed=1)
         self.watchdog.track("serve_clear_slot", self._clear_fn, allowed=1)
@@ -369,8 +377,10 @@ class ServeEngine:
         model, max_len = self.model, self.max_len
 
         def admit(params, prompt, cache, rows, slot):
-            with jax.named_scope("obs:serve/prefill"):
+            with jax.named_scope("obs:serve/prefill"), traced_paths() as paths:
                 _, pf = model.prefill(params, {"tokens": prompt})
+            if paths - {"ref"}:
+                self._kernel_prefills.add(s0)
             with jax.named_scope("obs:serve/place"):
                 return place_paged_prefill(model, pf, cache, rows, slot, s0,
                                            max_len)
@@ -388,7 +398,7 @@ class ServeEngine:
         pages_total = sum(len(p) for p in adm.pages.values())
         with host_scope("obs:serve/admit", rid=req.rid, slot=int(slot),
                         prompt_tokens=s0 - 1, pages=pages_total,
-                        scan_tokens=(s0 - 1) * self._scanned_layers):
+                        scan_tokens=(s0 - 1) * self._scanned_layers) as span:
             with host_scope("obs:serve/slot_write"):
                 rows = {}
                 for kind in self._tables:
@@ -412,6 +422,8 @@ class ServeEngine:
                     cache = fn(self.params, prompt, c["cache"], rows,
                                jnp.int32(slot))
             dt = call.seconds
+            span.attrs["scan_kernel_tokens"] = (
+                (s0 - 1) * self._mamba_layers if s0 in self._kernel_prefills else 0)
             if s0 != 1:
                 with host_scope("obs:serve/admit_wait") as wait:
                     jax.block_until_ready(jax.tree.leaves(cache)[0])

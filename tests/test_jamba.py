@@ -10,7 +10,8 @@ the program's normal path, against the plain reference.
   the Mamba mixers' inner norms left out, and with RoPE put in.
 * The Mamba layers' ``obs:ssm/conv`` and ``obs:ssm/scan`` scopes are in the
   engine's prefill and decode programs, and each admission span counts the
-  tokens its prefill scans (``scan_tokens``).
+  tokens its prefill scans (``scan_tokens``) and those of them that went
+  through the ``mamba_scan`` kernel (``scan_kernel_tokens``).
 * A tree given to an engine that narrows its weights is held narrowed, and
   the admissions' host reader the cell is listed in reads a Jamba run.
 * The family's scan and decode costs equal numbers worked by hand, and the
@@ -32,6 +33,7 @@ import pytest
 
 from repro import obs
 from repro.configs import get_arch
+from repro.kernels.mamba_scan import ops as mamba_scan_ops
 from repro.models import TransformerLM, ssm
 from repro.serve import Request, ServeEngine
 from repro.serve import engine as engine_mod
@@ -174,8 +176,20 @@ def _op_names(lowered) -> set:
     return set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
 
 
-def test_scopes_in_the_prefill_and_decode_programs_and_scan_tokens_on_admits():
-    model = TransformerLM(get_arch("jamba2_3b", smoke=True))
+@pytest.mark.parametrize("path,groups", [("ref", 1), ("kernel", 1), ("kernel", 2)],
+                         ids=["ref", "kernel", "kernel_scanned_groups"])
+def test_scopes_in_the_prefill_and_decode_programs_and_scan_tokens_on_admits(
+        path, groups, monkeypatch):
+    """On the kernel path (the ``mamba_scan`` kernel in interpret mode) every
+    scanned token is a kernel token, also where a ``lax.scan`` over the
+    layer groups traces one group's layers for all; on the reference path,
+    the CPU's, none."""
+    if path == "kernel":
+        monkeypatch.setattr(mamba_scan_ops, "kernel_path",
+                            lambda use_kernel, interpret: "interpret")
+    cfg = get_arch("jamba2_3b", smoke=True)
+    cfg = dataclasses.replace(cfg, n_layers=cfg.n_layers * groups)
+    model = TransformerLM(cfg)
     params = model.init(jax.random.PRNGKey(0))
     engine = ServeEngine(model, params, max_batch=2, max_len=24, page_size=4)
     prompts = {0: 9, 1: 1, 2: 5}
@@ -185,9 +199,11 @@ def test_scopes_in_the_prefill_and_decode_programs_and_scan_tokens_on_admits():
                clock="steps")
     admits = [s for s in obs.spans() if s.name == "obs:serve/admit"]
     mamba = sum(b == "mamba" for b, _ in model.cfg.group_pattern()) * model.cfg.n_groups
-    assert mamba == 3
+    assert (mamba, model.cfg.n_groups) == (3 * groups, groups)
     assert {s.attrs["rid"]: s.attrs["scan_tokens"] for s in admits} == \
         {r: (n - 1) * mamba for r, n in prompts.items()}
+    assert {s.attrs["rid"]: s.attrs["scan_kernel_tokens"] for s in admits} == \
+        {r: (n - 1) * mamba * (path == "kernel") for r, n in prompts.items()}
 
     prefill = _op_names(engine._admit_fns[9].lower(
         engine.params, jnp.zeros((1, 8), jnp.int32), engine._carry["cache"],
@@ -208,6 +224,7 @@ def test_dense_admissions_scan_nothing():
                         arrival=0.0)], clock="steps")
     admits = [s for s in obs.spans() if s.name == "obs:serve/admit"]
     assert [s.attrs["scan_tokens"] for s in admits] == [0]
+    assert [s.attrs["scan_kernel_tokens"] for s in admits] == [0]
 
 
 def test_the_admission_host_reader_reads_a_jamba_run_in_the_cell():

@@ -8,6 +8,7 @@ shapes are qwen2-0.5b's (``configs/qwen2_0_5b.py``): the embedding and an
 MLP matrix as per-node gossip leaves, and the int8 KV rows of the serving
 pool.  The serving engine's decode step is compiled for two layers at
 h2o-danube-1.8b widths, to see which weight converts the compiler keeps.
+Jamba2-3B's prefill selective scan is compiled at its published widths.
 
 The topology is described inside a module-scoped fixture, never at import,
 and the persistent compilation cache is off while these tests compile.
@@ -171,3 +172,31 @@ def test_prepared_weights_end_the_decode_steps_weight_converts(danube_decode):
     assert _weight_converts(hlo["prepared"], sizes) == []
     # the float32 program still rounds its weights on every call
     assert _weight_converts(hlo["float32"], sizes)
+
+
+# -- Jamba's prefill selective scan -------------------------------------------
+
+def test_mamba_scan_compiles_for_v5e_in_the_prefills_scopes(one_chip,
+                                                            monkeypatch):
+    """The kernel at Jamba2-3B widths (an 8192-token prompt, d_inner 5120,
+    N 16) fits the chip's VMEM, and its custom call carries both scopes the
+    ``ssm_scan_*`` readers look for in the trace."""
+    from repro.kernels.mamba_scan import ops
+
+    monkeypatch.setattr(ops, "kernel_path", lambda use_kernel, interpret: "pallas")
+    b, s, di, n = 1, 8192, 5120, 16
+
+    def fn(u, dt, bm, cm, a, d, h0):
+        with jax.named_scope("obs:serve/prefill"), \
+                jax.named_scope("obs:ssm/scan"):
+            return ops.selective_scan(u, dt, bm, cm, a, d, h0)
+
+    text = _compiled_text(fn, one_chip, ((b, s, di), jnp.float32),
+                          ((b, s, di), jnp.float32), ((b, s, n), jnp.float32),
+                          ((b, s, n), jnp.float32), ((di, n), jnp.float32),
+                          ((di,), jnp.float32), ((b, di, n), jnp.float32))
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    (op_name,) = re.findall(r'op_name="([^"]*)"', calls[0])
+    assert "obs:serve/prefill" in op_name and "obs:ssm/scan" in op_name
