@@ -68,7 +68,8 @@ def _gossip_mixer(graph, kwargs, num_nodes, topology, drop_p, seed,
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from repro.dynamics import DynamicGossipMixer, make_schedule
+    from repro.comm import make_gossip_mixer
+    from repro.dynamics import make_schedule
     from repro.graphs import build_graph, metropolis_weights
     from repro.launch.mesh import make_auto_mesh
 
@@ -85,10 +86,10 @@ def _gossip_mixer(graph, kwargs, num_nodes, topology, drop_p, seed,
 
     def make(params_tree):
         param_specs = jax.tree.map(lambda _: P("node"), params_tree)
-        return DynamicGossipMixer(schedule, mesh, "node", param_specs,
-                                  quantized=compression,
-                                  ef_rebase_every=ef_rebase_every,
-                                  ef_rebase_threshold=ef_rebase_threshold)
+        return make_gossip_mixer(None, mesh, "node", param_specs,
+                                 compression, schedule=schedule,
+                                 ef_rebase_every=ef_rebase_every,
+                                 ef_rebase_threshold=ef_rebase_threshold)
 
     def put_state(state):
         def _put(x):
@@ -113,7 +114,7 @@ def _hierarchical_mixer(graph, kwargs, num_nodes, replicas, seed):
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from repro.core import make_hierarchical_mixer
+    from repro.comm import make_gossip_mixer
     from repro.graphs import (
         build_graph,
         metropolis_weights,
@@ -131,8 +132,8 @@ def _hierarchical_mixer(graph, kwargs, num_nodes, replicas, seed):
 
     def make(params_tree):
         param_specs = jax.tree.map(lambda _: P("node"), params_tree)
-        return make_hierarchical_mixer(decomp, mesh, "node", "replica",
-                                       param_specs)
+        return make_gossip_mixer(decomp, mesh, "node", param_specs,
+                                 replica_axis="replica")
 
     def put_state(state):
         def _put(x):
@@ -175,7 +176,7 @@ def run_decentralized(dataset: str, *, robust: bool, mu: float = 6.0,
     see EXPERIMENTS.md §Paper-repro).
 
     ``lowering="gossip"`` runs the consensus on the ppermute lowering
-    (``repro.dynamics.DynamicGossipMixer`` — one node per device shard):
+    (``make_gossip_mixer(..., schedule=...)`` — one node per device shard):
     memoryless masked int8 wire for ``error_feedback=False`` configs, the
     error-feedback wire with ``hat_mix`` re-basing every
     ``ef_rebase_every`` rounds otherwise.

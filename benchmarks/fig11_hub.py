@@ -14,7 +14,7 @@ DR-DSGD μ = 3):
   (W = 11ᵀ/K, the ρ = 0 endpoint): K uploads + K downloads per round, the
   most wire per round and the fastest consensus (disagreement snaps to
   float noise each round).
-* **hub H = 4 (FedAvg)** — ``LocalUpdateMixer(HubMixer(K), 4)``: 4 local
+* **hub H = 4 (FedAvg)** — ``LocalUpdateMixer(make_hub_mixer(K), 4)``: 4 local
   steps between server rounds cuts cumulative wire 4× at the price of
   client drift under the non-IID split.
 * **hub H = 4 + gradient tracking (SCAFFOLD)** — the tracker correction

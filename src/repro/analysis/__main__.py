@@ -22,9 +22,8 @@ def _audit_smoke(devices: int) -> int:
 
     from repro.analysis.audit import audit_mixer, audit_train_step
     from repro.comm import CompressionConfig
-    from repro.core.consensus import make_dense_mixer, make_gossip_mixer
+    from repro.comm.mixers import make_dense_mixer, make_gossip_mixer
     from repro.core.spec import TrainerSpec
-    from repro.dynamics.mixers import DynamicGossipMixer
     from repro.dynamics.schedule import StaticSchedule
     from repro.graphs import metropolis_weights, permutation_decomposition
     from repro.graphs.topology import ring_graph
@@ -47,10 +46,10 @@ def _audit_smoke(devices: int) -> int:
                            compression=CompressionConfig(kind="int8")),
          None),
         ("dynamic-ef",
-         DynamicGossipMixer(
-             StaticSchedule(w), mesh, "node", specs,
-             quantized=CompressionConfig(kind="int8", error_feedback=True),
-             ef_rebase_every=4),
+         make_gossip_mixer(
+             None, mesh, "node", specs,
+             CompressionConfig(kind="int8", error_feedback=True),
+             schedule=StaticSchedule(w), ef_rebase_every=4),
          None),
     ]
     for name, mixer, state in targets:

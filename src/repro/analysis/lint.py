@@ -11,7 +11,7 @@ checkpoints.  These rules encode the repo's own discipline:
           ``float()`` / ``int()`` / ``bool()`` / ``.item()`` /
           ``np.asarray()`` / ``np.array()`` on something derived from a
           traced argument.  These sync the device or crash under jit.
-  RPR003  A Mixer subclass whose ``init_state`` populates a non-trivial
+  RPR003  A ``Mixer`` subclass whose ``init_state`` populates a non-trivial
           ``CommState`` field without a ``state_specs`` (own or inherited
           in-module) declaring that field's partitioning — the field would
           silently fall back to the trivial spec under pjit.
@@ -44,7 +44,8 @@ Suppression: append ``# repro: noqa`` (all rules) or
 justification nearby.
 
 Traced regions are found statically: ``__call__``/``_mix``/``mix_tree``
-methods of Mixer classes, the traced layer methods of ``*Topology`` /
+methods of Mixer classes (those deriving from ``Mixer`` or
+``ComposedMixer``), the traced layer methods of ``*Topology`` /
 ``*Transport`` / ``*Wire`` classes (``round_w``; ``apply_w``/``apply``/
 ``node_index``; ``encode_leaf``/``compress_block``/``rate``/
 ``next_sched_state``/``round_wire_bits``/``gamma_for``), functions named
@@ -188,15 +189,27 @@ def _function_index(tree: ast.Module):
     return module_fns, classes
 
 
-def _is_mixer_class(cls: ast.ClassDef, classes: dict) -> bool:
+# the consensus-protocol bases: a class deriving from one of them (by name,
+# directly or through classes of the same module) is a mixer — the wrappers
+# (RepeatMixer, LocalUpdateMixer) and ComposedMixer included, whatever the
+# subclass is called
+_MIXER_BASES = {"Mixer", "ComposedMixer"}
+
+
+def _is_mixer_class(cls: ast.ClassDef, classes: dict,
+                    seen: frozenset = frozenset()) -> bool:
+    if cls.name in _MIXER_BASES:
+        return True
     for base in cls.bases:
         chain = _attr_chain(base)
         name = chain[-1] if chain else ""
-        if "Mixer" in name or "Mixer" in cls.name:
+        if name in _MIXER_BASES:
             return True
-        if name in classes and _is_mixer_class(classes[name][0], classes):
+        if (name in classes and name not in seen
+                and _is_mixer_class(classes[name][0], classes,
+                                    seen | {cls.name})):
             return True
-    return "Mixer" in cls.name
+    return False
 
 
 def _find_traced_functions(tree: ast.Module):

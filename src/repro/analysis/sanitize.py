@@ -28,6 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import checkify
 
+from repro.comm.composed import ComposedMixer
+from repro.comm.topology import gather_round_vectors
+from repro.comm.transport import GossipTransport
+from repro.comm.wire import CodecWire
+
 # Doubly-stochastic tolerance: renormalized dropout weights accumulate a few
 # ulps per row; 1e-4 is ~3 orders above observed f32 noise and well below
 # any real renormalization bug (a single dropped-and-unreturned link shifts
@@ -48,14 +53,25 @@ def _unwrap(mixer):
     return mixer
 
 
-def _round_w(target, prev_comm):
-    """The (K, K) mixing matrix the round ran under, or None."""
-    if hasattr(target, "_round_topology_w"):
-        # dynamic lowerings: the traced W_r of THIS round (prev_comm.rounds
-        # is the counter value the mixer read when it gathered weights)
-        return target._round_topology_w(prev_comm.rounds)
-    w = getattr(target, "w", None)
-    return None if w is None else jnp.asarray(w, jnp.float32)
+def _round_w(target: ComposedMixer, prev_comm):
+    """The (K, K) mixing matrix the round ran under, or None where the
+    stack has no topology layer (identity; static gossip keeps its W in
+    the decomposition weights)."""
+    if target.topo is None:
+        return None
+    if target.topo.time_varying:
+        # the traced W_r of THIS round (prev_comm.rounds is the counter
+        # value the mixer read when it gathered weights)
+        return target.topo.round_w(prev_comm.rounds)
+    return jnp.asarray(target._round_w(prev_comm), jnp.float32)
+
+
+def _gathers_masks(target: ComposedMixer) -> bool:
+    """Time-varying gossip on the identity or masked-quant wire gathers
+    {0, 1} link masks out of W_r; the clocked EF stack gets no such check."""
+    return (target.topo.time_varying
+            and isinstance(target.transport, GossipTransport)
+            and not isinstance(target.wire, CodecWire))
 
 
 def check_doubly_stochastic(w) -> None:
@@ -111,12 +127,11 @@ def check_masks_binary(masks) -> None:
             "sanitize: matching %d link mask is not in {{0, 1}}" % i)
 
 
-def check_rate_in_container(target, prev_comm) -> None:
-    rate_fn = getattr(target, "_rate", None)
-    compression = getattr(target, "compression", None)
-    if rate_fn is None or compression is None:
+def check_rate_in_container(wire, prev_comm) -> None:
+    compression = wire.compression
+    if compression is None:
         return
-    rate = rate_fn(prev_comm)
+    rate = wire.rate(prev_comm)
     if rate is None:
         return
     if compression.kind in ("int8", "int4"):
@@ -141,12 +156,14 @@ def step_checks(mixer, prev_comm, theta_mixed, comm) -> None:
       comm: the CommState the round produced.
     """
     target = _unwrap(mixer)
+    stack = isinstance(target, ComposedMixer)
     check_finite_tree(theta_mixed, "mixed params at ")
-    w = _round_w(target, prev_comm)
+    w = _round_w(target, prev_comm) if stack else None
     if w is not None:
         check_doubly_stochastic(w)
-        if hasattr(target, "_round_vectors"):
-            _, _, masks = target._round_vectors(w)
+        if _gathers_masks(target):
+            _, _, masks = gather_round_vectors(w, target.transport.perm_idx)
             check_masks_binary(masks)
     check_choco_invariant(comm)
-    check_rate_in_container(target, prev_comm)
+    if stack:
+        check_rate_in_container(target.wire, prev_comm)

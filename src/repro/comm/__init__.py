@@ -20,11 +20,10 @@ wire.py        — WHAT crosses each link: identity, memoryless codec,
                  CHOCO error feedback (± delta/re-base clock), masked
                  int8/int4 Pallas — each owning its ``CommState`` fields.
 composed.py    — :class:`ComposedMixer`: one consensus operator over a
-                 (topology, transport, wire) stack; all legacy mixer
-                 classes are constructor shims over it.
-mixers.py      — those shims for the compressed stacks
-                 (:class:`CompressedDenseMixer`,
-                 :class:`CompressedGossipMixer`).
+                 (topology, transport, wire) stack.
+mixers.py      — the four factories that build every stack:
+                 ``make_dense_mixer`` / ``make_gossip_mixer`` /
+                 ``make_hub_mixer`` / ``make_identity_mixer``.
 
 The fused Pallas quantize/dequantize-accumulate kernel lives in
 ``repro.kernels.quant_gossip`` and plugs in via
@@ -47,9 +46,10 @@ from repro.comm.compressors import (
     quant_bits,
 )
 from repro.comm.mixers import (
-    CompressedDenseMixer,
-    CompressedGossipMixer,
-    ef_residual,
+    make_dense_mixer,
+    make_gossip_mixer,
+    make_hub_mixer,
+    make_identity_mixer,
 )
 from repro.comm.protocol import (
     CommMetrics,
@@ -78,7 +78,8 @@ from repro.comm.wire import (
     MaskedQuantWire,
     RebaseClock,
     Wire,
-    make_codec_wire,
+    ef_residual,
+    make_wire,
 )
 
 __all__ = [
@@ -87,7 +88,8 @@ __all__ = [
     "TopKCompressor", "RandKCompressor",
     "Mixer", "CommMetrics", "CommState",
     "trivial_comm_state", "trivial_state_specs",
-    "CompressedDenseMixer", "CompressedGossipMixer",
+    "make_dense_mixer", "make_gossip_mixer", "make_hub_mixer",
+    "make_identity_mixer",
     "ef_residual", "per_node_keys", "fold_leaf", "quant_bits",
     "ScheduleConfig", "CompressionSchedule",
     # layer API
@@ -95,5 +97,5 @@ __all__ = [
     "Topology", "StaticTopology", "ScheduledTopology", "StarTopology",
     "Transport", "DenseTransport", "GossipTransport", "StarTransport",
     "Wire", "IdentityWire", "CodecWire", "ChocoWire", "MaskedQuantWire",
-    "RebaseClock", "make_codec_wire",
+    "RebaseClock", "make_wire",
 ]

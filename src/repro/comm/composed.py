@@ -13,11 +13,11 @@ It is now ONE operator assembled from three orthogonal layers:
   int8/int4 Pallas — each owning exactly the ``CommState`` fields it
   declares.
 
-The legacy class names survive as thin constructor shims assembling layer
-stacks (``DenseMixer = Static × Dense × Identity``, ...), which keeps
-``obs:consensus/<name>`` scopes, isinstance relationships, and constructor
-signatures intact; every shipped stack is bit-exact against its
-pre-refactor trajectory (``tests/data/mixer_anchors.json`` gates all 22).
+The four factories of ``comm/mixers.py`` are the only constructors; each
+labels its stack's device scope ``obs:consensus/<name>`` (``dense``,
+``gossip``, ``hub``, ``identity``).  Every shipped stack is bit-exact
+against its trajectory anchor (``tests/data/mixer_anchors.json`` gates
+all 22).
 
 Round bodies (the traced code below) are the frozen pre-refactor paths:
 
@@ -35,11 +35,9 @@ choco+clock × sched×gossip  :meth:`_clocked_gossip_call` (delta/re-base
                             two-mode ``lax.cond`` on ``ef_rounds``)
 ==========================  ==============================================
 
-Sanitizer duck-typing contract (``repro.analysis.sanitize``): the
-*instance* attributes ``_round_topology_w`` (time-varying stacks only) and
-``_round_vectors`` (dynamic gossip with identity/masked wires only) are
-assigned per-stack in ``__init__`` — ``hasattr`` gating must match the
-legacy classes exactly, or the sanitized program changes shape.
+The round bodies read the layers (``self.transport.perms``,
+``self.wire.gamma``, ...); nothing is copied onto the mixer but the static
+dense W, cast once to the transport's ``compute_dtype`` at construction.
 """
 
 from __future__ import annotations
@@ -58,7 +56,6 @@ from repro.comm.protocol import (
     trivial_state_specs,
 )
 from repro.comm.topology import (
-    StarTopology,
     Topology,
     active_links,
     active_sends,
@@ -72,7 +69,6 @@ from repro.comm.transport import (
     gossip_mix_local,
 )
 from repro.comm.wire import (
-    ChocoWire,
     CodecWire,
     MaskedQuantWire,
     Wire,
@@ -87,107 +83,50 @@ from repro.utils.tree import tree_bytes
 class ComposedMixer(Mixer):
     """One consensus operator over a (topology, transport, wire) stack.
 
-    ``topology=None`` + ``transport=None`` is the no-communication stack
-    (IdentityMixer); ``topology=None`` with a gossip transport is the
-    static-gossip stack (the W lives frozen in the decomposition weights).
-    Legacy attribute surface (``.k``, ``.w``, ``.gamma``, ``.topology`` =
-    the TopologySchedule, ``.faults``, ``.perms``, ...) is mirrored from
-    the layers at construction so external duck-typing (sanitize, audit,
-    benchmarks, tests) keeps working unchanged.
+    ``topology=None`` + ``transport=None`` is the no-communication stack;
+    ``topology=None`` with a gossip transport is the static-gossip stack
+    (the W lives frozen in the decomposition weights).  ``name`` labels the
+    ``obs:consensus/<name>`` scope; the factories set it.
     """
+
+    name = "composed"
 
     def __init__(self, topology: Topology | None,
                  transport: Transport | None, wire: Wire):
         self.topo = topology
         self.transport = transport
         self.wire = wire
-        dynamic = topology is not None and topology.time_varying
-        self._dynamic = dynamic
-        self._is_gossip = isinstance(transport, GossipTransport)
+        if isinstance(transport, DenseTransport) and not self._dynamic:
+            # the one construction-time cast: the static W is materialized
+            # once at compute_dtype
+            self.w = jnp.asarray(topology.base_weights(),
+                                 transport.compute_dtype)
 
-        if topology is not None:
-            self.k = topology.k
-        elif transport is not None:
-            self.k = transport.k
+    # -- what the stack is ------------------------------------------------------
 
-        if dynamic:
-            # legacy names: .topology is the TopologySchedule (tests mutate
-            # it), .faults the enabled FaultConfig or None; the sanitizer
-            # duck-types on the hasattr of _round_topology_w (instance
-            # attribute — static stacks must NOT grow it)
-            self.topology = topology.schedule
-            self.faults = topology.faults
-            self._round_topology_w = topology.round_w
+    @property
+    def k(self) -> int:
+        layer = self.topo if self.topo is not None else self.transport
+        if layer is None:
+            raise AttributeError("the no-communication stack has no K")
+        return layer.k
 
-        if isinstance(transport, DenseTransport):
-            self.compute_dtype = transport.compute_dtype
-            if not dynamic:
-                # DenseMixer's historical construction-time cast: the
-                # static W is materialized once at compute_dtype
-                self.w = jnp.asarray(topology.base_weights(),
-                                     transport.compute_dtype)
-        elif isinstance(transport, StarTransport):
-            self.w = jnp.asarray(topology.base_weights(), jnp.float32)
-        elif self._is_gossip:
-            t = transport
-            self.mesh = t.mesh
-            self.axis = t.axis
-            self.param_specs = t.param_specs
-            self.perms = t.perms
-            self.replica_axis = t.replica_axis
-            self.self_w = t.self_w
-            self.match_ws = t.match_ws
-            self.decomp = t.decomp
-            self._p_node = t._p_node
-            self._perm_idx = t._perm_idx
-            if dynamic and not isinstance(wire, CodecWire):
-                # sanitize's mask-binariness check keys off this hasattr;
-                # the clocked EF stack deliberately does not expose it
-                self._round_vectors = partial(gather_round_vectors,
-                                              perm_idx=t._perm_idx)
-            if topology is not None and dynamic and topology.k != t.k:
-                raise ValueError(
-                    f"topology K={topology.k} != transport K={t.k}")
+    @property
+    def _dynamic(self) -> bool:
+        return self.topo is not None and self.topo.time_varying
 
-        if isinstance(wire, CodecWire):
-            if transport is None:
-                raise ValueError("a codec wire needs a transport")
-            if isinstance(transport, StarTransport):
-                raise ValueError(
-                    "codec wires on the hub stack ride the dense transport "
-                    "with the star W (see make_hub_mixer)")
-            self.compressor = wire.compressor
-            self.gamma = wire.gamma
-            self.ef = wire.ef
-            self.schedule = wire.schedule
-            clock = getattr(wire, "clock", None)
-            if clock is not None:
-                if not (self._is_gossip and dynamic):
-                    raise ValueError(
-                        "the delta/re-base clock serves the dynamic gossip "
-                        "stack (incremental hat_mix cache); dense re-mixes "
-                        "the full public-copy matrix every round")
-                self.adaptive = clock.adaptive
-                self.ef_rebase_every = int(clock.every)
-                self.ef_rebase_threshold = float(clock.threshold)
-        elif isinstance(wire, MaskedQuantWire):
-            if not (self._is_gossip and dynamic):
-                raise ValueError(
-                    "the masked quant wire rides the dynamic gossip "
-                    "transport (per-round link masks)")
-            self.quantized = wire.quantized
-            self._qmax = wire._qmax
-            self._compressor = wire.compressor
-        if isinstance(transport, StarTransport) and dynamic:
-            raise ValueError(
-                "the hub stack has no fault/schedule model yet — "
-                "the star topology is static (ROADMAP: federated faults)")
-
-    # -- layer delegation (legacy method surface) ------------------------------
+    @property
+    def _is_gossip(self) -> bool:
+        return isinstance(self.transport, GossipTransport)
 
     @property
     def compression(self):
         return self.wire.compression
+
+    @property
+    def compressor(self):
+        """The codec of a compressed wire (None on the identity wire)."""
+        return getattr(self.wire, "compressor", None)
 
     @property
     def traced_wire(self) -> bool:
@@ -195,32 +134,15 @@ class ComposedMixer(Mixer):
             return True
         return bool(self.wire.traced_wire)
 
-    def _rate(self, state: CommState):
-        """Traced codec rate for the round about to run (None = static) —
-        also the sanitizer's rate-in-container hook."""
-        return self.wire.rate(state)
-
-    def _next_sched_state(self, state: CommState, res_norm):
-        return self.wire.next_sched_state(state, res_norm)
-
-    def _round_wire_bits(self, params, rate, senders):
-        return self.wire.round_wire_bits(params, rate, senders, self.k)
-
-    def _encode_leaf(self, x, hat, keys, rate, send_mask=None):
-        return self.wire.encode_leaf(x, hat, keys, rate, send_mask=send_mask)
-
-    def _node_index(self):
-        return self.transport.node_index()
-
     def _round_w(self, state: CommState):
-        """The mixing matrix of the codec-dense round about to run: static
-        W, or the schedule's traced per-round matrix — EF composes with a
-        moving W exactly on this lowering because it re-mixes the full
+        """The mixing matrix of the round about to run: the static W, or
+        the topology's traced per-round matrix — EF composes with a moving
+        W exactly on the dense lowering because it re-mixes the full
         public-copy matrix every round (no incremental cache to go stale).
         """
-        if self._dynamic:
-            return self.topo.round_w(state.rounds)
-        return self.w
+        if isinstance(self.transport, DenseTransport) and not self._dynamic:
+            return self.w
+        return self.topo.round_w(state.rounds)
 
     def _senders(self, w):
         """Wire-accounting sender count: every node injects once on the
@@ -256,17 +178,17 @@ class ComposedMixer(Mixer):
         if t is None:
             return 0
         if isinstance(self.wire, MaskedQuantWire):
-            sends = sum(len(pairs) for pairs in self.perms)
+            sends = sum(len(pairs) for pairs in t.perms)
             per_node = sum(self.wire.leaf_bits(x.size // self.k)
                            for x in jax.tree.leaves(params)) / 8.0
             return round(sends * per_node)
         if isinstance(self.wire, CodecWire):
-            q = _leaf_payload_bytes(self.compressor, params, self.k)
+            q = _leaf_payload_bytes(self.wire.compressor, params, self.k)
             if not self._is_gossip:
                 # dense codec: every node injects its payload once
                 return self.k * q
-            sends = sum(len(pairs) for pairs in self.perms)
-            clock = getattr(self.wire, "clock", None)
+            sends = sum(len(pairs) for pairs in t.perms)
+            clock = self.wire.clock
             if clock is None:
                 return sends * q
             # clocked EF: fault-free amortized estimate over the FULL union
@@ -296,7 +218,7 @@ class ComposedMixer(Mixer):
                 return sends * tree_bytes(params) // self.k
             # uncompressed static dense: every node injects its block once
             return tree_bytes(params)
-        sends = sum(len(pairs) for pairs in self.perms)
+        sends = sum(len(pairs) for pairs in t.perms)
         return sends * tree_bytes(params) // self.k
 
     def wire_dtype_bytes(self, params) -> dict[str, float] | None:
@@ -305,7 +227,7 @@ class ComposedMixer(Mixer):
         the ``audit_wire`` contract)."""
         if not self._is_gossip:
             return None
-        sends = sum(len(pairs) for pairs in self.perms)
+        sends = sum(len(pairs) for pairs in self.transport.perms)
         if isinstance(self.wire, MaskedQuantWire):
             # the masked wire always moves the full union-support buffers,
             # and the int4 rate rides the int8 *container*: per-entry
@@ -316,13 +238,13 @@ class ComposedMixer(Mixer):
                 d = x.size // self.k
                 out["s8"] = out.get("s8", 0.0) + sends * d
                 out["f32"] = out.get("f32", 0.0) \
-                    + sends * 4.0 * self._compressor._n_blocks(d)
+                    + sends * 4.0 * self.wire.compressor._n_blocks(d)
             return out
         if isinstance(self.wire, CodecWire):
             delta = _merge_dtype_bytes(*[
-                _codec_wire_dtypes(self.compressor, x.size // self.k)
+                _codec_wire_dtypes(self.wire.compressor, x.size // self.k)
                 for x in jax.tree.leaves(params)], scale=sends)
-            clock = getattr(self.wire, "clock", None)
+            clock = self.wire.clock
             if clock is None:
                 return delta
             # both lax.cond modes live in the program when both can run:
@@ -353,7 +275,7 @@ class ComposedMixer(Mixer):
             return t.apply(theta)
         if isinstance(t, DenseTransport):
             return t.apply_w(self.w, theta)
-        return self._plain_gossip(theta, self.self_w, self.match_ws)
+        return self._plain_gossip(theta, t.self_w, t.match_ws)
 
     def _plain_gossip(self, theta, self_w, match_ws):
         t = self.transport
@@ -374,8 +296,8 @@ class ComposedMixer(Mixer):
         return jax.shard_map(
             body,
             mesh=t.mesh,
-            in_specs=(t.param_specs, t._p_node,
-                      [t._p_node] * len(match_ws)),
+            in_specs=(t.param_specs, t.p_node,
+                      [t.p_node] * len(match_ws)),
             out_specs=t.param_specs,
         )(theta, self_w, list(match_ws))
 
@@ -390,7 +312,8 @@ class ComposedMixer(Mixer):
             w = self.topo.round_w(state.rounds)
             if isinstance(self.transport, DenseTransport):
                 return self.transport.apply_w(w, tree)
-            self_w, match_ws, _ = gather_round_vectors(w, self._perm_idx)
+            self_w, match_ws, _ = gather_round_vectors(
+                w, self.transport.perm_idx)
             return self._plain_gossip(tree, self_w, match_ws)
         return self._mix(tree)
 
@@ -398,9 +321,9 @@ class ComposedMixer(Mixer):
 
     def __call__(self, theta, state: CommState, *, round=None):
         if isinstance(self.wire, CodecWire):
-            if self._is_gossip and getattr(self.wire, "clock", None) is not None:
+            if self.wire.clock is not None:
                 return self._clocked_gossip_call(theta, state)
-            with jax.named_scope(f"obs:consensus/{type(self).__name__}"):
+            with jax.named_scope(f"obs:consensus/{self.name}"):
                 if self._is_gossip:
                     return self._gossip_round(theta, state)
                 return self._dense_round(theta, state)
@@ -413,7 +336,7 @@ class ComposedMixer(Mixer):
     # -- identity-wire dynamic rounds ------------------------------------------
 
     def _dynamic_dense_call(self, theta, state: CommState):
-        with jax.named_scope(f"obs:consensus/{type(self).__name__}"):
+        with jax.named_scope(f"obs:consensus/{self.name}"):
             w = self.topo.round_w(state.rounds)
             mixed = self.transport.apply_w(w, theta)
         per_node_bits = 8.0 * (tree_bytes(theta) // self.k)
@@ -423,12 +346,13 @@ class ComposedMixer(Mixer):
         )
 
     def _dynamic_gossip_call(self, theta, state: CommState):
-        quantized = getattr(self, "quantized", None)
-        with jax.named_scope(f"obs:consensus/{type(self).__name__}"):
+        masked = isinstance(self.wire, MaskedQuantWire)
+        with jax.named_scope(f"obs:consensus/{self.name}"):
             w = self.topo.round_w(state.rounds)
-            self_w, match_ws, masks = gather_round_vectors(w, self._perm_idx)
+            self_w, match_ws, masks = gather_round_vectors(
+                w, self.transport.perm_idx)
             key = state.key
-            if quantized is None:
+            if not masked:
                 mixed = self._plain_gossip(theta, self_w, match_ws)
                 per_node_bits = 8.0 * (tree_bytes(theta) // self.k)
             else:
@@ -451,7 +375,7 @@ class ComposedMixer(Mixer):
         from repro.kernels.quant_gossip.ops import quant_gossip_round
 
         t = self.transport
-        cfg = self.quantized
+        cfg = self.wire.quantized
 
         def body(tr, sw, mws, mks, k0):
             leaves, treedef = jax.tree.flatten(tr)
@@ -462,12 +386,12 @@ class ComposedMixer(Mixer):
                 xf = x.reshape(k_local, d).astype(jnp.float32)
                 acc = xf * sw[:, None]
                 lk = jax.random.fold_in(
-                    jax.random.fold_in(k0, i), self._node_index())
+                    jax.random.fold_in(k0, i), t.node_index())
                 for m, (pw, mk, perm) in enumerate(
                         zip(mws, mks, t.perms)):
                     acc = quant_gossip_round(
                         xf, acc, pw, t.axis, perm,
-                        jax.random.fold_in(lk, m), mask=mk, qmax=self._qmax,
+                        jax.random.fold_in(lk, m), mask=mk, qmax=self.wire.qmax,
                         block_d=cfg.block_d, interpret=cfg.interpret,
                         use_kernel=cfg.use_kernel)
                 out.append(acc.reshape(x.shape).astype(x.dtype))
@@ -479,8 +403,8 @@ class ComposedMixer(Mixer):
             body,
             mesh=t.mesh,
             check_vma=False,
-            in_specs=(t.param_specs, t._p_node,
-                      [t._p_node] * n, [t._p_node] * n, p_rep),
+            in_specs=(t.param_specs, t.p_node,
+                      [t.p_node] * n, [t.p_node] * n, p_rep),
             out_specs=t.param_specs,
         )(theta, self_w, list(match_ws), list(masks), key)
 
@@ -489,7 +413,7 @@ class ComposedMixer(Mixer):
     def _dense_round(self, theta, state: CommState):
         w = self._round_w(state)
         key, sub = jax.random.split(state.key)
-        rate = self._rate(state)
+        rate = self.wire.rate(state)
         gamma = self.wire.gamma_for(rate)
         node_ks = per_node_keys(sub, jnp.arange(self.k))
         # a time-varying W can isolate a node: like the gossip lowering's
@@ -499,26 +423,27 @@ class ComposedMixer(Mixer):
             off_diag = w * (1.0 - jnp.eye(self.k, dtype=w.dtype))
             send = jnp.any(off_diag != 0, axis=1).astype(jnp.float32)
         leaves, treedef = jax.tree.flatten(theta)
-        hats = (treedef.flatten_up_to(state.hat) if self.ef
+        ef = self.wire.ef
+        hats = (treedef.flatten_up_to(state.hat) if ef
                 else [() for _ in leaves])
         out_theta, out_hat = [], []
         res_sq = jnp.float32(0.0)
         for i, (x, h) in enumerate(zip(leaves, hats)):
             k = x.shape[0]
             xf = x.reshape(k, -1).astype(jnp.float32)
-            hf = h.reshape(k, -1) if self.ef else None
-            if self.ef:
+            hf = h.reshape(k, -1) if ef else None
+            if ef:
                 res_sq = res_sq + jnp.sum(jnp.square(xf - hf))
-            _, public, new_hat = self._encode_leaf(
+            _, public, new_hat = self.wire.encode_leaf(
                 xf, hf, fold_leaf(node_ks, i), rate, send_mask=send)
             mixed = jnp.einsum(
                 "kl,ld->kd", w, public,
                 precision=jax.lax.Precision.HIGHEST)
             out = xf + gamma * (mixed - public)
             out_theta.append(out.reshape(x.shape).astype(x.dtype))
-            if self.ef:
+            if ef:
                 out_hat.append(new_hat.reshape(x.shape))
-        res_norm, res_ref, rounds = self._next_sched_state(
+        res_norm, res_ref, rounds = self.wire.next_sched_state(
             state, jnp.sqrt(res_sq))
         unflat = treedef.unflatten
         # _replace, not CommState(...): fields this round does not own
@@ -526,10 +451,10 @@ class ComposedMixer(Mixer):
         # an explicit construction silently resets any field added later
         # (the PR-4/PR-5 bug class; repro.analysis lint RPR005 enforces it)
         return unflat(out_theta), state._replace(
-            hat=unflat(out_hat) if self.ef else (), key=key,
+            hat=unflat(out_hat) if ef else (), key=key,
             res_norm=res_norm, res_ref=res_ref, rounds=rounds,
-            wire_bits=self._round_wire_bits(theta, rate,
-                                            senders=self._senders(w)))
+            wire_bits=self.wire.round_wire_bits(theta, rate,
+                                                self._senders(w), self.k))
 
     def _gossip_round(self, theta, state: CommState, *, self_w=None,
                       match_ws=None, masks=None, senders=None):
@@ -545,11 +470,11 @@ class ComposedMixer(Mixer):
         """
         t = self.transport
         key, sub = jax.random.split(state.key)
-        rate = self._rate(state)
+        rate = self.wire.rate(state)
         p_node = jax.sharding.PartitionSpec(t.axis)
         p_rep = jax.sharding.PartitionSpec()
         specs = t.param_specs
-        ef = self.ef
+        ef = self.wire.ef
         have_rate = rate is not None
         have_masks = masks is not None
         if self_w is None:
@@ -564,7 +489,7 @@ class ComposedMixer(Mixer):
             leaves, treedef = jax.tree.flatten(tr)
             k_local = leaves[0].shape[0] if leaves else 1
             # global node ids of the local rows -> dense-identical keys
-            rows = self._node_index() * k_local + jnp.arange(k_local)
+            rows = t.node_index() * k_local + jnp.arange(k_local)
             node_ks = per_node_keys(k0, rows)
             hats = (treedef.flatten_up_to(hat) if ef
                     else [() for _ in leaves])
@@ -582,7 +507,7 @@ class ComposedMixer(Mixer):
                 if ef:
                     res_sq = res_sq + jnp.sum(
                         jnp.square(xf - h.reshape(k_local, d)))
-                payload, public, new_hat = self._encode_leaf(
+                payload, public, new_hat = self.wire.encode_leaf(
                     xf, h.reshape(k_local, d) if ef else None,
                     fold_leaf(node_ks, i), r_op, send_mask=send)
                 # EF: s_i += W_ii q_i + Σ_m W_i,perm(i)·dequant(recv) keeps
@@ -621,7 +546,7 @@ class ComposedMixer(Mixer):
         t2, h2, s2, res_sq = shard(theta, state.hat, state.hat_mix,
                                    self_w, match_ws, mask_args, sub,
                                    rate_op)
-        res_norm, res_ref, rounds = self._next_sched_state(
+        res_norm, res_ref, rounds = self.wire.next_sched_state(
             state, jnp.sqrt(res_sq))
         if senders is None:
             senders = sum(len(pairs) for pairs in t.perms)
@@ -629,7 +554,8 @@ class ComposedMixer(Mixer):
         return t2, state._replace(
             hat=h2, hat_mix=s2, key=key,
             res_norm=res_norm, res_ref=res_ref, rounds=rounds,
-            wire_bits=self._round_wire_bits(theta, rate, senders=senders))
+            wire_bits=self.wire.round_wire_bits(theta, rate, senders,
+                                                self.k))
 
     def _accumulate(self, acc, payload, weight, d, mask=None):
         """acc + weight·dequant(payload), with an optional traced link mask.
@@ -640,16 +566,16 @@ class ComposedMixer(Mixer):
         passthrough bitwise (and lets a mask-consulting transport skip the
         payload entirely).  ``mask=None``/all-ones are bit-identical.
         """
+        codec = self.wire.compressor
         if mask is None:
-            fused = getattr(self.compressor, "accumulate", None)
+            fused = getattr(codec, "accumulate", None)
             if fused is not None:
                 return fused(acc, payload, weight)
-            return acc + weight * self.compressor.decompress(payload, d)
-        fused = getattr(self.compressor, "accumulate_masked", None)
+            return acc + weight * codec.decompress(payload, d)
+        fused = getattr(codec, "accumulate_masked", None)
         if fused is not None:
             return fused(acc, payload, weight, mask)
-        return acc + (weight * mask[:, None]) * self.compressor.decompress(
-            payload, d)
+        return acc + (weight * mask[:, None]) * codec.decompress(payload, d)
 
     # -- the clocked EF gossip stack (delta / re-base two-mode) ----------------
 
@@ -669,9 +595,11 @@ class ComposedMixer(Mixer):
         return jnp.sqrt(total)
 
     def _clocked_gossip_call(self, theta, state: CommState):
-        with jax.named_scope(f"obs:consensus/{type(self).__name__}"):
+        clock = self.wire.clock
+        with jax.named_scope(f"obs:consensus/{self.name}"):
             w = self.topo.round_w(state.rounds)
-            self_w, match_ws, masks = gather_round_vectors(w, self._perm_idx)
+            self_w, match_ws, masks = gather_round_vectors(
+                w, self.transport.perm_idx)
             senders = active_sends(masks)
 
             def delta(tr, st):
@@ -683,18 +611,18 @@ class ComposedMixer(Mixer):
                 return self._rebase_round(tr, st, self_w, match_ws, masks,
                                           senders)
 
-            if self.adaptive:
+            if clock.adaptive:
                 # drift-triggered re-base: measure the cache staleness
                 # against THIS round's W before mixing and re-base this
                 # round when it exceeds the threshold.  Both modes live in
                 # one lax.cond program — the trigger is a traced operand,
                 # so a threshold sweep never recompiles.
                 drift = self._cache_drift(w, state.hat, state.hat_mix)
-                t2, s2 = jax.lax.cond(drift > self.ef_rebase_threshold,
+                t2, s2 = jax.lax.cond(drift > clock.threshold,
                                       rebase, delta, theta, state)
                 s2 = s2._replace(ef_drift=drift)
             else:
-                b = self.ef_rebase_every
+                b = clock.every
                 if b == 0:
                     t2, s2 = delta(theta, state)
                 elif b == 1:
@@ -715,7 +643,7 @@ class ComposedMixer(Mixer):
         """
         t = self.transport
         key, sub = jax.random.split(state.key)
-        rate = self._rate(state)
+        rate = self.wire.rate(state)
         p_node = jax.sharding.PartitionSpec(t.axis)
         p_rep = jax.sharding.PartitionSpec()
         specs = t.param_specs
@@ -727,7 +655,7 @@ class ComposedMixer(Mixer):
             send = _send_mask(mks)
             leaves, treedef = jax.tree.flatten(tr)
             k_local = leaves[0].shape[0] if leaves else 1
-            rows = self._node_index() * k_local + jnp.arange(k_local)
+            rows = t.node_index() * k_local + jnp.arange(k_local)
             node_ks = per_node_keys(k0, rows)
             hats = treedef.flatten_up_to(hat)
             o_t, o_h, o_s = [], [], []
@@ -741,7 +669,7 @@ class ComposedMixer(Mixer):
                     xf = jax.lax.psum(xf, t.replica_axis) / r
                 hf = h.reshape(k_local, d)
                 res_sq = res_sq + jnp.sum(jnp.square(xf - hf))
-                _, _, new_hat = self._encode_leaf(
+                _, _, new_hat = self.wire.encode_leaf(
                     xf, hf, fold_leaf(node_ks, i), r_op, send_mask=send)
                 acc = self_w[:, None] * new_hat
                 for pw, mk, perm in zip(match_ws, mks, t.perms):
@@ -767,7 +695,7 @@ class ComposedMixer(Mixer):
         rate_op = rate if have_rate else jnp.float32(0.0)
         t2, h2, s2, res_sq = shard(theta, state.hat, self_w, list(match_ws),
                                    list(masks), sub, rate_op)
-        res_norm, res_ref, rounds = self._next_sched_state(
+        res_norm, res_ref, rounds = self.wire.next_sched_state(
             state, jnp.sqrt(res_sq))
         # full-precision wire: active links × per-node f32 payload
         full_bits = 32.0 * sum(x.size // self.k

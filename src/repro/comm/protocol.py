@@ -72,7 +72,7 @@ class CommState(NamedTuple):
     ef_rounds: the error-feedback *consensus-round* clock of the dynamic
               compressed gossip lowering (int32): counts rounds the EF wire
               actually executed and drives the periodic ``hat_mix`` re-base
-              (``repro.dynamics.DynamicCompressedGossipMixer``, rebase when
+              (``repro.comm.wire.RebaseClock``, rebase when
               ``ef_rounds % B == B − 1``).  Deliberately distinct from
               ``rounds``, which wrapper mixers (``LocalUpdateMixer``)
               overwrite with the optimizer-step clock — the re-base cadence
@@ -81,7 +81,7 @@ class CommState(NamedTuple):
               padded — see ``repro.checkpoint.restore_train_state``).
     ef_drift: f32 — the measured staleness ‖s − W_r θ̂‖_F of the incremental
               ``hat_mix`` cache, maintained only by the *adaptive* re-base
-              mode of ``DynamicCompressedGossipMixer``
+              mode of the clocked EF gossip wire
               (``ef_rebase_threshold > 0``): each round measures the drift
               against the current topology and the next round re-bases when
               it exceeds the threshold.  () for every other mixer and for
@@ -147,6 +147,12 @@ class Mixer:
     compression = None
     traced_wire = False
 
+    @property
+    def name(self) -> str:
+        """Label of the device scope ``obs:consensus/<name>``: the class
+        name, unless the mixer (or its factory) says otherwise."""
+        return type(self).__name__
+
     # -- state ----------------------------------------------------------------
 
     def init_state(self, params) -> CommState:
@@ -194,7 +200,7 @@ class Mixer:
         ``CommState.rounds`` counter (which counts *consensus* rounds, a
         different clock under ``mix_every``/``repeat_mixer``).
         """
-        with jax.named_scope(f"obs:consensus/{type(self).__name__}"):
+        with jax.named_scope(f"obs:consensus/{self.name}"):
             mixed = self._mix(theta)
         return mixed, state._replace(
             rounds=state.rounds + 1,

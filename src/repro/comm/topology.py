@@ -125,8 +125,6 @@ class ScheduledTopology(Topology):
     time_varying = True
 
     def __init__(self, schedule, faults=None):
-        from repro.dynamics.faults import FaultConfig  # noqa: F401 (doc)
-
         self.schedule = schedule
         self.faults = faults if (faults is not None and faults.enabled) \
             else None

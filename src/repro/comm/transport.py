@@ -125,7 +125,7 @@ class GossipTransport(Transport):
     it feeds shard_map in/out specs so tensor-parallel dims stay sharded.
     Holds the frozen f32 decomposition weights (``self_w``/``match_ws``) —
     what the static stacks mix with, bit-identical to the pre-refactor
-    mixers — plus the static edge coloring ``_perm_idx`` the dynamic
+    mixers — plus the static edge coloring ``perm_idx`` the dynamic
     stacks gather per-round weights through.
     """
 
@@ -152,8 +152,8 @@ class GossipTransport(Transport):
         self.match_ws = [jnp.asarray(w, jnp.float32)
                          for w in decomp.matching_weights]
         self.perms = decomp.ppermute_pairs()
-        self._perm_idx = [np.asarray(p, np.int64) for p in decomp.matchings]
-        self._p_node = jax.sharding.PartitionSpec(self.axis)
+        self.perm_idx = [np.asarray(p, np.int64) for p in decomp.matchings]
+        self.p_node = jax.sharding.PartitionSpec(self.axis)
 
     def node_index(self):
         """Global node id of this shard (traced; inside shard_map only)."""

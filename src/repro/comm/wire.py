@@ -110,15 +110,40 @@ def _leaf_payload_bytes(compressor, params, k: int) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class RebaseClock:
-    """The delta/re-base cadence of the dynamic EF gossip stack.
+    """The delta/re-base cadence of EF gossip over a time-varying W.
 
-    every:     B — re-base the incremental ``hat_mix`` cache from
-               full-precision public copies every B-th executed consensus
-               round (``ef_rounds % B == B − 1``).  0 = never (static
-               fault-free schedules only), 1 = every round.
+    The static EF gossip stack keeps its incremental cache
+    s_i = Σ_j W_ij θ̂_j current by adding each round's received
+    innovations — valid **only under a static W**: the base term
+    Σ_j W_ij θ̂_j(r₀) goes stale the moment W moves.  The clock makes EF
+    sound on the traced per-round weights with a two-mode round, selected
+    by a second traced clock (``CommState.ef_rounds``, which counts
+    executed consensus rounds — wrappers overwrite ``rounds`` with the
+    step clock):
+
+    * **delta rounds** (all but every B-th): the static EF leaf path with
+      this round's gathered weights/masks — masked senders emit nothing and
+      freeze their θ̂, and the cache advances by current-W-weighted
+      increments, s_i += W_ii(r)·q_i + Σ_m W_{i,pm(i)}(r)·dequant(recv).
+      Every increment is weighted by a doubly-stochastic W_r, so
+      Σ_i s_i = Σ_i θ̂_i holds exactly however the topology moves; only the
+      bias of s_i against Σ_j W_ij(r) θ̂_j(r) drifts.
+    * **re-base rounds** (``ef_rounds % B == B − 1``): the codec still runs
+      (θ̂ advances), but the matchings exchange the full-precision public
+      copies and the cache is rebuilt exactly under the current weights.
+      The re-base wire is f32 on active links, amortized 1/B.
+
+    every:     B.  0 = never re-base: bit-exact to the static EF gossip
+               stack, so only legal for a constant schedule with no faults.
+               1 = re-base every round (matches the dense EF lowering,
+               which re-mixes full public copies each round).  B ≥ 2 = one
+               ``lax.cond`` selects the mode at run time; both modes live in
+               ONE compiled program.
     threshold: > 0 replaces the fixed clock with the drift proxy
-               ‖s − W_r θ̂‖_F measured each round (adaptive re-base; the
-               measurement lands in ``CommState.ef_drift``).
+               ‖s − W_r θ̂‖_F measured each round (exact — a (K, K) einsum
+               over the public copies): the round it exceeds the threshold
+               re-bases.  The measurement lands in ``CommState.ef_drift``;
+               the sanitizer's CHOCO-drift check doubles as its oracle.
     """
 
     every: int = 8
@@ -164,6 +189,7 @@ class CodecWire(Wire):
     stalls at the quantization noise floor — see ``comm/mixers.py``)."""
 
     ef = False
+    clock: RebaseClock | None = None
 
     def __init__(self, compression: CompressionConfig):
         self.compression = compression
@@ -265,7 +291,19 @@ class ChocoWire(CodecWire):
     s_i = Σ_j W_ij θ̂_j of the gossip lowering).  With a
     :class:`RebaseClock` it additionally owns the ``ef_rounds`` consensus
     clock (and ``ef_drift`` in adaptive mode) that selects delta vs
-    full-precision re-base rounds on the dynamic gossip stack.
+    full-precision re-base rounds on the time-varying gossip stack.
+
+    Over a time-varying W, EF composes *exactly* with the dense transport
+    (its round re-mixes the full public-copy matrix, so there is no cache
+    to go stale; a node with no live link this round mixes with row e_i and
+    ships its accumulated innovation on its next live round), and soundly
+    with the gossip transport only under the clock.  Under a constant
+    schedule with no faults the clock's delta rounds reproduce the static
+    gossip stack bit-for-bit: the gathered weights equal the frozen
+    decomposition weights and every mask is 1.  γ
+    (``CompressionConfig.resolved_gamma``) damps the correction as on a
+    static W; dropout shrinks each round's spectral gap, so under heavy
+    dropout prefer γ at or below the static recommendation.
     """
 
     ef = True
@@ -303,11 +341,13 @@ class ChocoWire(CodecWire):
 
 
 class MaskedQuantWire(Wire):
-    """Memoryless masked int8/int4 quantization for the dynamic gossip
+    """Memoryless masked int8/int4 quantization for the time-varying gossip
     transport: each matching runs the fused masked Pallas kernels,
     quantize(mask) → ppermute(int8 payload + scales) → masked
     dequantize-accumulate, with a fresh C(θ) every round (the int4 rate
-    rides the int8 container at a traced qmax).  Owns only ``key``.
+    rides the int8 container at a traced qmax).  The ablation that stalls
+    at the quantization noise floor; it keeps no cache, so there is nothing
+    to re-base.  Owns only ``key``.
     """
 
     ef = False
@@ -325,7 +365,7 @@ class MaskedQuantWire(Wire):
         # int4 rides the int8 container at qmax=7 (the masked kernel's
         # traced rate); payload accounting bills the effective bits,
         # like the scheduled-rate static path
-        self._qmax = 127 if quantized.kind == "int8" else 7
+        self.qmax = 127 if quantized.kind == "int8" else 7
         from repro.comm.compressors import KernelInt8Quantizer
 
         self.compressor = KernelInt8Quantizer(
@@ -342,19 +382,52 @@ class MaskedQuantWire(Wire):
         traced context; staging a constant would leak a tracer)."""
         import math
 
-        bits = math.ceil(math.log2(2 * self._qmax + 1))
+        bits = math.ceil(math.log2(2 * self.qmax + 1))
         # d is a leaf .size — host int, see docstring
         return float(bits * d + 32 * self.compressor._n_blocks(d))  # repro: noqa[RPR002]
 
 
-def make_codec_wire(compression: CompressionConfig,
-                    clock: RebaseClock | None = None) -> CodecWire:
-    """The EF/memoryless split the legacy compressed mixers encoded in a
-    flag: ``error_feedback=True`` → :class:`ChocoWire` (+ optional clock),
-    False → :class:`CodecWire`."""
-    if compression.error_feedback:
-        return ChocoWire(compression, clock=clock)
-    if clock is not None:
-        raise ValueError("the delta/re-base clock belongs to the "
-                         "error-feedback wire")
-    return CodecWire(compression)
+def make_wire(compression: CompressionConfig | None, *, topology=None,
+              gossip: bool = False, ef_rebase_every: int = 8,
+              ef_rebase_threshold: float = 0.0) -> Wire:
+    """The one place a consensus stack's wire is chosen.
+
+    * no (or a disabled) compression → :class:`IdentityWire`;
+    * a static topology, or the dense transport → :class:`ChocoWire` for
+      ``error_feedback=True``, else the memoryless :class:`CodecWire`;
+    * gossip over a time-varying ``topology`` → :class:`ChocoWire` with a
+      :class:`RebaseClock` (``ef_rebase_every``/``ef_rebase_threshold``)
+      for EF, else the masked int8/int4 :class:`MaskedQuantWire`.
+    """
+    enabled = compression is not None and compression.enabled
+    ef = enabled and compression.error_feedback
+    clocked = (gossip and topology is not None and topology.time_varying)
+    if ef_rebase_threshold > 0 and not ef:
+        raise ValueError(
+            "ef_rebase_threshold drives the adaptive hat_mix re-base, "
+            "which only exists on the error-feedback wire — pass an "
+            "error_feedback=True CompressionConfig")
+    if ef_rebase_threshold > 0 and not clocked:
+        raise ValueError(
+            "ef_rebase_threshold drives the hat_mix re-base of gossip over "
+            "a time-varying topology — pass a schedule")
+    if not enabled:
+        return IdentityWire()
+    if not clocked:
+        return ChocoWire(compression) if ef else CodecWire(compression)
+    if not ef:
+        return MaskedQuantWire(compression)
+    if ef_rebase_every < 0:
+        raise ValueError("ef_rebase_every must be >= 0")
+    if ef_rebase_threshold < 0:
+        raise ValueError("ef_rebase_threshold must be >= 0")
+    varies = not topology.schedule.constant or topology.faults is not None
+    if ef_rebase_every == 0 and varies and not ef_rebase_threshold > 0:
+        raise ValueError(
+            "ef_rebase_every=0 (never re-base) keeps the incremental "
+            "hat_mix cache forever, which is only valid for a static "
+            "fault-free W; this schedule/fault config varies per round "
+            "— pass ef_rebase_every >= 1 or an ef_rebase_threshold")
+    return ChocoWire(compression,
+                     clock=RebaseClock(every=int(ef_rebase_every),
+                                       threshold=float(ef_rebase_threshold)))

@@ -1,8 +1,9 @@
 """The paper's primary contribution: KL-DRO robust decentralized SGD.
 
 robust.py     — the KL-regularized DRO objective and the exp(l/mu)/mu scale
-consensus.py  — mixing operators (dense einsum / ppermute gossip / hierarchical)
-                behind the uniform stateful Mixer protocol (repro.comm.protocol)
+consensus.py  — the four mixer factories (dense / gossip / hub / identity,
+                from repro.comm.mixers) and RepeatMixer, behind the uniform
+                stateful Mixer protocol (repro.comm.protocol)
 drdsgd.py     — DR-DSGD & DSGD train-step builders over node-stacked pytrees
 api.py        — DecentralizedTrainer high-level API (step + scan-based run)
 spec.py       — TrainerSpec: declarative construction shared by CLI/benchmarks
@@ -22,14 +23,10 @@ from repro.core.robust import (
     mixture_weights,
 )
 from repro.core.consensus import (
-    DenseMixer,
-    GossipMixer,
-    HierarchicalMixer,
-    IdentityMixer,
     RepeatMixer,
     make_dense_mixer,
     make_gossip_mixer,
-    make_hierarchical_mixer,
+    make_hub_mixer,
     make_identity_mixer,
     repeat_mixer,
 )
@@ -53,10 +50,9 @@ from repro.core.spec import (
 __all__ = [
     "CommMetrics", "CommState", "CompressionConfig", "ScheduleConfig",
     "RobustConfig", "robust_scale", "robust_objective", "mixture_weights",
-    "Mixer", "DenseMixer", "GossipMixer", "HierarchicalMixer",
-    "IdentityMixer", "RepeatMixer",
-    "make_dense_mixer", "make_gossip_mixer",
-    "make_hierarchical_mixer", "make_identity_mixer", "repeat_mixer",
+    "Mixer", "RepeatMixer",
+    "make_dense_mixer", "make_gossip_mixer", "make_hub_mixer",
+    "make_identity_mixer", "repeat_mixer",
     "DecentralizedState", "TrainStepConfig", "build_train_step",
     "build_eval_step", "init_state", "replicate_params",
     "DecentralizedTrainer", "run_segments",

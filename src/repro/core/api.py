@@ -41,6 +41,7 @@ from repro.core.drdsgd import (
     replicate_params,
 )
 from repro.core.robust import RobustConfig
+from repro.dynamics import build_dynamic_mixer
 from repro.graphs import build_graph, metropolis_weights, spectral_norm
 from repro.obs.profiler import PhaseTimer
 from repro.optim import Optimizer, sgd
@@ -155,11 +156,8 @@ class DecentralizedTrainer:
                                 and self.dynamics.enabled) else None
         if self.mixer is None:
             if dyn is not None and self.mixing != "none":
-                # dynamic topology / faults / local updates: dense-lowering
-                # stack from repro.dynamics (lazy import: dynamics builds on
-                # repro.core.consensus)
-                from repro.dynamics import build_dynamic_mixer
-
+                # dynamic topology / faults / local updates: the
+                # dense-lowering stack of repro.dynamics
                 self.mixer = build_dynamic_mixer(
                     dyn, self.w, compression=self.compression)
             else:
@@ -172,7 +170,8 @@ class DecentralizedTrainer:
                 raise ValueError(
                     "both a pre-built mixer and a DynamicsConfig were "
                     "provided — wrap the mixer yourself (repro.dynamics."
-                    "LocalUpdateMixer / DynamicGossipMixer) or drop one")
+                    "LocalUpdateMixer / make_gossip_mixer(schedule=...)) "
+                    "or drop one")
             if self.compression is not None and self.compression.enabled \
                     and self.mixer.compression is None:
                 raise ValueError(

@@ -2,32 +2,14 @@
 
 All operators act on *node-stacked* pytrees: every leaf has a leading axis K
 (the decentralized node count).  Numerically they all implement the same
-doubly-stochastic mixing; they differ in the collectives XLA emits:
-
-* ``make_dense_mixer``   — einsum over the node axis. Simple, works anywhere
-  (including CPU simulation with any K); under pjit it lowers to an
-  all-gather of O(K·P) bytes over the node mesh axis. Paper-faithful baseline.
-* ``make_gossip_mixer``  — shard_map + one ``lax.ppermute`` per matching of
-  the edge-colored graph. O(deg·P) bytes; matchings of a ring/torus map to
-  the physical neighbor links of the TPU interconnect. This is the
-  communication-efficient lowering that realizes the paper's
-  decentralization benefit on real hardware.
-* ``make_hierarchical_mixer`` — beyond-paper: psum-mean over an inner
-  ``replica`` mesh axis (data-parallel replicas inside each node) composed
-  with gossip over the outer node axis. Lets K ≪ data-parallel world size so
-  that per-chip parameter memory stays bounded for multi-100B models.
-* ``make_hub_mixer``     — the federated lowering: every consensus round is
-  the exact server average (W = 11ᵀ/K, the ρ=0 endpoint of the mixing-rate
-  axis).  Stacked under ``LocalUpdateMixer`` this is FedAvg; with
-  ``gradient_tracking=True`` the tracker correction is exactly SCAFFOLD's
-  control variate (c_i = global window progress − local window progress).
-
-Since the Topology × Transport × Wire refactor every class here is a thin
-constructor shim assembling a layer stack behind
-:class:`repro.comm.composed.ComposedMixer` (see that module for the layer
-contract); the shims keep the historical names, signatures,
-``obs:consensus/<name>`` scopes and bit-exact trajectories
-(``tests/data/mixer_anchors.json``).
+doubly-stochastic mixing; they differ in the collectives XLA emits.  The
+four factories — ``make_dense_mixer`` (einsum; an all-gather under pjit),
+``make_gossip_mixer`` (one ``lax.ppermute`` per graph matching),
+``make_hub_mixer`` (the exact server average) and ``make_identity_mixer``
+(no communication) — are defined in :mod:`repro.comm.mixers` and
+re-exported here; each builds a Topology × Transport × Wire stack behind
+:class:`repro.comm.composed.ComposedMixer`.  This module adds
+:class:`RepeatMixer`.
 
 Protocol v2: every factory returns a :class:`repro.comm.protocol.Mixer`
 with ONE calling convention, compressed or not::
@@ -36,155 +18,31 @@ with ONE calling convention, compressed or not::
     theta, comm = mixer(theta, comm, round=step)   # one consensus round
 
 Uncompressed mixers carry the *trivial* ``CommState`` (no public copies, a
-never-consumed PRNG key) and stamp their static full-precision ``wire_bits``
-into it each round; ``mixer.state_specs(param_specs)`` gives matching
-PartitionSpecs for pjit.  Every factory accepts a ``compression:
-CompressionConfig`` (``repro.comm``): when enabled it returns the
-corresponding compressed mixer that gossips error-feedback-corrected
-compressed innovations instead of raw parameters — same protocol, richer
-state.
+never-consumed PRNG key) and stamp their static full-precision
+``wire_bits`` into it each round; ``mixer.state_specs(param_specs)`` gives
+matching PartitionSpecs for pjit.  Every factory accepts a ``compression:
+CompressionConfig`` (``repro.comm``): when enabled the stack's wire gossips
+error-feedback-corrected compressed innovations instead of raw parameters
+— same protocol, richer state.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 
-from repro.comm import CompressedDenseMixer, CompressedGossipMixer, CompressionConfig
-from repro.comm.composed import ComposedMixer
-from repro.comm.protocol import Mixer
-from repro.comm.topology import StarTopology, StaticTopology
-from repro.comm.transport import (  # noqa: F401  (legacy import surface)
-    DenseTransport,
-    GossipTransport,
-    StarTransport,
-    _bcast,
-    gossip_mix_local,
+from repro.comm.mixers import (
+    make_dense_mixer,
+    make_gossip_mixer,
+    make_hub_mixer,
+    make_identity_mixer,
 )
-from repro.comm.wire import IdentityWire
-from repro.graphs.mixing import MixingDecomposition
+from repro.comm.protocol import Mixer
 
-AxisName = str | tuple[str, ...]
-
-
-def _compression_enabled(compression: CompressionConfig | None) -> bool:
-    return compression is not None and compression.enabled
-
-
-class DenseMixer(ComposedMixer):
-    """θ_i ← Σ_j W_ij θ_j via einsum along the leading node axis."""
-
-    def __init__(self, w: np.ndarray, compute_dtype=jnp.float32):
-        super().__init__(StaticTopology(w), DenseTransport(compute_dtype),
-                         IdentityWire())
-
-
-def make_dense_mixer(w: np.ndarray, compute_dtype=jnp.float32,
-                     compression: CompressionConfig | None = None) -> Mixer:
-    """Dense einsum mixing (or its compressed counterpart)."""
-    if _compression_enabled(compression):
-        return CompressedDenseMixer(w, compression)
-    return DenseMixer(w, compute_dtype)
-
-
-class GossipMixer(ComposedMixer):
-    """Sparse gossip mixing: one collective-permute per graph matching.
-
-    ``param_specs`` is a pytree of PartitionSpecs matching the *node-stacked*
-    params (leading dim partitioned over ``node_axis``); it is used for
-    shard_map in/out specs so tensor-parallel dims stay sharded.
-    """
-
-    def __init__(self, decomp: MixingDecomposition, mesh,
-                 node_axis: AxisName, param_specs):
-        super().__init__(
-            None, GossipTransport(decomp, mesh, node_axis, param_specs),
-            IdentityWire())
-
-
-def make_gossip_mixer(
-    decomp: MixingDecomposition,
-    mesh,
-    node_axis: AxisName,
-    param_specs,
-    compression: CompressionConfig | None = None,
-) -> Mixer:
-    """Gossip mixing over the mesh node axis (or its compressed counterpart)."""
-    if _compression_enabled(compression):
-        return CompressedGossipMixer(decomp, mesh, node_axis, param_specs,
-                                     compression)
-    return GossipMixer(decomp, mesh, node_axis, param_specs)
-
-
-class HierarchicalMixer(GossipMixer):
-    """FSDP-inside / gossip-across: psum-mean over ``replica_axis`` then gossip.
-
-    Node-stacked leaves are *replicated* across ``replica_axis`` (each node's
-    replicas hold divergent gradient contributions that are averaged here),
-    then the per-node consensus step runs over ``node_axis``.
-    """
-
-    def __init__(self, decomp, mesh, node_axis, replica_axis: str,
-                 param_specs):
-        ComposedMixer.__init__(
-            self, None,
-            GossipTransport(decomp, mesh, node_axis, param_specs,
-                            replica_axis=replica_axis),
-            IdentityWire())
-        self._r_size = mesh.shape[replica_axis]
-
-
-def make_hierarchical_mixer(
-    decomp: MixingDecomposition,
-    mesh,
-    node_axis: AxisName,
-    replica_axis: str,
-    param_specs,
-    compression: CompressionConfig | None = None,
-) -> Mixer:
-    """Hierarchical replica-average + gossip (or its compressed counterpart)."""
-    if _compression_enabled(compression):
-        return CompressedGossipMixer(decomp, mesh, node_axis, param_specs,
-                                     compression, replica_axis=replica_axis)
-    return HierarchicalMixer(decomp, mesh, node_axis, replica_axis, param_specs)
-
-
-class IdentityMixer(ComposedMixer):
-    """No communication — for ablations (pure local SGD)."""
-
-    def __init__(self):
-        super().__init__(None, None, IdentityWire())
-
-
-def make_identity_mixer() -> Mixer:
-    return IdentityMixer()
-
-
-class HubMixer(ComposedMixer):
-    """Hub-and-spoke (federated) consensus: the exact global average.
-
-    Star topology × star transport: each round every node uploads its block
-    and downloads the mean — one round reaches consensus exactly (ρ = 0).
-    ``LocalUpdateMixer(HubMixer(k), H)`` is FedAvg with H local steps;
-    adding ``gradient_tracking=True`` yields the SCAFFOLD control variate
-    (the tracker update (Δ̄ − Δ_i)/H under W = 11ᵀ/K is exactly c_i).
-    """
-
-    def __init__(self, k: int):
-        super().__init__(StarTopology(k), StarTransport(k), IdentityWire())
-
-
-def make_hub_mixer(k: int,
-                   compression: CompressionConfig | None = None) -> Mixer:
-    """Federated server averaging (or its compressed counterpart).
-
-    The compressed hub rides the dense transport with the star W — the
-    codec round re-mixes the full public-copy matrix, which with W = 11ᵀ/K
-    is exactly "server averages the reconstructed client innovations".
-    """
-    if _compression_enabled(compression):
-        return CompressedDenseMixer(np.full((k, k), 1.0 / k), compression)
-    return HubMixer(k)
+__all__ = [
+    "RepeatMixer", "repeat_mixer",
+    "make_dense_mixer", "make_gossip_mixer", "make_hub_mixer",
+    "make_identity_mixer",
+]
 
 
 class RepeatMixer(Mixer):
@@ -192,8 +50,7 @@ class RepeatMixer(Mixer):
 
     Theorem 1's consensus term contracts like ρ^rounds, so m rounds on a
     sparse graph can substitute for a denser graph at m× the mixing wire —
-    a knob for trading interconnect bytes against the convergence constant
-    (see EXPERIMENTS.md §Perf A4 for the measured trade).
+    a knob for trading interconnect bytes against the convergence constant.
     """
 
     def __init__(self, mixer: Mixer, rounds: int):

@@ -160,7 +160,7 @@ def build_train_step(
     # wrappers (LocalUpdateMixer/RepeatMixer) to find the faulted mixer.
     _m, step_faults = mixer, None
     while _m is not None and step_faults is None:
-        step_faults = getattr(_m, "faults", None)
+        step_faults = getattr(getattr(_m, "topo", None), "faults", None)
         _m = getattr(_m, "inner", None)
     if not (step_faults is not None and step_faults.enabled
             and step_faults.straggler_skips_compute

@@ -19,16 +19,6 @@ schedule.py — :class:`TopologySchedule`: per-round doubly-stochastic W as a
 faults.py   — :class:`FaultConfig`: link dropout, per-round node stragglers,
               correlated multi-round outages — all as a symmetric link-keep
               mask renormalized into W (doubly-stochastic preserved).
-mixers.py   — the consensus lowerings: :class:`DynamicDenseMixer` (einsum,
-              any schedule), :class:`DynamicGossipMixer` (static matchings +
-              traced weights/masks; optional masked int8 Pallas wire),
-              :class:`DynamicCompressedDenseMixer` (error-feedback
-              compression × dynamic topology, exact on the dense lowering),
-              :class:`DynamicCompressedGossipMixer` (EF on the ppermute
-              lowering: θ̂-delta gossip with per-round weights plus a
-              periodic full-precision re-base of the ``hat_mix`` cache
-              every ``ef_rebase_every`` rounds — the ``CommState.ef_rounds``
-              clock).
 local.py    — :class:`LocalUpdateMixer`: H local steps per consensus round
               with optional gradient-tracking correction carried in
               ``CommState.track``.
@@ -36,10 +26,17 @@ config.py   — :class:`DynamicsConfig` + :func:`build_dynamic_mixer`: the
               declarative entry point used by ``TrainerSpec``
               (``--topology/--drop-p/--local-updates/...`` CLI flags).
               ``--topology hub`` selects the federated lowering
-              (:class:`repro.core.consensus.HubMixer` — exact server
-              average; FedAvg under ``--local-updates H``, SCAFFOLD with
+              (``repro.comm.make_hub_mixer`` — exact server average;
+              FedAvg under ``--local-updates H``, SCAFFOLD with
               ``--gradient-tracking``); hub has no fault model yet, so
               hub + faults raises at config build.
+
+The consensus lowerings themselves are the factories of
+``repro.comm.mixers``: ``make_dense_mixer(None, schedule=s, faults=f)``
+(einsum, any schedule) and ``make_gossip_mixer(None, mesh, ...,
+schedule=s, faults=f)`` (static matchings + traced weights/masks; the
+memoryless masked int8/int4 Pallas wire, or error feedback with the
+``hat_mix`` re-base clock of ``repro.comm.wire.RebaseClock``).
 
 Conventions — how H, dropout p and the EF step size γ interact:
 
@@ -76,13 +73,6 @@ from repro.dynamics.config import (
 )
 from repro.dynamics.faults import FaultConfig, fault_keep_matrix, replay_fault_masks
 from repro.dynamics.local import LocalUpdateMixer
-from repro.dynamics.mixers import (
-    DynamicCompressedDenseMixer,
-    DynamicCompressedGossipMixer,
-    DynamicDenseMixer,
-    DynamicGossipMixer,
-    gather_round_vectors,
-)
 from repro.dynamics.schedule import (
     DropoutSchedule,
     GeometricRedrawSchedule,
@@ -96,8 +86,6 @@ __all__ = [
     "DynamicsConfig", "TOPOLOGY_KINDS", "build_dynamic_mixer",
     "FaultConfig", "fault_keep_matrix", "replay_fault_masks",
     "LocalUpdateMixer",
-    "DynamicDenseMixer", "DynamicGossipMixer", "DynamicCompressedDenseMixer",
-    "DynamicCompressedGossipMixer", "gather_round_vectors",
     "TopologySchedule", "StaticSchedule", "RoundRobinSchedule",
     "DropoutSchedule", "GeometricRedrawSchedule", "make_schedule",
 ]

@@ -7,7 +7,7 @@ the local-update period H and whether gradient tracking is on.
 :func:`build_dynamic_mixer` assembles the mixer stack
 (schedule → faults → [compression] → [local updates]) for the dense
 simulation lowering; the gossip lowering is built explicitly via
-:class:`~repro.dynamics.mixers.DynamicGossipMixer` (it needs a mesh).
+``repro.comm.make_gossip_mixer(..., schedule=...)`` (it needs a mesh).
 """
 
 from __future__ import annotations
@@ -17,10 +17,10 @@ import dataclasses
 import numpy as np
 
 from repro.comm.compressors import CompressionConfig
+from repro.comm.mixers import make_dense_mixer, make_hub_mixer
 from repro.comm.protocol import Mixer
 from repro.dynamics.faults import FaultConfig
 from repro.dynamics.local import LocalUpdateMixer
-from repro.dynamics.mixers import DynamicCompressedDenseMixer, DynamicDenseMixer
 from repro.dynamics.schedule import make_schedule
 
 TOPOLOGY_KINDS = ("static", "round_robin", "dropout", "geometric", "hub")
@@ -49,7 +49,7 @@ class DynamicsConfig:
         (stragglers / correlated outages / extra link dropout) composed on
         top of the schedule.
       ef_rebase_every: B — re-base period of the error-feedback compressed
-        *gossip* lowering (:class:`~repro.dynamics.DynamicCompressedGossipMixer`):
+        *gossip* lowering (``make_gossip_mixer(..., schedule=...)``):
         every B-th consensus round exchanges full-precision public copies to
         rebuild the incremental ``hat_mix`` cache under the current W.
         0 = never re-base (only valid for a static fault-free topology, or
@@ -122,25 +122,18 @@ def build_dynamic_mixer(cfg: DynamicsConfig, w: np.ndarray,
     keeps only K, and topology="hub" (federated) keeps only K as well —
     the star W = 11ᵀ/K replaces the graph entirely.
     """
+    k = int(np.asarray(w).shape[0])
     if cfg.topology == "hub":
-        from repro.core.consensus import make_hub_mixer
-
-        mixer = make_hub_mixer(int(np.asarray(w).shape[0]), compression)
-        if cfg.local_updates > 1 or cfg.gradient_tracking:
-            # FedAvg; with gradient_tracking the tracker correction under
-            # W = 11^T/K is exactly SCAFFOLD's control variate
-            mixer = LocalUpdateMixer(mixer, cfg.local_updates,
-                                     gradient_tracking=cfg.gradient_tracking)
-        return mixer
-    schedule = make_schedule(
-        cfg.topology, w=w, k=int(np.asarray(w).shape[0]),
-        drop_p=cfg.drop_p, radius=cfg.radius, seed=cfg.seed)
-    if compression is not None and compression.enabled:
-        mixer: Mixer = DynamicCompressedDenseMixer(
-            schedule, compression, faults=cfg.faults)
+        mixer = make_hub_mixer(k, compression)
     else:
-        mixer = DynamicDenseMixer(schedule, faults=cfg.faults)
+        schedule = make_schedule(
+            cfg.topology, w=w, k=k, drop_p=cfg.drop_p, radius=cfg.radius,
+            seed=cfg.seed)
+        mixer = make_dense_mixer(None, compression=compression,
+                                 schedule=schedule, faults=cfg.faults)
     if cfg.local_updates > 1 or cfg.gradient_tracking:
+        # under the hub: FedAvg, and with gradient_tracking the tracker
+        # correction under W = 11^T/K is exactly SCAFFOLD's control variate
         mixer = LocalUpdateMixer(mixer, cfg.local_updates,
                                  gradient_tracking=cfg.gradient_tracking)
     return mixer

@@ -70,7 +70,7 @@ class LocalUpdateMixer(Mixer):
         when enabled).
       gradient_tracking: carry the drift correction in ``CommState.track``.
         Requires an *uncompressed* inner mixer exposing a pure
-        ``mix_tree`` (DenseMixer/GossipMixer and the dynamic mixers); the
+        ``mix_tree`` (any identity-wire dense/gossip/hub stack); the
         tracker exchange doubles the consensus round's wire.
     """
 

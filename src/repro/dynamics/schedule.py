@@ -9,8 +9,8 @@ structure, so a run over a time-varying graph compiles exactly one program
 The schedule's W is the single source of truth for *both* consensus
 lowerings: the dense mixer einsums it directly, and the gossip mixer gathers
 per-matching edge weights out of it along the static edge-coloring of the
-*union support* (see ``repro.dynamics.mixers``), so the two lowerings see
-bit-identical weights each round.
+*union support* (see ``repro.comm.topology.gather_round_vectors``), so the
+two lowerings see bit-identical weights each round.
 
 Implementations:
 
@@ -58,11 +58,14 @@ class TopologySchedule:
         traced weights).  Schedules whose support moves (geometric re-draws)
         are dense-only.
       seed: seed of the schedule's own randomness (dropout coins, re-draws).
+      constant: True when every round's W is the same matrix (only
+        :class:`StaticSchedule`) — what lets EF gossip skip the re-base.
     """
 
     k: int
     static_support = True
     seed = 0
+    constant = False
 
     def round_weights(self, rounds) -> jax.Array:
         """The (K, K) doubly-stochastic W of round ``rounds`` (traced)."""
@@ -87,6 +90,8 @@ class TopologySchedule:
 
 class StaticSchedule(TopologySchedule):
     """Constant topology — the frozen-graph baseline as a schedule."""
+
+    constant = True
 
     def __init__(self, w: np.ndarray):
         self._w_np = np.asarray(w, np.float64)

@@ -94,23 +94,16 @@ def build_train(cfg: ArchConfig, shape: ShapeConfig, mesh, mixer_kind: str,
             permutation_decomposition(w), mesh, node_axis, pspecs,
             compression=compression)
     elif mixer_kind == "gossip-dynamic":
-        # time-varying topology lowering (repro.dynamics): static ppermute
-        # structure over the union support, traced per-round weights/masks.
-        # An error-feedback config builds the EF wire with periodic hat_mix
-        # re-basing (DynamicCompressedGossipMixer, --ef-rebase-every);
-        # --no-error-feedback keeps the memoryless masked int8 kernel wire.
-        from repro.dynamics import DynamicGossipMixer, make_schedule
+        # time-varying topology lowering: static ppermute structure over
+        # the union support, traced per-round weights/masks.  An
+        # error-feedback config builds the EF wire with periodic hat_mix
+        # re-basing (--ef-rebase-every); --no-error-feedback keeps the
+        # memoryless masked int8/int4 kernel wire.
+        from repro.dynamics import make_schedule
 
-        if (compression is not None and compression.enabled
-                and not compression.error_feedback
-                and compression.kind not in ("int8", "int4")):
-            raise ValueError(
-                "the memoryless gossip-dynamic wire serves --compress "
-                "int8/int4 (masked kernel wire, traced qmax); "
-                "error-feedback configs take any codec")
-        mixer = DynamicGossipMixer(
-            make_schedule(topology, w=w, k=k, drop_p=drop_p),
-            mesh, node_axis, pspecs, quantized=compression,
+        mixer = make_gossip_mixer(
+            None, mesh, node_axis, pspecs, compression,
+            schedule=make_schedule(topology, w=w, k=k, drop_p=drop_p),
             ef_rebase_every=ef_rebase_every)
     else:
         raise ValueError(mixer_kind)

@@ -1,13 +1,13 @@
-"""Bit-exact trajectory anchors for the legacy mixer matrix.
+"""Bit-exact trajectory anchors for the shipped mixer stacks.
 
 ``gen`` mode runs every shipped mixer config for ``N_ROUNDS`` consensus
 rounds on a deterministic synthetic trajectory and records a sha256 of the
 mixed parameters plus every ``CommState`` field (digests for pytrees, exact
 values for scalars) into ``mixer_anchors.json``.  ``check`` mode re-runs the
 same configs and asserts every record matches — this is the equivalence
-gate of the Topology x Transport x Wire refactor: the anchors were captured
-from the pre-refactor classes, so any layer decomposition that is not
-bit-exact fails here, field by field.
+gate of the Topology x Transport x Wire stacks: the anchors were captured
+from the classes that preceded the layers, so any stack that is not
+bit-exact against them fails here, field by field.
 
 The two groups isolate device requirements:
 
@@ -110,14 +110,10 @@ def _theta(shapes: dict, seed: int = 42):
 def dense_configs():
     """Single-device mixers: the dense/einsum simulation lowerings."""
     from repro.comm import CompressionConfig, ScheduleConfig
-    from repro.comm.mixers import CompressedDenseMixer
-    from repro.core.consensus import DenseMixer, IdentityMixer, RepeatMixer
+    from repro.comm.mixers import make_dense_mixer, make_identity_mixer
+    from repro.core.consensus import RepeatMixer
     from repro.dynamics.faults import FaultConfig
     from repro.dynamics.local import LocalUpdateMixer
-    from repro.dynamics.mixers import (
-        DynamicCompressedDenseMixer,
-        DynamicDenseMixer,
-    )
     from repro.dynamics.schedule import DropoutSchedule, StaticSchedule
     from repro.graphs import build_graph, metropolis_weights
 
@@ -125,32 +121,34 @@ def dense_configs():
     cc = CompressionConfig
     theta = _theta({"a": (8, 48), "b": (8, 3, 10)})
     configs = {
-        "identity": lambda: IdentityMixer(),
-        "dense_plain": lambda: DenseMixer(w),
-        "repeat_dense": lambda: RepeatMixer(DenseMixer(w), 2),
-        "dense_int8_mem": lambda: CompressedDenseMixer(
-            w, cc(kind="int8", error_feedback=False, seed=11)),
-        "dense_int8_ef": lambda: CompressedDenseMixer(
-            w, cc(kind="int8", seed=11)),
-        "dense_topk_ef": lambda: CompressedDenseMixer(
-            w, cc(kind="topk", ratio=0.25, seed=11)),
-        "dense_int8_sched": lambda: CompressedDenseMixer(
-            w, cc(kind="int8", seed=11,
-                  schedule=ScheduleConfig(kind="adaptive", warmup_rounds=2))),
-        "dense_dyn_plain": lambda: DynamicDenseMixer(
-            DropoutSchedule(w, 0.3, seed=5)),
-        "dense_dyn_faults": lambda: DynamicDenseMixer(
-            StaticSchedule(w),
+        "identity": lambda: make_identity_mixer(),
+        "dense_plain": lambda: make_dense_mixer(w),
+        "repeat_dense": lambda: RepeatMixer(make_dense_mixer(w), 2),
+        "dense_int8_mem": lambda: make_dense_mixer(
+            w, compression=cc(kind="int8", error_feedback=False, seed=11)),
+        "dense_int8_ef": lambda: make_dense_mixer(
+            w, compression=cc(kind="int8", seed=11)),
+        "dense_topk_ef": lambda: make_dense_mixer(
+            w, compression=cc(kind="topk", ratio=0.25, seed=11)),
+        "dense_int8_sched": lambda: make_dense_mixer(
+            w, compression=cc(
+                kind="int8", seed=11,
+                schedule=ScheduleConfig(kind="adaptive", warmup_rounds=2))),
+        "dense_dyn_plain": lambda: make_dense_mixer(
+            None, schedule=DropoutSchedule(w, 0.3, seed=5)),
+        "dense_dyn_faults": lambda: make_dense_mixer(
+            None, schedule=StaticSchedule(w),
             faults=FaultConfig(straggler_p=0.2, seed=3)),
-        "dense_dyn_int8_ef": lambda: DynamicCompressedDenseMixer(
-            DropoutSchedule(w, 0.3, seed=5), cc(kind="int8", seed=11)),
+        "dense_dyn_int8_ef": lambda: make_dense_mixer(
+            None, compression=cc(kind="int8", seed=11),
+            schedule=DropoutSchedule(w, 0.3, seed=5)),
         "local_gt": lambda: LocalUpdateMixer(
-            DenseMixer(w), 2, gradient_tracking=True),
+            make_dense_mixer(w), 2, gradient_tracking=True),
         "local_h3_int8": lambda: LocalUpdateMixer(
-            CompressedDenseMixer(w, cc(kind="int8", seed=11)), 3),
+            make_dense_mixer(w, compression=cc(kind="int8", seed=11)), 3),
         "local_gt_dynamic": lambda: LocalUpdateMixer(
-            DynamicDenseMixer(DropoutSchedule(w, 0.3, seed=5)), 2,
-            gradient_tracking=True),
+            make_dense_mixer(None, schedule=DropoutSchedule(w, 0.3, seed=5)),
+            2, gradient_tracking=True),
     }
     return configs, theta
 
@@ -160,13 +158,8 @@ def gossip_configs():
     from jax.sharding import PartitionSpec as P
 
     from repro.comm import CompressionConfig
-    from repro.comm.mixers import CompressedGossipMixer
-    from repro.core.consensus import GossipMixer, HierarchicalMixer
+    from repro.comm.mixers import make_gossip_mixer
     from repro.dynamics.faults import FaultConfig
-    from repro.dynamics.mixers import (
-        DynamicCompressedGossipMixer,
-        DynamicGossipMixer,
-    )
     from repro.dynamics.schedule import DropoutSchedule, StaticSchedule
     from repro.graphs import (
         build_graph,
@@ -189,29 +182,33 @@ def gossip_configs():
     mesh2 = make_auto_mesh((2, 4), ("rep", "data"))
     theta4 = _theta({"a": (4, 64), "b": (4, 3, 5)})
 
+    def dropout():
+        return DropoutSchedule(w, 0.3, seed=5)
+
     configs = {
-        "gossip_plain": lambda: GossipMixer(decomp, mesh, "data", specs),
-        "gossip_int8_ef": lambda: CompressedGossipMixer(
+        "gossip_plain": lambda: make_gossip_mixer(decomp, mesh, "data", specs),
+        "gossip_int8_ef": lambda: make_gossip_mixer(
             decomp, mesh, "data", specs, cc(kind="int8", seed=7)),
-        "hier_plain": lambda: HierarchicalMixer(
-            decomp4, mesh2, "data", "rep", specs),
-        "hier_int8_ef": lambda: CompressedGossipMixer(
+        "hier_plain": lambda: make_gossip_mixer(
+            decomp4, mesh2, "data", specs, replica_axis="rep"),
+        "hier_int8_ef": lambda: make_gossip_mixer(
             decomp4, mesh2, "data", specs, cc(kind="int8", seed=7),
             replica_axis="rep"),
-        "gossip_dyn_plain": lambda: DynamicGossipMixer(
-            DropoutSchedule(w, 0.3, seed=5), mesh, "data", specs),
-        "gossip_dyn_quant_mem": lambda: DynamicGossipMixer(
-            DropoutSchedule(w, 0.3, seed=5), mesh, "data", specs,
-            quantized=cc(kind="int8", error_feedback=False, seed=7)),
-        "gossip_dyn_int8_ef_b2": lambda: DynamicGossipMixer(
-            DropoutSchedule(w, 0.3, seed=5), mesh, "data", specs,
-            quantized=cc(kind="int8", seed=7), ef_rebase_every=2),
-        "gossip_dyn_int8_ef_adaptive": lambda: DynamicCompressedGossipMixer(
-            DropoutSchedule(w, 0.3, seed=5), mesh, "data", specs,
-            cc(kind="int8", seed=7), ef_rebase_every=8,
+        "gossip_dyn_plain": lambda: make_gossip_mixer(
+            None, mesh, "data", specs, schedule=dropout()),
+        "gossip_dyn_quant_mem": lambda: make_gossip_mixer(
+            None, mesh, "data", specs,
+            cc(kind="int8", error_feedback=False, seed=7),
+            schedule=dropout()),
+        "gossip_dyn_int8_ef_b2": lambda: make_gossip_mixer(
+            None, mesh, "data", specs, cc(kind="int8", seed=7),
+            schedule=dropout(), ef_rebase_every=2),
+        "gossip_dyn_int8_ef_adaptive": lambda: make_gossip_mixer(
+            None, mesh, "data", specs, cc(kind="int8", seed=7),
+            schedule=dropout(), ef_rebase_every=8,
             ef_rebase_threshold=0.05),
-        "gossip_dyn_faults": lambda: DynamicGossipMixer(
-            StaticSchedule(w), mesh, "data", specs,
+        "gossip_dyn_faults": lambda: make_gossip_mixer(
+            None, mesh, "data", specs, schedule=StaticSchedule(w),
             faults=FaultConfig(link_drop_p=0.3, seed=3)),
     }
     per_config_theta = {"hier_plain": theta4, "hier_int8_ef": theta4}

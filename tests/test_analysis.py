@@ -522,7 +522,7 @@ def test_sanitize_throws_on_corrupted_w():
     target = trainer.mixer
     while hasattr(target, "inner"):
         target = target.inner
-    sched = target.topology
+    sched = target.topo.schedule
     object.__setattr__(sched, "w",
                        jnp.asarray(sched.w).at[0, 0].add(0.5))
     with pytest.raises(Exception, match="doubly stochastic"):
@@ -544,7 +544,8 @@ def test_adaptive_rebase_static_schedule_never_fires():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.comm import CompressionConfig
-from repro.dynamics import DynamicCompressedGossipMixer, StaticSchedule
+from repro.core import make_gossip_mixer
+from repro.dynamics import StaticSchedule
 from repro.graphs import metropolis_weights, ring_graph
 from repro.launch.mesh import make_auto_mesh
 
@@ -555,10 +556,13 @@ theta = {"a": jnp.asarray(np.random.default_rng(0).normal(size=(k, 64)),
                           jnp.float32)}
 specs = {"a": P("data", None)}
 cc = CompressionConfig(kind="int8", seed=0)
-adaptive = DynamicCompressedGossipMixer(StaticSchedule(w), mesh, "data",
-    specs, cc, ef_rebase_threshold=1e6)  # huge threshold: cond never taken
-delta_only = DynamicCompressedGossipMixer(StaticSchedule(w), mesh, "data",
-    specs, cc, ef_rebase_every=0)       # the pure delta wire
+# huge threshold: the cond is never taken
+adaptive = make_gossip_mixer(None, mesh, "data", specs, cc,
+                             schedule=StaticSchedule(w),
+                             ef_rebase_threshold=1e6)
+# the pure delta wire
+delta_only = make_gossip_mixer(None, mesh, "data", specs, cc,
+                               schedule=StaticSchedule(w), ef_rebase_every=0)
 st = adaptive.init_state(theta)
 step = jax.jit(adaptive)
 bits = []
@@ -581,7 +585,8 @@ def test_adaptive_rebase_fires_under_dropout():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.comm import CompressionConfig
-from repro.dynamics import DropoutSchedule, DynamicCompressedGossipMixer
+from repro.core import make_gossip_mixer
+from repro.dynamics import DropoutSchedule
 from repro.graphs import metropolis_weights, ring_graph
 from repro.launch.mesh import make_auto_mesh
 
@@ -592,8 +597,9 @@ theta = {"a": jnp.asarray(np.random.default_rng(0).normal(size=(k, 64)),
                           jnp.float32)}
 specs = {"a": P("data", None)}
 cc = CompressionConfig(kind="int8", seed=0)
-mixer = DynamicCompressedGossipMixer(DropoutSchedule(w, 0.4, seed=3), mesh,
-    "data", specs, cc, ef_rebase_threshold=0.5)
+mixer = make_gossip_mixer(None, mesh, "data", specs, cc,
+                          schedule=DropoutSchedule(w, 0.4, seed=3),
+                          ef_rebase_threshold=0.5)
 st = mixer.init_state(theta)
 step = jax.jit(mixer)
 drifts, bits = [], []

@@ -20,11 +20,9 @@ import numpy as np
 import pytest
 
 from repro.core import TrainerSpec
-from repro.core.consensus import DenseMixer
+from repro.core.consensus import make_dense_mixer
 from repro.dynamics import (
     DropoutSchedule,
-    DynamicCompressedDenseMixer,
-    DynamicDenseMixer,
     DynamicsConfig,
     FaultConfig,
     GeometricRedrawSchedule,
@@ -151,9 +149,10 @@ def test_static_schedule_reproduces_dense_mixer_bitexact():
     k = 8
     w = metropolis_weights(build_graph("erdos_renyi", k))
     params = _params(k)
-    ref, _ = DenseMixer(w)(params, DenseMixer(w).init_state(params))
+    dense = make_dense_mixer(w)
+    ref, _ = dense(params, dense.init_state(params))
     for sched in (StaticSchedule(w), DropoutSchedule(w, 0.0, seed=9)):
-        mixer = DynamicDenseMixer(sched)
+        mixer = make_dense_mixer(None, schedule=sched)
         out, comm = jax.jit(mixer)(params, mixer.init_state(params))
         for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(out)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -161,15 +160,14 @@ def test_static_schedule_reproduces_dense_mixer_bitexact():
 
 
 def test_static_schedule_reproduces_gossip_mixer_bitexact():
-    """Subprocess (8 host devices): DynamicGossipMixer(StaticSchedule) and
-    DropoutSchedule(p=0) are bit-identical to today's GossipMixer; a full
+    """Subprocess (8 host devices): gossip over StaticSchedule and
+    DropoutSchedule(p=0) is bit-identical to static gossip; a full
     straggler round reports wire_bits == 0 and leaves θ untouched."""
     script = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core.consensus import GossipMixer
-from repro.dynamics import (DynamicGossipMixer, StaticSchedule,
-                            DropoutSchedule, FaultConfig)
+from repro.core.consensus import make_gossip_mixer
+from repro.dynamics import StaticSchedule, DropoutSchedule, FaultConfig
 from repro.graphs import metropolis_weights, ring_graph, permutation_decomposition
 from repro.launch.mesh import make_auto_mesh
 
@@ -180,16 +178,16 @@ specs = {"a": P("data", None)}
 rng = np.random.default_rng(0)
 params = {"a": jnp.asarray(rng.normal(size=(k, 6)), jnp.float32)}
 
-gm = GossipMixer(permutation_decomposition(w), mesh, "data", specs)
+gm = make_gossip_mixer(permutation_decomposition(w), mesh, "data", specs)
 ref, _ = jax.jit(gm)(params, gm.init_state(params))
 for sched in (StaticSchedule(w), DropoutSchedule(w, 0.0, seed=4)):
-    dg = DynamicGossipMixer(sched, mesh, "data", specs)
+    dg = make_gossip_mixer(None, mesh, "data", specs, schedule=sched)
     out, comm = jax.jit(dg)(params, dg.init_state(params))
     np.testing.assert_array_equal(np.asarray(ref["a"]), np.asarray(out["a"]))
     assert float(comm.wire_bits) == 8.0 * gm.bytes_per_round(params)
 
-dgs = DynamicGossipMixer(StaticSchedule(w), mesh, "data", specs,
-                         faults=FaultConfig(straggler_p=0.999, seed=1))
+dgs = make_gossip_mixer(None, mesh, "data", specs, schedule=StaticSchedule(w),
+                        faults=FaultConfig(straggler_p=0.999, seed=1))
 out, comm = jax.jit(dgs)(params, dgs.init_state(params))
 assert float(comm.wire_bits) == 0.0, float(comm.wire_bits)
 np.testing.assert_allclose(np.asarray(out["a"]), np.asarray(params["a"]),
@@ -322,7 +320,7 @@ def test_dropout_comm_bytes_counts_active_links_exactly():
     k = 8
     w = metropolis_weights(build_graph("ring", k))
     sched = DropoutSchedule(w, 0.5, seed=11)
-    mixer = DynamicDenseMixer(sched)
+    mixer = make_dense_mixer(None, schedule=sched)
     params = _params(k)
     per_node = sum(x.size * 4 for x in jax.tree.leaves(params)) // k
     state = mixer.init_state(params)
@@ -338,7 +336,8 @@ def test_dropout_comm_bytes_counts_active_links_exactly():
 def test_local_update_period_gates_wire():
     k = 6
     w = metropolis_weights(build_graph("ring", k))
-    mixer = LocalUpdateMixer(DynamicDenseMixer(StaticSchedule(w)), 3)
+    mixer = LocalUpdateMixer(
+        make_dense_mixer(None, schedule=StaticSchedule(w)), 3)
     params = _params(k)
     state = mixer.init_state(params)
     theta = params
@@ -360,8 +359,9 @@ def test_local_update_period_one_matches_inner_bitexact():
     k = 6
     w = metropolis_weights(build_graph("ring", k))
     params = _params(k)
-    inner = DynamicDenseMixer(StaticSchedule(w))
-    wrapped = LocalUpdateMixer(DynamicDenseMixer(StaticSchedule(w)), 1)
+    inner = make_dense_mixer(None, schedule=StaticSchedule(w))
+    wrapped = LocalUpdateMixer(
+        make_dense_mixer(None, schedule=StaticSchedule(w)), 1)
     a, _ = inner(params, inner.init_state(params))
     b, _ = wrapped(params, wrapped.init_state(params))
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
@@ -397,9 +397,11 @@ def test_gradient_tracking_doubles_consensus_wire():
     k = 6
     w = metropolis_weights(build_graph("ring", k))
     params = _params(k)
-    plain = LocalUpdateMixer(DynamicDenseMixer(StaticSchedule(w)), 2)
-    gt = LocalUpdateMixer(DynamicDenseMixer(StaticSchedule(w)), 2,
-                          gradient_tracking=True)
+    plain = LocalUpdateMixer(
+        make_dense_mixer(None, schedule=StaticSchedule(w)), 2)
+    gt = LocalUpdateMixer(
+        make_dense_mixer(None, schedule=StaticSchedule(w)), 2,
+        gradient_tracking=True)
     sp, sg = plain.init_state(params), gt.init_state(params)
     t = params
     for r in range(2):
@@ -412,10 +414,9 @@ def test_gradient_tracking_doubles_consensus_wire():
 
 def test_gradient_tracking_rejects_compressed_inner():
     from repro.comm import CompressionConfig
-    from repro.comm.mixers import CompressedDenseMixer
 
     w = metropolis_weights(build_graph("ring", 6))
-    inner = CompressedDenseMixer(w, CompressionConfig(kind="int8"))
+    inner = make_dense_mixer(w, compression=CompressionConfig(kind="int8"))
     with pytest.raises(ValueError, match="uncompressed"):
         LocalUpdateMixer(inner, 2, gradient_tracking=True)
 
@@ -435,14 +436,14 @@ def test_compressed_dense_dynamic_matches_static_at_p0():
     """EF int8 over a dropout schedule at p = 0 is bit-identical to the
     static compressed mixer (same codec PRNG, same W)."""
     from repro.comm import CompressionConfig
-    from repro.comm.mixers import CompressedDenseMixer
 
     k = 6
     w = metropolis_weights(build_graph("ring", k))
     cc = CompressionConfig(kind="int8", seed=3)
     params = _params(k)
-    ref = CompressedDenseMixer(w, cc)
-    dyn = DynamicCompressedDenseMixer(DropoutSchedule(w, 0.0, seed=1), cc)
+    ref = make_dense_mixer(w, compression=cc)
+    dyn = make_dense_mixer(None, compression=cc,
+                           schedule=DropoutSchedule(w, 0.0, seed=1))
     sa, sb = ref.init_state(params), dyn.init_state(params)
     ta, tb = params, params
     for r in range(3):
@@ -459,8 +460,8 @@ def test_compressed_dynamic_converges_under_dropout():
 
     k = 8
     w = metropolis_weights(build_graph("ring", k))
-    mixer = DynamicCompressedDenseMixer(
-        DropoutSchedule(w, 0.3, seed=5), CompressionConfig(kind="int8"))
+    mixer = make_dense_mixer(None, compression=CompressionConfig(kind="int8"),
+                             schedule=DropoutSchedule(w, 0.3, seed=5))
     params = _params(k)
     state = mixer.init_state(params)
     theta = params
@@ -480,9 +481,9 @@ def test_compressed_dynamic_converges_under_dropout():
 def test_ef_gossip_rebase_anchors():
     """The three PR-5 bit-exactness anchors (subprocess, 8 host devices):
 
-    * an EF config on ``DynamicGossipMixer`` builds the re-based wire (the
+    * an EF config on scheduled gossip builds the re-based wire (the
       silent memoryless downgrade was the bug);
-    * static schedule + EF ≡ the frozen ``CompressedGossipMixer`` bit-exact
+    * static schedule + EF ≡ the frozen static EF gossip stack bit-exact
       while no re-base fires (B = 0 and B > horizon), tight-allclose across
       a re-base (pure float reordering under a static W);
     * B = 1 re-bases every round: the cache is the fresh memoryless-style
@@ -497,11 +498,9 @@ def test_ef_gossip_rebase_anchors():
     script = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.comm import CompressionConfig
-from repro.comm.mixers import CompressedGossipMixer
-from repro.dynamics import (DynamicCompressedDenseMixer,
-                            DynamicCompressedGossipMixer, DynamicGossipMixer,
-                            DropoutSchedule, StaticSchedule)
+from repro.comm import ChocoWire, CompressionConfig
+from repro.core.consensus import make_dense_mixer, make_gossip_mixer
+from repro.dynamics import DropoutSchedule, StaticSchedule
 from repro.graphs import metropolis_weights, ring_graph, permutation_decomposition
 from repro.launch.mesh import make_auto_mesh
 
@@ -514,14 +513,14 @@ theta = {"a": jnp.asarray(rng.normal(size=(k, 64)), jnp.float32),
          "b": jnp.asarray(rng.normal(size=(k, 3, 5)), jnp.float32)}
 cc = CompressionConfig(kind="int8", seed=7)
 
-m = DynamicGossipMixer(StaticSchedule(w), mesh, "data", specs, quantized=cc,
-                       ef_rebase_every=8)
-assert isinstance(m, DynamicCompressedGossipMixer), type(m)
+m = make_gossip_mixer(None, mesh, "data", specs, cc,
+                      schedule=StaticSchedule(w), ef_rebase_every=8)
+assert isinstance(m.wire, ChocoWire) and m.wire.clock.every == 8, m.wire
 
-ref = CompressedGossipMixer(permutation_decomposition(w), mesh, "data", specs, cc)
+ref = make_gossip_mixer(permutation_decomposition(w), mesh, "data", specs, cc)
 for b in (0, 8):
-    dyn = DynamicCompressedGossipMixer(StaticSchedule(w), mesh, "data",
-                                       specs, cc, ef_rebase_every=b)
+    dyn = make_gossip_mixer(None, mesh, "data", specs, cc,
+                            schedule=StaticSchedule(w), ef_rebase_every=b)
     ta, sa = theta, ref.init_state(theta)
     tb, sb = theta, dyn.init_state(theta)
     ja, jb = jax.jit(ref), jax.jit(dyn)
@@ -537,8 +536,8 @@ for b in (0, 8):
     assert float(sa.wire_bits) == float(sb.wire_bits)
     assert int(sb.ef_rounds) == 5
 
-dyn = DynamicCompressedGossipMixer(StaticSchedule(w), mesh, "data", specs, cc,
-                                   ef_rebase_every=2)
+dyn = make_gossip_mixer(None, mesh, "data", specs, cc,
+                        schedule=StaticSchedule(w), ef_rebase_every=2)
 ta, sa = theta, ref.init_state(theta)
 tb, sb = theta, dyn.init_state(theta)
 ja, jb = jax.jit(ref), jax.jit(dyn)
@@ -550,21 +549,23 @@ for n in theta:
                                rtol=1e-5, atol=1e-5)
 
 sched = DropoutSchedule(w, 0.3, seed=5)
-m1 = DynamicCompressedGossipMixer(sched, mesh, "data", specs, cc,
-                                  ef_rebase_every=1)
+m1 = make_gossip_mixer(None, mesh, "data", specs, cc, schedule=sched,
+                       ef_rebase_every=1)
 t1, s1 = jax.jit(m1)(theta, m1.init_state(theta))
-w0 = np.asarray(m1._round_topology_w(jnp.int32(0)))
+w0 = np.asarray(m1.topo.round_w(jnp.int32(0)))
 for n in theta:
     hat = np.asarray(s1.hat[n]).reshape(k, -1)
     s = np.asarray(s1.hat_mix[n]).reshape(k, -1)
     np.testing.assert_allclose(s, w0 @ hat, rtol=1e-5, atol=1e-6)
-    out = np.asarray(theta[n]).reshape(k, -1) + m1.gamma * (s - hat)
+    out = np.asarray(theta[n]).reshape(k, -1) + m1.wire.gamma * (s - hat)
     np.testing.assert_allclose(np.asarray(t1[n]).reshape(k, -1), out,
                                rtol=1e-5, atol=1e-6)
 
-dm = DynamicCompressedDenseMixer(DropoutSchedule(w, 0.3, seed=5), cc)
-gm = DynamicCompressedGossipMixer(DropoutSchedule(w, 0.3, seed=5), mesh,
-                                  "data", specs, cc, ef_rebase_every=1)
+dm = make_dense_mixer(None, compression=cc,
+                      schedule=DropoutSchedule(w, 0.3, seed=5))
+gm = make_gossip_mixer(None, mesh, "data", specs, cc,
+                       schedule=DropoutSchedule(w, 0.3, seed=5),
+                       ef_rebase_every=1)
 td, sd = theta, dm.init_state(theta)
 tg, sg = theta, gm.init_state(theta)
 jd, jg = jax.jit(dm), jax.jit(gm)
@@ -582,8 +583,8 @@ for n in theta:
                                rtol=1e-2, atol=2e-2)
 
 try:
-    DynamicCompressedGossipMixer(DropoutSchedule(w, 0.3), mesh, "data",
-                                 specs, cc, ef_rebase_every=0)
+    make_gossip_mixer(None, mesh, "data", specs, cc,
+                      schedule=DropoutSchedule(w, 0.3), ef_rebase_every=0)
     raise AssertionError("B=0 on a dropout schedule must raise")
 except ValueError:
     pass
@@ -604,8 +605,8 @@ def test_ef_gossip_beats_memoryless_under_dropout():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.comm import CompressionConfig
-from repro.core import TrainerSpec
-from repro.dynamics import DynamicGossipMixer, DropoutSchedule
+from repro.core import TrainerSpec, make_gossip_mixer
+from repro.dynamics import DropoutSchedule
 from repro.graphs import metropolis_weights, ring_graph
 from repro.launch.mesh import make_auto_mesh
 
@@ -620,8 +621,9 @@ def loss_fn(params, batch):
 
 def run(cfg, b):
     specs = {"x": P("node")}
-    mixer = DynamicGossipMixer(DropoutSchedule(w, 0.2, seed=3), mesh, "node",
-                               specs, quantized=cfg, ef_rebase_every=b)
+    mixer = make_gossip_mixer(None, mesh, "node", specs, cfg,
+                              schedule=DropoutSchedule(w, 0.2, seed=3),
+                              ef_rebase_every=b)
     spec = TrainerSpec(num_nodes=k, graph="ring", robust=False, lr=0.03,
                        compress=cfg, metrics_disagreement=False)
     tr = spec.build(loss_fn, mixer=mixer)
@@ -664,8 +666,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.analysis import audit_wire, wire_summary
 from repro.comm import CompressionConfig
-from repro.dynamics import (DynamicCompressedGossipMixer, DynamicGossipMixer,
-                            DropoutSchedule, StaticSchedule)
+from repro.core import make_gossip_mixer
+from repro.dynamics import DropoutSchedule, StaticSchedule
 from repro.graphs import metropolis_weights, ring_graph
 from repro.launch.mesh import make_auto_mesh
 
@@ -684,11 +686,13 @@ def wire(mixer):
     return s
 
 cc = CompressionConfig(kind="int8", seed=0)
-plain = DynamicGossipMixer(DropoutSchedule(w, 0.2, seed=1), mesh, "data", specs)
+plain = make_gossip_mixer(None, mesh, "data", specs,
+                          schedule=DropoutSchedule(w, 0.2, seed=1))
 assert wire(plain)["total"] == plain.bytes_per_round(theta)
 
-mem = DynamicGossipMixer(DropoutSchedule(w, 0.2, seed=1), mesh, "data", specs,
-    quantized=CompressionConfig(kind="int8", error_feedback=False))
+mem = make_gossip_mixer(None, mesh, "data", specs,
+                        CompressionConfig(kind="int8", error_feedback=False),
+                        schedule=DropoutSchedule(w, 0.2, seed=1))
 s_mem = wire(mem)
 assert s_mem["total"] == mem.bytes_per_round(theta)
 assert s_mem["by_dtype"].get("s8", 0) > 0, "int8 payload not on the wire"
@@ -696,26 +700,29 @@ assert s_mem["by_dtype"].get("s8", 0) > 0, "int8 payload not on the wire"
 # int4 rate rides the int8 container: the wire moves the same s8 buffers
 # (HLO bytes unchanged) while the effective-bit accounting halves the
 # entry bits — the scheduled-rate convention of repro.comm
-mem4 = DynamicGossipMixer(DropoutSchedule(w, 0.2, seed=1), mesh, "data",
-    specs, quantized=CompressionConfig(kind="int4", error_feedback=False))
+mem4 = make_gossip_mixer(None, mesh, "data", specs,
+                         CompressionConfig(kind="int4", error_feedback=False),
+                         schedule=DropoutSchedule(w, 0.2, seed=1))
 assert wire(mem4)["total"] == s_mem["total"]
 assert mem4.bytes_per_round(theta) < mem.bytes_per_round(theta)
 
-delta = DynamicCompressedGossipMixer(StaticSchedule(w), mesh, "data", specs,
-                                     cc, ef_rebase_every=0)
+delta = make_gossip_mixer(None, mesh, "data", specs, cc,
+                          schedule=StaticSchedule(w), ef_rebase_every=0)
 d_bytes = wire(delta)["total"]
 assert d_bytes == delta.bytes_per_round(theta), (
     d_bytes, delta.bytes_per_round(theta))
 
-rebase = DynamicCompressedGossipMixer(DropoutSchedule(w, 0.2, seed=1), mesh,
-                                      "data", specs, cc, ef_rebase_every=1)
+rebase = make_gossip_mixer(None, mesh, "data", specs, cc,
+                           schedule=DropoutSchedule(w, 0.2, seed=1),
+                           ef_rebase_every=1)
 r_bytes = wire(rebase)["total"]
 assert r_bytes == rebase.bytes_per_round(theta), (
     r_bytes, rebase.bytes_per_round(theta))
 
 # B >= 2: ONE program holds both round modes -> HLO carries both wires
-both = DynamicCompressedGossipMixer(DropoutSchedule(w, 0.2, seed=1), mesh,
-                                    "data", specs, cc, ef_rebase_every=4)
+both = make_gossip_mixer(None, mesh, "data", specs, cc,
+                         schedule=DropoutSchedule(w, 0.2, seed=1),
+                         ef_rebase_every=4)
 assert wire(both)["total"] == d_bytes + r_bytes
 # amortized static estimate sits between the two modes
 assert d_bytes < both.bytes_per_round(theta) < r_bytes
@@ -786,7 +793,8 @@ def test_ef_gossip_kernel_wire_matches_jnp_path():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.comm import CompressionConfig
-from repro.dynamics import DynamicCompressedGossipMixer, DropoutSchedule
+from repro.core import make_gossip_mixer
+from repro.dynamics import DropoutSchedule
 from repro.graphs import metropolis_weights, ring_graph
 from repro.launch.mesh import make_auto_mesh
 
@@ -797,13 +805,13 @@ specs = {"a": P("data", None)}
 rng = np.random.default_rng(1)
 theta = {"a": jnp.asarray(rng.normal(size=(k, 64)), jnp.float32)}
 sched = lambda: DropoutSchedule(w, 0.3, seed=9)
-jn = DynamicCompressedGossipMixer(
-    sched(), mesh, "data", specs,
-    CompressionConfig(kind="int8", seed=2), ef_rebase_every=3)
-kr = DynamicCompressedGossipMixer(
-    sched(), mesh, "data", specs,
-    CompressionConfig(kind="int8", seed=2, use_kernel=True, interpret=True),
-    ef_rebase_every=3)
+jn = make_gossip_mixer(None, mesh, "data", specs,
+                       CompressionConfig(kind="int8", seed=2),
+                       schedule=sched(), ef_rebase_every=3)
+kr = make_gossip_mixer(None, mesh, "data", specs,
+                       CompressionConfig(kind="int8", seed=2, use_kernel=True,
+                                         interpret=True),
+                       schedule=sched(), ef_rebase_every=3)
 ta, sa = theta, jn.init_state(theta)
 tb, sb = theta, kr.init_state(theta)
 ja, jb = jax.jit(jn), jax.jit(kr)
@@ -831,8 +839,8 @@ def test_ef_rebase_clock_composes_with_local_updates():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.comm import CompressionConfig
-from repro.dynamics import (DynamicCompressedGossipMixer, DropoutSchedule,
-                            LocalUpdateMixer)
+from repro.core import make_gossip_mixer
+from repro.dynamics import DropoutSchedule, LocalUpdateMixer
 from repro.graphs import metropolis_weights, ring_graph
 from repro.launch.mesh import make_auto_mesh
 
@@ -842,9 +850,10 @@ mesh = make_auto_mesh((k,), ("data",))
 specs = {"a": P("data", None)}
 rng = np.random.default_rng(0)
 theta = {"a": jnp.asarray(rng.normal(size=(k, 64)), jnp.float32)}
-inner = DynamicCompressedGossipMixer(
-    DropoutSchedule(w, 0.0, seed=2), mesh, "data", specs,
-    CompressionConfig(kind="int8", seed=1), ef_rebase_every=2)
+inner = make_gossip_mixer(None, mesh, "data", specs,
+                          CompressionConfig(kind="int8", seed=1),
+                          schedule=DropoutSchedule(w, 0.0, seed=2),
+                          ef_rebase_every=2)
 mixer = LocalUpdateMixer(inner, 2)
 state = mixer.init_state(theta)
 step = jax.jit(mixer)

@@ -1,9 +1,10 @@
-"""Equivalence matrix of the Topology × Transport × Wire refactor.
+"""Equivalence matrix of the Topology × Transport × Wire stacks.
 
-Every legacy mixer name must (a) still construct — as a thin shim over
-:class:`repro.comm.composed.ComposedMixer` — and (b) reproduce its
-pre-refactor trajectory bit-exactly, field by field, against the anchors in
-``tests/data/mixer_anchors.json`` (captured from the pre-refactor classes).
+Every factory and argument mix must (a) build the expected layer stack
+behind :class:`repro.comm.composed.ComposedMixer` and (b) reproduce its
+trajectory bit-exactly, field by field, against the anchors in
+``tests/data/mixer_anchors.json`` (captured from the classes that preceded
+the layers).
 The anchor replay runs ``tests/data/gen_mixer_anchors.py check`` in a
 subprocess per device group; checkpoints written under the old class layout
 must restore through ``COMM_STATE_PAD`` and continue bit-exactly on the
@@ -46,44 +47,74 @@ def test_gossip_group_matches_pre_refactor_anchors():
 
 
 def test_every_legacy_name_is_a_composed_shim():
-    """The class matrix is gone: every legacy mixer name constructs a layer
-    stack behind ComposedMixer (RepeatMixer/LocalUpdateMixer wrap one)."""
-    from repro.comm import CompressionConfig
-    from repro.comm.composed import ComposedMixer
-    from repro.comm.mixers import CompressedDenseMixer
+    """The class matrix is gone: each factory and argument mix returns one
+    ComposedMixer whose (topology, transport, wire) stack and scope name
+    are these (RepeatMixer/LocalUpdateMixer wrap one).  The gossip rows
+    are covered by the anchor replay (they need a device mesh)."""
+    from repro.comm import (
+        ChocoWire,
+        CodecWire,
+        CompressionConfig,
+        ComposedMixer,
+        DenseTransport,
+        IdentityWire,
+        ScheduledTopology,
+        StarTopology,
+        StarTransport,
+        StaticTopology,
+    )
     from repro.core.consensus import (
-        DenseMixer,
-        HubMixer,
-        IdentityMixer,
         RepeatMixer,
+        make_dense_mixer,
+        make_hub_mixer,
+        make_identity_mixer,
     )
+    from repro.dynamics.faults import FaultConfig
     from repro.dynamics.local import LocalUpdateMixer
-    from repro.dynamics.mixers import (
-        DynamicCompressedDenseMixer,
-        DynamicDenseMixer,
-    )
-    from repro.dynamics.schedule import DropoutSchedule
+    from repro.dynamics.schedule import DropoutSchedule, StaticSchedule
     from repro.graphs import build_graph, metropolis_weights
 
     w = metropolis_weights(build_graph("ring", 8))
     cc = CompressionConfig(kind="int8", seed=11)
-    direct = [
-        IdentityMixer(),
-        DenseMixer(w),
-        HubMixer(8),
-        CompressedDenseMixer(w, cc),
-        DynamicDenseMixer(DropoutSchedule(w, 0.3, seed=5)),
-        DynamicCompressedDenseMixer(DropoutSchedule(w, 0.3, seed=5), cc),
+    mem = CompressionConfig(kind="int8", error_feedback=False, seed=11)
+    drop = DropoutSchedule(w, 0.3, seed=5)
+    faults = FaultConfig(straggler_p=0.2, seed=3)
+    table = [
+        (make_identity_mixer(), None, None, IdentityWire, "identity"),
+        (make_dense_mixer(w), StaticTopology, DenseTransport, IdentityWire,
+         "dense"),
+        (make_dense_mixer(w, compression=cc), StaticTopology,
+         DenseTransport, ChocoWire, "dense"),
+        (make_dense_mixer(w, compression=mem), StaticTopology,
+         DenseTransport, CodecWire, "dense"),
+        (make_dense_mixer(None, schedule=drop), ScheduledTopology,
+         DenseTransport, IdentityWire, "dense"),
+        (make_dense_mixer(None, schedule=StaticSchedule(w), faults=faults),
+         ScheduledTopology, DenseTransport, IdentityWire, "dense"),
+        (make_dense_mixer(None, compression=cc, schedule=drop),
+         ScheduledTopology, DenseTransport, ChocoWire, "dense"),
+        (make_hub_mixer(8), StarTopology, StarTransport, IdentityWire,
+         "hub"),
+        (make_hub_mixer(8, cc), StarTopology, DenseTransport, ChocoWire,
+         "hub"),
     ]
-    for m in direct:
-        assert isinstance(m, ComposedMixer), type(m).__name__
+    for m, topo, transport, wire, name in table:
+        assert type(m) is ComposedMixer
+        assert (topo is None and m.topo is None) or type(m.topo) is topo
+        assert ((transport is None and m.transport is None)
+                or type(m.transport) is transport)
+        assert type(m.wire) is wire, (name, type(m.wire))
+        assert m.name == name
+    # the EF wire carries no re-base clock off the time-varying gossip stack
+    assert all(getattr(m.wire, "clock", None) is None for m, *_ in table)
     wrappers = [
-        RepeatMixer(DenseMixer(w), 2),
-        LocalUpdateMixer(DenseMixer(w), 2, gradient_tracking=True),
-        LocalUpdateMixer(HubMixer(8), 4, gradient_tracking=True),
+        RepeatMixer(make_dense_mixer(w), 2),
+        LocalUpdateMixer(make_dense_mixer(w), 2, gradient_tracking=True),
+        LocalUpdateMixer(make_hub_mixer(8), 4, gradient_tracking=True),
     ]
     for m in wrappers:
-        assert isinstance(m.inner, ComposedMixer), type(m).__name__
+        assert type(m.inner) is ComposedMixer, type(m).__name__
+        assert m.name == type(m).__name__
 
 
 def _toy_trainer(**kw):

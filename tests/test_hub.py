@@ -1,12 +1,12 @@
 """Hub-and-spoke (federated) lowering: the Topology × Transport × Wire
-refactor cashed in.
+stack cashed in.
 
 Anchors:
-  * HubMixer (StarTopology × StarTransport × IdentityWire) equals the dense
+  * make_hub_mixer (StarTopology × StarTransport × IdentityWire) equals the dense
     simulation of W = 11ᵀ/K and reaches exact consensus in ONE round;
   * make_hub_mixer routes compression through the dense codec stack with
     the star W (server averages the reconstructed client innovations);
-  * LocalUpdateMixer(HubMixer, H) is FedAvg; adding gradient_tracking is
+  * LocalUpdateMixer(make_hub_mixer(K), H) is FedAvg; adding gradient_tracking is
     the SCAFFOLD control variate — both train through TrainerSpec via
     --topology hub;
   * DynamicsConfig rejects hub + faults (the star has no fault model yet).
@@ -17,9 +17,15 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.comm import CommState, CompressionConfig, CompressedDenseMixer
+from repro.comm import (
+    ChocoWire,
+    CommState,
+    CompressionConfig,
+    DenseTransport,
+    StarTransport,
+)
 from repro.core import TrainerSpec
-from repro.core.consensus import DenseMixer, HubMixer, make_hub_mixer
+from repro.core.consensus import make_dense_mixer, make_hub_mixer
 from repro.dynamics import (
     DynamicsConfig,
     FaultConfig,
@@ -38,7 +44,7 @@ def _theta(k=K, seed=0):
 
 def test_hub_is_exact_one_round_consensus():
     theta = _theta()
-    mixer = HubMixer(K)
+    mixer = make_hub_mixer(K)
     out, comm = jax.jit(mixer)(theta, mixer.init_state(theta))
     for name, x in theta.items():
         mean = np.mean(np.asarray(x, np.float32), axis=0)
@@ -55,8 +61,8 @@ def test_hub_is_exact_one_round_consensus():
 
 def test_hub_matches_dense_star_matrix():
     theta = _theta()
-    hub = HubMixer(K)
-    dense = DenseMixer(np.full((K, K), 1.0 / K))
+    hub = make_hub_mixer(K)
+    dense = make_dense_mixer(np.full((K, K), 1.0 / K))
     th, _ = jax.jit(hub)(theta, hub.init_state(theta))
     td, _ = jax.jit(dense)(theta, dense.init_state(theta))
     for name in theta:
@@ -67,7 +73,7 @@ def test_hub_matches_dense_star_matrix():
 
 def test_hub_protocol_state_is_trivial():
     theta = _theta()
-    hub = HubMixer(K)
+    hub = make_hub_mixer(K)
     st = hub.init_state(theta)
     assert isinstance(st, CommState)
     assert st.hat == () and st.hat_mix == () and st.track == ()
@@ -78,15 +84,16 @@ def test_hub_protocol_state_is_trivial():
 
 def test_hub_consensus_scope_name():
     theta = _theta()
-    hub = HubMixer(K)
+    hub = make_hub_mixer(K)
     lowered = jax.jit(hub).lower(theta, hub.init_state(theta))
     hlo = lowered.compiler_ir("hlo").as_hlo_module().to_string()
-    assert "obs:consensus/HubMixer" in hlo
+    assert "obs:consensus/hub" in hlo
 
 
 def test_make_hub_mixer_compressed_rides_dense_star():
     m = make_hub_mixer(K, CompressionConfig(kind="int8", seed=3))
-    assert isinstance(m, CompressedDenseMixer)
+    assert isinstance(m.transport, DenseTransport)
+    assert isinstance(m.wire, ChocoWire)
     np.testing.assert_allclose(np.asarray(m.w), np.full((K, K), 1.0 / K),
                                rtol=1e-7)
     theta = _theta()
@@ -99,8 +106,8 @@ def test_make_hub_mixer_compressed_rides_dense_star():
     assert spread1 < 0.1 * spread0
     assert m.compression is not None and int(comm.rounds) == 1
     # uncompressed falls back to the star transport
-    assert isinstance(make_hub_mixer(K), HubMixer)
-    assert isinstance(make_hub_mixer(K, None), HubMixer)
+    assert isinstance(make_hub_mixer(K).transport, StarTransport)
+    assert isinstance(make_hub_mixer(K, None).transport, StarTransport)
 
 
 def test_dynamics_config_hub_validation():
@@ -115,24 +122,26 @@ def test_dynamics_config_hub_validation():
 def test_build_dynamic_mixer_hub_paths():
     w = np.full((K, K), 1.0 / K)
     m = build_dynamic_mixer(DynamicsConfig(topology="hub"), w)
-    assert isinstance(m, HubMixer)
+    assert isinstance(m.transport, StarTransport)
     fed = build_dynamic_mixer(
         DynamicsConfig(topology="hub", local_updates=4), w)
     assert isinstance(fed, LocalUpdateMixer) and fed.period == 4
-    assert isinstance(fed.inner, HubMixer) and not fed.gt
+    assert isinstance(fed.inner.transport, StarTransport) and not fed.gt
     scaffold = build_dynamic_mixer(
         DynamicsConfig(topology="hub", local_updates=4,
                        gradient_tracking=True), w)
-    assert scaffold.gt and isinstance(scaffold.inner, HubMixer)
+    assert scaffold.gt and isinstance(scaffold.inner.transport,
+                                      StarTransport)
     comp = build_dynamic_mixer(
         DynamicsConfig(topology="hub"), w,
         compression=CompressionConfig(kind="int8"))
-    assert isinstance(comp, CompressedDenseMixer)
+    assert isinstance(comp.transport, DenseTransport)
+    assert isinstance(comp.wire, ChocoWire)
 
 
 def test_fedavg_rounds_local_then_exact_average():
     theta = _theta()
-    fed = LocalUpdateMixer(HubMixer(K), 3)
+    fed = LocalUpdateMixer(make_hub_mixer(K), 3)
     st = fed.init_state(theta)
     t = theta
     step = jax.jit(fed)

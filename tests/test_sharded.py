@@ -161,14 +161,14 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_auto_mesh
 from repro.graphs import ring_graph, metropolis_weights, permutation_decomposition
-from repro.core import make_dense_mixer, make_hierarchical_mixer
+from repro.core import make_dense_mixer, make_gossip_mixer
 mesh = make_auto_mesh((4, 2), ("node", "replica"))
 g = ring_graph(4)
 w = metropolis_weights(g)
 d = permutation_decomposition(w)
 theta = {"a": jnp.arange(4*6, dtype=jnp.float32).reshape(4, 6)}
 specs = {"a": P("node", None)}   # replicated over "replica"
-mixer = make_hierarchical_mixer(d, mesh, "node", "replica", specs)
+mixer = make_gossip_mixer(d, mesh, "node", specs, replica_axis="replica")
 dm = make_dense_mixer(w)
 dense, _ = dm(theta, dm.init_state(theta))
 out, _ = jax.jit(mixer)(theta, mixer.init_state(theta))
